@@ -30,8 +30,9 @@ timings are NOT meaningful TPU efficiency numbers, the curve's
     python bench_scaling.py --model resnet18 --batch-size 2 \
         --image-size 32 --worlds 1x1,1x2,2x1,2x2 --out SCALING_r01.json
 
-On a real pod, point ``--worlds`` at the slice inventory (``4x4`` =
-4 hosts x 4 chips) and the same artifact falls out.
+This is a CPU harness by construction: every rank it spawns is pinned
+to the CPU (``JAX_PLATFORMS=cpu`` in ``_world_env``), so its children
+never contend for a chip and nothing it prints is a device number.
 """
 
 import argparse
@@ -326,7 +327,11 @@ def drive(args):
 
 
 def main():
-    ap = argparse.ArgumentParser()
+    ap = argparse.ArgumentParser(
+        description="Weak-scaling sweep over multi-process worlds. CPU "
+                    "harness: every rank it spawns is pinned to "
+                    "JAX_PLATFORMS=cpu, so it never touches or contends "
+                    "for a chip and its times are not device numbers.")
     ap.add_argument("--model", default="resnet101",
                     choices=["resnet18", "resnet50", "resnet101", "vgg16"])
     ap.add_argument("--batch-size", type=int, default=64,

@@ -1,0 +1,567 @@
+"""The quickest proof that the trainer still starts on the chip.
+
+    python chip_smoke.py                # one TPU chip
+    python chip_smoke.py --four-chips   # one four-chip host, nothing else
+
+With no arguments it drives the training path once through the entry
+points users call, at the full width of two models the repo supports:
+
+* ``hvdrun``        ``hvdrun -np 1 -- python examples/jax_synthetic_benchmark.py``
+                    as a child, before this process touches jax: the
+                    launcher parent must not hold the chip its worker needs.
+* ``flash_kernel``  the Pallas flash kernel's forward and gradients against
+                    ``_reference_attention`` at the two head shapes of the
+                    main path, on the chip (not the interpreter).
+* ``lm``            the decoder LM, 8 layers d2048 16 heads, vocab 32000,
+                    sequence 2048, batch 8, flash on (``make_lm_bench`` ->
+                    ``make_lm_train_step``): loss finite and falling on a
+                    repeated batch, ``tpu_custom_call`` in the compiled step.
+* ``resnet101``     bf16 compute / f32 params, batch 256 at 224x224,
+                    SGD-momentum, donated buffers (``create_train_state`` /
+                    ``make_train_step``): loss finite at every step.
+* ``serve``         a ``ServeEngine`` built as ``hvd-serve`` builds it, 12
+                    layers d768: 512-token prompts, 32 greedy tokens each,
+                    against the argmax of an uncached full forward.
+
+``--four-chips`` runs only the data-parallel phase and what it is compared
+with: the 12-layer d768 LM on the ``(data=4)`` mesh of ``hvd.init()``
+against the same global batch and seed on the first chip alone.
+
+Depth is cut and the weights are random, made from a seed; widths are not
+cut. Every phase prints one JSON line. A phase that fails raises: nothing
+here turns a failure into a result, a fallback from the flash kernel is an
+error, and nothing runs unless jax's first device is a TPU. The last line
+of standard output is the contract's
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Step times and compile seconds are information for the first benchmark PR,
+not claims.
+"""
+
+import argparse
+import gc
+import json
+import os
+import signal
+import subprocess
+import sys
+import time
+import warnings
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+# The platform a phase may run on. The script never sets JAX_PLATFORMS.
+REQUIRED_PLATFORM = "tpu"
+
+HVDRUN = dict(model="resnet50", batch=32, image=224, warmup=1,
+              batches_per_iter=3, iters=1, timeout=600)
+# kernel vs reference: the main path's head shapes [B, S, H, D]; batch 2
+# keeps the plain-XLA reference's S x S scores (and their gradients)
+# small beside the kernel's operands
+KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64))
+# bf16 operands, fp32 accumulation: a tensor agrees when its error is
+# under 2% of the reference in L2 and no element is off by more than
+# tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
+KERNEL_REL_L2 = 2e-2
+KERNEL_ATOL = dict(out=5e-2, dq=8e-2, dk=8e-2, dv=8e-2)
+LM = dict(layers=8, d_model=2048, heads=16, vocab=32000, seq_len=2048,
+          batch=8, steps=4)
+RESNET = dict(model="resnet101", batch=256, image=224, steps=3)
+SERVE = dict(layers=12, d_model=768, heads=12, vocab=32000, prompts=4,
+             prompt_len=512, new_tokens=32,
+             # hvd-serve's defaults (serve/cli.py build_parser)
+             max_slots=8, prefill_chunk=256, block_size=16,
+             max_seq_len=2048)
+# a greedy token may differ from the reference argmax only where the
+# reference's own logits are closer than two bf16 steps at their scale
+SERVE_LOGIT_TOL = 2 * 2.0 ** -5
+DP = dict(layers=12, d_model=768, heads=12, vocab=32000, seq_len=2048,
+          batch=8, steps=3)
+# four chips against one, bf16: the same losses up to reduction order
+DP_LOSS_RTOL = 1e-2
+
+
+# ---- children (only while this process has not touched jax) ------------
+
+def _run_child(cmd, timeout):
+    """Run ``cmd`` in its own process group with the repo importable;
+    the whole group is killed if it outlives ``timeout``. Returns its
+    standard output; a non-zero exit raises with the child's tail."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.Popen(cmd, env=env, cwd=REPO, text=True,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            start_new_session=True)
+    try:
+        out, err = proc.communicate(timeout=timeout)
+    except subprocess.TimeoutExpired:
+        os.killpg(proc.pid, signal.SIGKILL)
+        proc.communicate()
+        raise RuntimeError(f"{cmd[:4]} ... outlived its {timeout}s limit")
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"{cmd[:4]} ... exited with {proc.returncode}\n"
+            f"--- stdout ---\n{out[-2000:]}\n--- stderr ---\n{err[-4000:]}")
+    return out
+
+
+def _last_json(out):
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def probe_device():
+    """What jax finds, asked of a child so that this process does not
+    yet hold the chip the hvdrun phase's worker needs."""
+    code = ("import json, jax; d = jax.devices(); print(json.dumps("
+            "{'platform': d[0].platform, 'kind': d[0].device_kind, "
+            "'count': len(d)}))")
+    return _last_json(_run_child([sys.executable, "-c", code], 300))
+
+
+def phase_hvdrun():
+    """The normal entry point: launcher parent + one worker. The parent
+    imports horovod_tpu (and so jax) but must never start a backend, or
+    its worker could not have the chip."""
+    a = HVDRUN
+    t0 = time.perf_counter()
+    out = _run_child(
+        [sys.executable, "-m", "horovod_tpu.run", "-np", "1", "--",
+         sys.executable, "examples/jax_synthetic_benchmark.py",
+         "--model", a["model"], "--batch-size", str(a["batch"]),
+         "--image-size", str(a["image"]),
+         "--num-warmup-batches", str(a["warmup"]),
+         "--num-batches-per-iter", str(a["batches_per_iter"]),
+         "--num-iters", str(a["iters"])], a["timeout"])
+    line = _last_json(out)
+    if line["platform"] != REQUIRED_PLATFORM:
+        raise RuntimeError(f"the hvdrun worker ran on {line['platform']!r}, "
+                           f"not {REQUIRED_PLATFORM!r}: {line}")
+    _check_finite("hvdrun worker loss", [line["final_loss"]])
+    print(json.dumps({"phase": "hvdrun", "np": 1, "worker": line,
+                      "wall_seconds": round(time.perf_counter() - t0, 1)}),
+          flush=True)
+
+
+# ---- in-process phases -------------------------------------------------
+
+class CompileWatch:
+    """Seconds jax spent in backend compiles and the persistent cache's
+    hits and misses, over a ``with`` block."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self.hits = 0
+        self.misses = 0
+
+    def _event(self, name, **_):
+        if name == "/jax/compilation_cache/cache_hits":
+            self.hits += 1
+        elif name == "/jax/compilation_cache/cache_misses":
+            self.misses += 1
+
+    def _duration(self, name, seconds, **_):
+        if name == "/jax/core/compile/backend_compile_duration":
+            self.seconds += seconds
+
+    def __enter__(self):
+        import jax.monitoring as m
+        m.register_event_listener(self._event)
+        m.register_event_duration_secs_listener(self._duration)
+        return self
+
+    def __exit__(self, *exc):
+        import jax.monitoring as m
+        m.unregister_event_listener(self._event)
+        m.unregister_event_duration_listener(self._duration)
+
+    def fields(self):
+        return {"compile_seconds": round(self.seconds, 2),
+                "persistent_cache": {"hits": self.hits,
+                                     "misses": self.misses,
+                                     "hit": self.hits > 0
+                                     and self.misses == 0}}
+
+
+def _device():
+    import jax
+    d = jax.devices()
+    return {"platform": d[0].platform, "kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def _emit(phase, **fields):
+    import jax
+
+    from horovod_tpu.utils.benchmarks import device_fields
+
+    stats = jax.devices()[0].memory_stats() or {}
+    print(json.dumps({
+        "phase": phase, **device_fields(), **fields,
+        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+        "bytes_in_use": stats.get("bytes_in_use"),
+        "libtpu_init_args": os.environ.get("LIBTPU_INIT_ARGS", ""),
+    }), flush=True)
+
+
+def _check_finite(what, values):
+    import math
+    if not all(math.isfinite(v) for v in values):
+        raise RuntimeError(f"{what} is not finite: {values}")
+
+
+def phase_init():
+    """What the backend started under: the one installation, the flags
+    ``hvd.init()`` handed libtpu (on every line as ``libtpu_init_args``),
+    and where and how large the persistent compile cache may grow — a
+    capped cache evicts, and a second run then compiles again."""
+    import importlib.metadata as md
+
+    import jax
+
+    _emit("init",
+          versions={p: md.version(p) for p in
+                    ("jax", "jaxlib", "libtpu", "flax", "optax")},
+          python=sys.version.split()[0],
+          compile_cache_dir=jax.config.jax_compilation_cache_dir,
+          compile_cache_max_size=jax.config.jax_compilation_cache_max_size,
+          bytes_limit=(jax.devices()[0].memory_stats() or {}).get(
+              "bytes_limit"))
+
+
+def assert_kernel_compiled(text, where):
+    """The flash kernel is in the compiled program as a Mosaic custom
+    call; interpret mode would have lowered it to plain HLO."""
+    if "tpu_custom_call" not in text:
+        raise RuntimeError(f"{where}: no tpu_custom_call in the compiled "
+                           "program — the flash kernel did not run as a "
+                           "kernel")
+    return text.count("tpu_custom_call")
+
+
+def _timed_steps(step_once, n):
+    """``n`` calls of ``step_once() -> loss``, each ended by reading the
+    loss back. Returns (losses, seconds per call)."""
+    losses, seconds = [], []
+    for _ in range(n):
+        t0 = time.perf_counter()
+        losses.append(float(step_once()))
+        seconds.append(round(time.perf_counter() - t0, 4))
+    return losses, seconds
+
+
+def phase_flash_kernel():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops import flash_attention as fa
+
+    results = []
+    with CompileWatch() as watch:
+        for shape in KERNEL_SHAPES:
+            b, s, h, d = shape
+            rng = np.random.default_rng(0)
+            q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                       for _ in range(3))
+            w = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+            # w is an argument, not a closure: a captured array becomes a
+            # constant of the executable, and four 30 MB constants push
+            # the train steps out of a size-capped compile cache
+            def kernel_loss(q, k, v, w):
+                out = fa.flash_attention(q, k, v, causal=True)
+                return jnp.sum(out.astype(jnp.float32) * w), out
+
+            def reference_loss(q, k, v, w):
+                def to_bh(x):
+                    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+                out = fa._reference_attention(
+                    to_bh(q), to_bh(k), to_bh(v),
+                    jnp.zeros((2,), jnp.int32), True, 1.0 / d ** 0.5)
+                out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+                return jnp.sum(out.astype(jnp.float32) * w), out
+
+            grad = lambda f: jax.jit(jax.value_and_grad(  # noqa: E731
+                f, argnums=(0, 1, 2), has_aux=True))
+            kernel = grad(kernel_loss)
+            kernels = assert_kernel_compiled(
+                kernel.lower(q, k, v, w).compile().as_text(),
+                f"flash kernel fwd+bwd at {shape}")
+            (_, out_k), grads_k = kernel(q, k, v, w)
+            (_, out_r), grads_r = grad(reference_loss)(q, k, v, w)
+            errors = {}
+            for name, got, want in zip(("out", "dq", "dk", "dv"),
+                                       (out_k,) + grads_k,
+                                       (out_r,) + grads_r):
+                got = np.asarray(got, np.float32)
+                want = np.asarray(want, np.float32)
+                rel = float(np.linalg.norm(got - want)
+                            / np.linalg.norm(want))
+                worst = float(np.max(np.abs(got - want)
+                                     / np.maximum(np.abs(want), 1.0)))
+                errors[name] = {"rel_l2": round(rel, 5),
+                                "max_abs": round(worst, 5)}
+                if not (rel <= KERNEL_REL_L2
+                        and worst <= KERNEL_ATOL[name]):
+                    raise RuntimeError(
+                        f"flash kernel {name} at {shape} disagrees with "
+                        f"_reference_attention: {errors[name]} (allowed "
+                        f"rel_l2 {KERNEL_REL_L2}, max_abs "
+                        f"{KERNEL_ATOL[name]})")
+            results.append({"shape": list(shape), "dtype": "bfloat16",
+                            "tpu_custom_calls": kernels, "errors": errors})
+    _emit("flash_kernel", shapes=results,
+          tolerance={"rel_l2": KERNEL_REL_L2, "max_abs": KERNEL_ATOL},
+          **watch.fields())
+
+
+def _lm_run(mesh, a):
+    """Build the LM benchmark workload on ``mesh``, compile its step
+    ahead of time (timed, cache watched), then take ``a['steps']`` steps
+    on one repeated batch through the step a user calls. Returns the
+    phase fields plus the objects a caller wants to inspect."""
+    import jax.numpy as jnp
+
+    from horovod_tpu.utils.benchmarks import make_lm_bench
+
+    step, state, tokens = make_lm_bench(
+        mesh=mesh, seq_axis=None, batch=a["batch"], seq_len=a["seq_len"],
+        layers=a["layers"], d_model=a["d_model"], heads=a["heads"],
+        vocab=a["vocab"], flash=True, dtype=jnp.bfloat16)
+    with CompileWatch() as watch:
+        compiled = step.lower(state, tokens).compile()
+    text = compiled.as_text()
+    kernels = assert_kernel_compiled(text, "LM train step")
+    box = [state]
+
+    def once():
+        box[0], loss = step(box[0], tokens)
+        return loss
+
+    losses, seconds = _timed_steps(once, a["steps"])
+    _check_finite("LM loss", losses)
+    if not losses[-1] < losses[0]:
+        raise RuntimeError(f"LM loss did not fall on a repeated batch: "
+                           f"{losses}")
+    fields = dict(
+        config={k: a[k] for k in ("layers", "d_model", "heads", "vocab",
+                                  "seq_len", "batch")},
+        dtype="bfloat16", flash_attention=True, tpu_custom_calls=kernels,
+        mesh=dict(zip(mesh.axis_names, mesh.devices.shape)),
+        losses=[round(x, 4) for x in losses], step_seconds=seconds,
+        **watch.fields())
+    return fields, box[0], compiled, text, tokens
+
+
+def phase_lm():
+    import jax
+    import numpy as np
+
+    # bench.py's and the example's mesh: (data, seq) with seq unused
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                             ("data", "seq"))
+    fields = _lm_run(mesh, LM)[0]
+    _emit("lm", **fields)
+
+
+def phase_resnet():
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    import horovod_tpu as hvd
+    from horovod_tpu import training
+    from horovod_tpu.utils.benchmarks import make_model, synthetic_batch
+
+    a = RESNET
+    model = make_model(a["model"], dtype=jnp.bfloat16)
+    tx = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9))
+    global_batch = a["batch"] * hvd.num_devices()
+    images, labels = synthetic_batch(global_batch, a["image"],
+                                     dtype=jnp.bfloat16)
+    state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
+                                        images[:1])
+    step = training.make_train_step(model, tx, donate=True)
+    with CompileWatch() as watch:
+        step.lower(state, images, labels).compile()
+    box = [state]
+
+    def once():
+        box[0], loss = step(box[0], images, labels)
+        return loss
+
+    losses, seconds = _timed_steps(once, a["steps"])
+    _check_finite("ResNet loss", losses)
+    param_dtypes = sorted({str(x.dtype) for x in
+                           jax.tree_util.tree_leaves(box[0].params)})
+    _emit("resnet101", config=dict(a), compute_dtype="bfloat16",
+          param_dtypes=param_dtypes, optimizer="sgd(0.01, momentum=0.9)",
+          donate=True, losses=[round(x, 4) for x in losses],
+          step_seconds=seconds, **watch.fields())
+
+
+def phase_serve():
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig)
+    from horovod_tpu.parallel import mesh as mesh_lib
+    from horovod_tpu.serve import engine as engine_lib
+    from horovod_tpu.serve import kvcache
+
+    a = SERVE
+    # hvd-serve's construction (serve/cli.py main), weights from a seed
+    # where it loads a checkpoint
+    cfg = TransformerConfig(
+        vocab_size=a["vocab"], num_layers=a["layers"],
+        num_heads=a["heads"], d_model=a["d_model"],
+        d_ff=4 * a["d_model"], dtype=jnp.bfloat16, causal=True)
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    mbps = -(-a["max_seq_len"] // a["block_size"])
+    kv = kvcache.KVCacheConfig(
+        num_blocks=a["max_slots"] * mbps + 1, block_size=a["block_size"],
+        num_layers=a["layers"], num_heads=a["heads"],
+        head_dim=a["d_model"] // a["heads"], max_blocks_per_seq=mbps,
+        dtype=jnp.bfloat16)
+    eng = engine_lib.ServeEngine(
+        model, params, kv, mesh=mesh_lib.build_mesh(jax.devices()),
+        max_slots=a["max_slots"], prefill_chunk=a["prefill_chunk"])
+    rng = np.random.default_rng(0)
+    prompts = rng.integers(0, a["vocab"],
+                           size=(a["prompts"], a["prompt_len"]))
+    t0 = time.perf_counter()
+    with CompileWatch() as watch:
+        eng.start()
+        try:
+            requests = [eng.generate(p, a["new_tokens"]) for p in prompts]
+            generated = np.asarray([r.result(timeout=900)
+                                    for r in requests])
+        finally:
+            eng.stop()
+    wall = time.perf_counter() - t0
+    if generated.shape != (a["prompts"], a["new_tokens"]):
+        raise RuntimeError(f"serve returned {generated.shape} tokens")
+
+    # one uncached full forward over prompt ++ the engine's own tokens:
+    # position prompt_len-1+i must choose token i
+    full = np.concatenate([prompts, generated[:, :-1]], axis=1)
+    first = a["prompt_len"] - 1
+    logits = np.asarray(jax.jit(
+        lambda p, t: model.apply({"params": p}, t)[:, first:, :])(
+            params, jnp.asarray(full, jnp.int32)))
+    chosen = np.take_along_axis(logits, generated[..., None], -1)[..., 0]
+    shortfall = logits.max(-1) - chosen
+    exact = int((logits.argmax(-1) == generated).sum())
+    if shortfall.max() > SERVE_LOGIT_TOL:
+        raise RuntimeError(
+            f"serve: a greedy token scores {shortfall.max():.4f} under "
+            f"the uncached forward's best (allowed {SERVE_LOGIT_TOL})")
+    _emit("serve", config=dict(a), dtype="bfloat16",
+          tokens_generated=int(generated.size),
+          argmax_exact=exact, argmax_within_tolerance=int(generated.size),
+          worst_logit_shortfall=round(float(shortfall.max()), 5),
+          logit_tolerance=SERVE_LOGIT_TOL, wall_seconds=round(wall, 2),
+          **watch.fields())
+
+
+def phase_data_parallel():
+    """The path across chips: the same LM step on the four-chip mesh of
+    ``hvd.init()`` and on the first chip alone."""
+    import jax
+    import numpy as np
+
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import gspmd
+
+    mesh4 = hvd.mesh()
+    n = mesh4.devices.size
+    fields4, state4, compiled4, text4, tokens = _lm_run(mesh4, DP)
+
+    def devices_of(x):
+        return sorted(s.device.id for s in x.addressable_shards)
+
+    # the batch as the compiled step takes it, and the state it returns
+    token_sharding = compiled4.input_shardings[0][-1]
+    batch_shard = token_sharding.shard_shape(tokens.shape)
+    batch_devices = sorted(d.id for d in token_sharding.device_set)
+    leaf_devices = {tuple(devices_of(x))
+                    for x in jax.tree_util.tree_leaves(state4)}
+    if (len(batch_devices) != n or batch_shard[0] * n != tokens.shape[0]
+            or leaf_devices != {tuple(batch_devices)}):
+        raise RuntimeError(
+            f"not spread over {n} devices: batch shard {batch_shard} on "
+            f"{batch_devices}, state leaves on {sorted(leaf_devices)}")
+    by_axis = gspmd.collective_axis_bytes_from_hlo(text4, mesh4)
+    if not by_axis.get("data", {}).get("bytes"):
+        raise RuntimeError(f"no collective over the {n}-way data axis in "
+                           f"the compiled step: {by_axis}")
+    _emit("data_parallel_4", **fields4, batch_shard_shape=list(batch_shard),
+          batch_devices=batch_devices,
+          state_leaf_devices=sorted(map(list, leaf_devices)),
+          zero_rows="not sharded by this step (plain DistributedOptimizer)",
+          collectives_by_axis=by_axis)
+    del state4, compiled4
+    gc.collect()
+
+    mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    fields1 = _lm_run(mesh1, DP)[0]
+    _emit("data_parallel_1", **fields1)
+    worst = max(abs(x - y) / abs(y) for x, y in
+                zip(fields4["losses"], fields1["losses"]))
+    if worst > DP_LOSS_RTOL:
+        raise RuntimeError(
+            f"four chips and one disagree: {fields4['losses']} against "
+            f"{fields1['losses']} (worst {worst:.4f}, allowed "
+            f"{DP_LOSS_RTOL})")
+    print(json.dumps({"phase": "data_parallel_agreement",
+                      "losses_4": fields4["losses"],
+                      "losses_1": fields1["losses"],
+                      "worst_relative_difference": round(worst, 6),
+                      "tolerance": DP_LOSS_RTOL}), flush=True)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--four-chips", action="store_true",
+                    help="run only the data-parallel LM phase on a "
+                         "four-chip host and its one-chip comparison")
+    args = ap.parse_args()
+
+    found = probe_device()
+    if found["platform"] != REQUIRED_PLATFORM:
+        sys.exit(f"chip_smoke: needs a {REQUIRED_PLATFORM.upper()} and jax "
+                 f"found platform {found['platform']!r} "
+                 f"({found['count']} x {found['kind']}); no phase was run")
+    if args.four_chips and found["count"] != 4:
+        sys.exit(f"chip_smoke --four-chips: needs four chips, jax found "
+                 f"{found['count']}; no phase was run")
+
+    if not args.four_chips:
+        phase_hvdrun()  # a child: must come before this process has jax
+
+    import horovod_tpu as hvd
+    from horovod_tpu.ops.flash_attention import FlashFallbackWarning
+
+    # asked for flash and got the reference: an error in every phase
+    warnings.simplefilter("error", FlashFallbackWarning)
+    hvd.init()  # first backend touch: libtpu starts under its flags
+    device = _device()
+    if device != found:
+        sys.exit(f"chip_smoke: this process sees {device}, the probe saw "
+                 f"{found}")
+    phases = ([phase_init, phase_data_parallel] if args.four_chips else
+              [phase_init, phase_flash_kernel, phase_lm, phase_resnet,
+               phase_serve])
+    for phase in phases:
+        phase()
+        gc.collect()  # the next phase needs the device memory back
+    hvd.shutdown()
+    print(json.dumps({"ok": True, "device": device}))
+
+
+if __name__ == "__main__":
+    main()

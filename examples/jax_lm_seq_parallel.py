@@ -25,6 +25,7 @@ import optax
 import horovod_tpu as hvd
 from horovod_tpu import training
 from horovod_tpu.models.transformer import Transformer, TransformerConfig
+from horovod_tpu.utils.benchmarks import compute_dtype
 
 
 def main():
@@ -55,7 +56,7 @@ def main():
         devs[:args.data * args.seq].reshape(args.data, args.seq),
         ("data", "seq"))
 
-    dtype = (jnp.bfloat16 if devs[0].platform == "tpu" else jnp.float32)
+    dtype = compute_dtype()
     cfg = TransformerConfig(vocab_size=256, num_layers=args.layers,
                             num_heads=4, d_model=args.d_model,
                             d_ff=4 * args.d_model, dtype=dtype,

@@ -40,21 +40,40 @@ _CXX_DIR = os.path.join(os.path.dirname(__file__), os.pardir, "cxx")
 _lib = None
 
 
+def _stale():
+    """True when the library is missing or any source under cxx/ is
+    newer than it. Both the library and cxx/build/ are git-ignored, so a
+    library left in a working tree by an older commit must not be what
+    runs. A tree with no cxx/ (an installed package) ships its library
+    built and is never stale."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    built = os.path.getmtime(_LIB_PATH)
+    for sub in ("Makefile", "src", "include"):
+        top = os.path.join(_CXX_DIR, sub)
+        paths = [top] if os.path.isfile(top) else [
+            os.path.join(d, f) for d, _, fs in os.walk(top) for f in fs]
+        if any(os.path.getmtime(p) > built for p in paths):
+            return True
+    return False
+
+
 def build(force=False):
     """Build libhvdcore.so from cxx/ (the reference's setup.py build step,
-    here a plain make). File-locked: concurrently launched ranks must not
-    run make into the same build dir at once."""
-    if os.path.exists(_LIB_PATH) and not force:
+    here a plain make) when it is missing or older than its sources.
+    File-locked: concurrently launched ranks must not run make into the
+    same build dir at once."""
+    if not (force or _stale()):
         return _LIB_PATH
     import fcntl
     lock_path = os.path.join(os.path.dirname(__file__), ".build.lock")
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
         try:
-            if os.path.exists(_LIB_PATH) and not force:  # built while waiting
-                return _LIB_PATH
-            subprocess.run(["make", "-C", os.path.abspath(_CXX_DIR), "-j"],
-                           check=True, capture_output=True)
+            if force or _stale():  # not built while waiting
+                subprocess.run(
+                    ["make", "-C", os.path.abspath(_CXX_DIR), "-j"],
+                    check=True, capture_output=True)
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)
     return _LIB_PATH
@@ -64,8 +83,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH):
-        build()
+    build()
     lib = ctypes.CDLL(_LIB_PATH)
     lib.hvdc_init.argtypes = [ctypes.c_int, ctypes.c_int, ctypes.c_char_p,
                               ctypes.c_int, ctypes.c_char_p]

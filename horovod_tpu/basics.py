@@ -86,6 +86,7 @@ def init(num_slices=None, devices=None):
         # the bucketed reduce-scatter pipeline compiles but never overlaps
         from horovod_tpu import config as config_lib
         config_lib.apply_xla_flags(cfg)
+        config_lib.apply_compile_cache()
 
         # Multi-process: join the distributed JAX runtime so jax.devices()
         # spans every chip in the job. The coordinator address is provided by

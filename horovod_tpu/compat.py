@@ -1,123 +1,19 @@
-"""Version bridging for the jax API surface this package targets.
+"""The one query this package makes of jax's trace context.
 
-The package is written against the current jax API (``jax.shard_map`` with
-``check_vma``, ``jax.sharding.get_abstract_mesh``). Older runtimes (jax
-0.4.x) spell these ``jax.experimental.shard_map.shard_map(check_rep=...)``
-and have no abstract-mesh query — there the bound named axes are only
-visible through ``jax.core``'s axis-env introspection. This module owns the
-translation in ONE place and, when needed, installs ``jax.shard_map`` so
-user code (and the test suite) written against the new spelling runs
-unchanged on both.
-
-Import-time side effect (installing the attribute on ``jax``) is deliberate:
-``horovod_tpu/__init__`` imports this first, so anything imported after
-``import horovod_tpu`` sees a working ``jax.shard_map`` regardless of the
-runtime's jax version.
+The package is written against the installed jax API and calls it
+directly (``jax.shard_map`` with ``check_vma``, ``jax.typeof``,
+``jax.lax.pcast``, ``jax.lax.axis_size``). What stays here is the lookup
+of the named axes bound in the current trace, which several modules need
+and which reads one ``jax.sharding`` entry point.
 """
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax < 0.6: experimental module, check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, check_rep=None, **kwargs):
-        if check_rep is None:
-            # check_vma's varying-manual-axes type system has no 0.4.x
-            # equivalent: this jax's check_rep rewrite rejects valid
-            # programs the vma checker accepts (e.g. cond branches with
-            # differing replication), so requests for vma checking
-            # degrade to unchecked — semantics are unchanged, only the
-            # soundness check is weaker
-            check_rep = False
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_rep,
-                          **kwargs)
-
-    jax.shard_map = shard_map
-
-
-# True when this jax has the varying-manual-axes (vma) type system —
-# pcast/pvary with their AD transposes. The shims below keep FORWARD
-# semantics on older jax, but code whose gradients rely on the
-# pcast<->psum transpose pair (the 1F1B pipeline composed with a data
-# axis) needs the real thing.
-NATIVE_VMA = hasattr(jax.lax, "pcast")
-
-if not hasattr(jax, "typeof"):  # jax < 0.6
-    def _typeof(x):
-        from jax import core
-        return core.get_aval(x)
-
-    jax.typeof = _typeof
-
-if not hasattr(jax.lax, "pcast"):  # jax < 0.6: no vma type system
-    def _pcast(x, axis_name, *, to):
-        # the varying-manual-axes annotation only exists where shard_map
-        # tracks per-axis replication (check_vma); on older jax the value
-        # is already "varying" by construction — identity is exact
-        del axis_name, to
-        return x
-
-    jax.lax.pcast = _pcast
-
-if not hasattr(jax.lax, "axis_size"):  # jax < 0.4.38
-    def _axis_size(axis_name):
-        # psum of the constant 1 over a named axis is special-cased to
-        # the (static) axis size — the pre-axis_size spelling
-        return jax.lax.psum(1, axis_name)
-
-    jax.lax.axis_size = _axis_size
-
-
-def gspmd_supported():
-    """``(ok, reason)`` — whether this jax can run the GSPMD hot path
-    (``training.make_train_step(spmd=True)`` / ``parallel/gspmd.py``):
-    ``NamedSharding``, ``with_sharding_constraint`` and a ``jax.jit``
-    that takes ``in_shardings``/``out_shardings``/``donate_argnums``.
-    jax 0.4.x ships all three; genuinely older runtimes keep the
-    explicit shard_map pipeline and get the reason string in the error.
-    """
-    import inspect
-
-    try:
-        from jax.sharding import NamedSharding  # noqa: F401
-    except ImportError:
-        return False, ("jax.sharding.NamedSharding is unavailable — "
-                       "this jax predates the GSPMD sharding API")
-    if not hasattr(jax.lax, "with_sharding_constraint"):
-        return False, ("jax.lax.with_sharding_constraint is unavailable "
-                       "— this jax cannot annotate in-program shardings")
-    try:
-        params = inspect.signature(jax.jit).parameters
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        return False, "jax.jit signature cannot be introspected"
-    for kw in ("in_shardings", "out_shardings", "donate_argnums"):
-        if kw not in params:
-            return False, (f"jax.jit lacks {kw}= — this jax cannot "
-                           "compile NamedSharding-annotated steps")
-    return True, None
-
 
 def bound_axis_names():
     """Mesh axis names bound in the current trace (inside ``shard_map`` /
-    any named-axis context); ``()`` at top level. Works on both the
-    abstract-mesh jax API and the 0.4.x axis-env internals."""
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract_mesh is not None:
-        try:
-            abstract_mesh = get_abstract_mesh()
-        # hvd-lint: disable=HVD-EXCEPT -- version-probe shim: failure means the feature is absent
-        except Exception:
-            return ()
-        if abstract_mesh is None or abstract_mesh.empty:
-            return ()
-        return tuple(abstract_mesh.axis_names)
-    try:  # jax 0.4.x
-        from jax import core
-        return tuple(core.unsafe_get_axis_names_DO_NOT_USE())
-    # hvd-lint: disable=HVD-EXCEPT -- version-probe shim: failure means the feature is absent
-    except Exception:
+    any named-axis context); ``()`` at top level."""
+    abstract_mesh = jax.sharding.get_abstract_mesh()
+    if abstract_mesh.empty:
         return ()
+    return tuple(abstract_mesh.axis_names)

@@ -251,13 +251,36 @@ def apply_xla_flags(cfg, env=None):
 def _tpu_backend_already_live():
     """True when a TPU backend is already initialized in this process —
     the point after which LIBTPU_INIT_ARGS edits are silently ignored.
-    Probes only; never initializes a backend itself."""
-    try:
-        from jax._src import xla_bridge
-        if not xla_bridge.backends_are_initialized():
-            return False
-        import jax
-        return any(d.platform == "tpu" for d in jax.devices())
-    # hvd-lint: disable=HVD-EXCEPT -- internal-API probe across jax versions; False is safe
-    except Exception:  # pragma: no cover - internal API drift
+    Probes only; never initializes a backend itself. jax 0.9.0 has no
+    public spelling of the probe; if the internal moves this raises
+    and the warning above is not lost in silence."""
+    import jax
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
         return False
+    return jax.devices()[0].platform == "tpu"
+
+
+# jax's persistent compile cache, when nothing outside placed it: one
+# fixed git-ignored directory in the checkout. The path is part of the
+# cache key's environment, so it never carries a pid, a time or a
+# temporary name.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def apply_compile_cache(env=None):
+    """Place jax's persistent compile cache — runs beside
+    :func:`apply_xla_flags`, before the first backend touch. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the program uses it and sets no
+    other path; where it is not, :data:`DEFAULT_COMPILE_CACHE_DIR`. The
+    variable is exported either way, so processes started from here
+    (hvdrun workers, serve replicas) share the one cache. Returns the
+    directory."""
+    import jax
+    env = os.environ if env is None else env
+    path = env.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_COMPILE_CACHE_DIR
+    env["JAX_COMPILATION_CACHE_DIR"] = path
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

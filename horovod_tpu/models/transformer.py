@@ -44,12 +44,13 @@ class TransformerConfig:
     sequence_axis: Optional[str] = None
     # fused Pallas flash-attention kernel for the local (non-ring) path
     # (ops/flash_attention.py). Requires the default contiguous positions;
-    # falls back to plain XLA attention when shapes don't tile.
+    # gives way to plain XLA attention, with a FlashFallbackWarning,
+    # when shapes don't tile or explicit positions are passed.
     # None (default) = auto: ON when running on TPU with local seq >=
-    # 1024 — the measured crossover on v5e with bf16 operands and
-    # 512x512 blocks (BENCH_NOTES.md round 5: flash fwd+bwd is ~2.4x
-    # dense at seq 2048, ~4x at 1024; a wash at 512). OFF elsewhere
-    # (interpret mode would crawl). Set True/False to force.
+    # 1024 (with bf16 operands and 512x512 blocks the kernel's lead over
+    # dense attention grows with sequence length and is gone by 512; not
+    # measured in this round). OFF elsewhere (interpret mode would
+    # crawl). Set True/False to force.
     flash_attention: Optional[bool] = None
     # Sparse-FFN blocks: every `moe_every`-th block (1-based; 0 = dense
     # everywhere) replaces its MLP with a top-k MoE of `num_experts`
@@ -137,6 +138,14 @@ class Attention(nn.Module):
             # (see TransformerConfig.flash_attention)
             use_flash = (jax.devices()[0].platform == "tpu"
                          and x.shape[1] >= 1024)
+        from horovod_tpu.ops import flash_attention as fa
+        if cfg.flash_attention and not contiguous_positions:
+            # the kernel masks by offset-contiguous positions; arbitrary
+            # user-supplied position arrays must use the dense path
+            fa.warn_fallback(
+                "models.transformer.Attention", q.shape, k.shape[1],
+                "explicit positions were passed and the kernel masks "
+                "by contiguous offset only")
         if cfg.sequence_axis is not None:
             from horovod_tpu.parallel import ring
             if use_flash and contiguous_positions:
@@ -150,9 +159,6 @@ class Attention(nn.Module):
                     causal=cfg.causal, q_positions=positions,
                     kv_positions=positions)
         elif use_flash and contiguous_positions:
-            # the kernel masks by offset-contiguous positions; arbitrary
-            # user-supplied position arrays must use the dense path
-            from horovod_tpu.ops import flash_attention as fa
             out = fa.attention(q, k, v, causal=cfg.causal)
         else:
             out = dense_attention(q, k, v, causal=cfg.causal,

@@ -23,23 +23,20 @@ recomputing P blockwise from (q, k, lse) saved by the forward — so the
 backward, like the forward, never materializes S x S and stays
 O(S * block) in memory (the flash-attention rematerialization policy).
 Kernel matmuls run at the MXU's default precision with fp32
-accumulation, matching XLA's own default on TPU. Falls back
-transparently (``attention`` helper) to the plain-XLA path when shapes
-don't tile; the kernels run anywhere under ``interpret=True``, which is
-how the CPU test suite exercises them.
+accumulation, matching XLA's own default on TPU. The ``attention``
+helper gives way to the plain-XLA path when shapes don't tile and says
+so with a :class:`FlashFallbackWarning`. Off the TPU the kernels run
+under ``interpret=True``, which is how the CPU test suite exercises
+them; in a process whose devices are TPUs they are never interpreted.
 """
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # Mosaic TPU backend; absent on some CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-# hvd-lint: disable=HVD-EXCEPT -- import probe: Mosaic backend absent on CPU-only installs
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 # Measured on v5e (bf16 operands, fwd+bwd, b8 h12 s2048 d64): 512x512
 # blocks run 4x faster than 128x128 — bigger tiles amortize grid/VPU
@@ -48,6 +45,20 @@ except Exception:  # pragma: no cover
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
+
+
+class FlashFallbackWarning(UserWarning):
+    """A caller asked for the flash kernel and got plain-XLA attention.
+    Python's default filter shows each distinct message once, so a
+    caller is told once per name and shape; a run that must not fall
+    back turns this category into an error."""
+
+
+def warn_fallback(caller, q_shape, kv_len, reason):
+    warnings.warn(
+        f"{caller}: flash attention was asked for and plain-XLA "
+        f"attention ran instead for q{tuple(q_shape)} against "
+        f"{kv_len} keys: {reason}", FlashFallbackWarning, stacklevel=3)
 
 
 def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -418,8 +429,6 @@ def kernel_supported(sq, skv, d, block_q=DEFAULT_BLOCK_Q,
                      block_k=DEFAULT_BLOCK_K):
     """True when these shapes tile onto the kernel (callers use this to
     fall back to the plain-XLA path)."""
-    if pltpu is None:
-        return False
     # incremental-decode shapes (q_len == 1 — one new token per sequence
     # against a long cached K/V, the serve/engine.py hot loop) can never
     # tile onto an MXU-floor block: route them to the dense path
@@ -438,11 +447,13 @@ def kernel_supported(sq, skv, d, block_q=DEFAULT_BLOCK_Q,
 def _prep(q, k, v, sm_scale, block_q, block_k, interpret):
     """Shared prologue: defaulting, tiling validation, and the
     [B,S,H,D] -> [BH,S,D] relayout."""
-    if pltpu is None:
-        raise RuntimeError("pallas TPU backend unavailable; use "
-                           "ops.flash_attention.attention (auto-fallback)")
+    on_tpu = jax.devices()[0].platform == "tpu"
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "flash attention kernels are not interpreted in a process "
+            "whose devices are TPUs; drop interpret=True")
     b, sq, h, d = q.shape
     skv = k.shape[1]
     sm_scale = sm_scale if sm_scale is not None else 1.0 / (float(d) ** 0.5)
@@ -536,13 +547,16 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
 
 
 def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0):
-    """flash_attention with automatic fallback to the plain-XLA path
-    when shapes don't tile onto the kernel blocks."""
+    """flash_attention, giving way to the plain-XLA path (with a
+    :class:`FlashFallbackWarning`) when shapes don't tile onto the
+    kernel blocks."""
     b, sq, h, d = q.shape
     skv = k.shape[1]
     if kernel_supported(sq, skv, d):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                kv_offset=kv_offset)
+    warn_fallback("ops.flash_attention.attention", q.shape, skv,
+                  "the shapes do not tile onto the kernel's blocks")
     offsets = jnp.asarray([q_offset, kv_offset], jnp.int32)
 
     def to_bh(x):

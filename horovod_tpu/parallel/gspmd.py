@@ -37,9 +37,7 @@ This module is the plan layer for that path:
 
 ``training.make_train_step(spmd=True)`` is the consumer;
 ``hvd.DistributedOptimizer`` stays the user-facing veneer
-(``hvd_jax.HorovodOptimizer.update_spmd`` routes here). Version gating
-lives in ``compat.gspmd_supported`` — jax builds without
-``NamedSharding``-aware ``jit`` keep the explicit pipeline.
+(``hvd_jax.HorovodOptimizer.update_spmd`` routes here).
 """
 
 import dataclasses

@@ -259,19 +259,6 @@ def pipeline_train_1f1b(block_fn: Callable[[Any, Any], Any], stacked_params,
     ``pipelined_forward`` (here the cross-data psum of the grads is
     explicit rather than an AD transpose).
     """
-    from horovod_tpu import compat
-    composed = ((batch_axis is not None and mesh.shape.get(batch_axis, 1) > 1)
-                or param_specs is not None)
-    if composed and not compat.NATIVE_VMA:
-        # The PP x DP / PP x TP composition's backward relies on the vma
-        # pcast<->psum AD transpose pair; on pre-vma jax the compat shims
-        # keep only forward semantics, and the gradients would be
-        # silently wrong (not an approximation — wrong). Refuse loudly.
-        raise NotImplementedError(
-            "pipeline_train_1f1b composed with a data/model axis needs "
-            "jax's varying-manual-axes (vma) AD semantics; this jax "
-            f"({jax.__version__}) predates them. Run the pure-PP form "
-            "(no batch_axis/param_specs) or upgrade jax.")
     n_stages = mesh.shape[axis_name]
     if n_micro is None:
         n_micro = n_stages

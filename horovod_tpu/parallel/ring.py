@@ -67,16 +67,19 @@ def ring_attention(q, k, v, axis_name, causal=True, q_positions=None,
     ``use_flash`` runs each shard's block attention through the Pallas
     flash kernel (ops/flash_attention.py) and merges blocks by
     log-sum-exp weighting; requires the DEFAULT contiguous positions
-    (pass ``q_positions=None``) and tiling shapes — callers with custom
-    positions keep the jnp path.
+    (pass ``q_positions=None``); shapes that do not tile keep the jnp
+    path with a ``FlashFallbackWarning``, and callers with custom
+    positions keep it by contract.
     """
     if use_flash and q_positions is None and kv_positions is None:
         from horovod_tpu.ops import flash_attention as fa
         _, sq_, _, d_ = q.shape
         if fa.kernel_supported(sq_, sq_, d_):
             return _ring_attention_flash(q, k, v, axis_name, causal)
-        # shapes don't tile onto the kernel: silently use the jnp ring,
-        # same fallback contract as the local attention() helper
+        # shapes don't tile onto the kernel: the jnp ring runs, under
+        # the same contract as the local attention() helper
+        fa.warn_fallback("parallel.ring.ring_attention", q.shape, sq_,
+                         "the shapes do not tile onto the kernel's blocks")
     n = lax.axis_size(axis_name)
     b, sq, h, d = q.shape
     scale = 1.0 / (float(d) ** 0.5)

@@ -1,10 +1,9 @@
 """Bench-trajectory tooling: diff the checked-in ``BENCH_*.json``
 rounds and flag regressions.
 
-The repo accumulates one ``BENCH_r<NN>*.json`` per perf round (nine and
-counting — BENCH_NOTES.md narrates them) but had no tool that reads two
-of them: "did round N regress round N-1" was eyeball work. This module
-loads every round, extracts the comparable series (headline
+The repo accumulates one ``BENCH_r<NN>*.json`` per perf round but had
+no tool that reads two of them: "did round N regress round N-1" was
+eyeball work. This module loads every round, extracts the comparable series (headline
 throughput, ``step_ms_*`` medians, MFU, goodput ratio, serve tokens/s
 and TTFT), and compares each metric's latest value against the
 previous round that reported it — a change worse than
@@ -114,7 +113,7 @@ def find_rounds(paths=None):
     """Resolve ``paths`` (files, dirs, or None for the repo root this
     process runs in) to the sorted list of ``BENCH_*.json`` then
     ``SCALING_*.json`` files — name order IS round order
-    (``BENCH_r01`` … ``BENCH_r09``, ``SCALING_r01`` …). Scaling sweeps
+    (``BENCH_r06`` … ``BENCH_r09``, ``SCALING_r01`` …). Scaling sweeps
     sort after the bench rounds: their metric keys (``scaling.*``)
     never collide with bench keys, so interleaving order between the
     two families is irrelevant to the diff."""

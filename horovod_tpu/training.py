@@ -659,18 +659,12 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
 
 
 def _spmd_gate(tx, what):
-    """Shared validation for the GSPMD builders: version support and the
-    optimizer contract. Returns the resolved wire format (``None`` or a
+    """Shared validation for the GSPMD builders: the optimizer
+    contract. Returns the resolved wire format (``None`` or a
     compressor — the caller compiles it in-place: the shard_map island
     for chunked quantizers, dtype-narrowed constraints for casts)."""
-    from horovod_tpu import compat, hvd_jax
+    from horovod_tpu import hvd_jax
 
-    ok, reason = compat.gspmd_supported()
-    if not ok:
-        raise RuntimeError(
-            f"{what}(spmd=True) needs the NamedSharding jit API: {reason}."
-            " Use the explicit pipeline (spmd=False) on this jax — "
-            "horovod_tpu/compat.py owns this gate.")
     if not isinstance(tx, hvd_jax.HorovodOptimizer):
         raise ValueError(
             f"{what}(spmd=True) needs the optimizer built by "

@@ -1,6 +1,8 @@
 """Lifecycle + identity tests (reference pattern: test/test_common.py and
 the rank/size checks at the top of test/test_tensorflow.py)."""
 
+import os
+
 import pytest
 
 
@@ -77,6 +79,32 @@ def test_config_knobs(monkeypatch):
     assert cfg.cycle_time_ms == 3.5
     assert cfg.hierarchical_allreduce is True
     assert cfg.stall_warning_time == 30.0
+
+
+def test_compile_cache_placed_from_outside_or_in_the_checkout():
+    """config.apply_compile_cache: an outside JAX_COMPILATION_CACHE_DIR
+    wins and is the only path set; unset, the one fixed directory in
+    the checkout — the same one tests/conftest.py names."""
+    import jax
+
+    from horovod_tpu import config
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert config.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+        repo, ".jax_cache")
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        env = {}
+        assert config.apply_compile_cache(env) == \
+            config.DEFAULT_COMPILE_CACHE_DIR
+        assert env == {"JAX_COMPILATION_CACHE_DIR":
+                       config.DEFAULT_COMPILE_CACHE_DIR}
+        env = {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}
+        assert config.apply_compile_cache(env) == "/somewhere/else"
+        assert jax.config.jax_compilation_cache_dir == "/somewhere/else"
+        assert env == {"JAX_COMPILATION_CACHE_DIR": "/somewhere/else"}
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_mpi_threads_supported(hvd):

@@ -1,5 +1,6 @@
 """Unit tests for the shared timing scaffold (utils/benchmarks.py) —
-the measurement discipline every bench path rides (BENCH_NOTES.md)."""
+the measurement discipline every bench path rides
+(docs/PERFORMANCE.md, "How the benchmarks measure")."""
 
 import time
 
@@ -32,8 +33,7 @@ def test_slope_window_measures_per_iteration_cost():
     interleaved windows must recover the per-iteration cost, cancelling
     fixed overhead. A warmup sync first: pending async work left by
     earlier tests in the process must drain OUTSIDE the timed windows
-    (the old single base/full pair let it deflate the slope — the
-    reproducible suite failure, VERDICT r5 Weak #1)."""
+    (a single base/full pair lets it deflate the slope)."""
     benchmarks.sync(jnp.zeros(()))  # warmup: flush pending device work
 
     def step(state):
@@ -76,7 +76,7 @@ def test_slope_window_flags_asymmetric_fixed_cost():
     """A fixed cost that attaches to SOME window lengths only (here: the
     mid-length window) deflates one segment rate and inflates the other;
     the disagreement between the implied per-iteration rates must be
-    flagged — the sample is not a clean slope (VERDICT r5 Weak #1)."""
+    flagged — the sample is not a clean slope."""
     calls = {"n": 0}
 
     def step(state):
@@ -95,7 +95,7 @@ def test_slope_window_flags_asymmetric_fixed_cost():
 
 
 def test_slope_window_sane_after_autotune_in_process(hvd):
-    """Regression for the VERDICT r5 sharpest finding: running the fusion
+    """Regression: running the fusion
     autotuner and then the timing primitive IN THE SAME PROCESS
     under-measured a 10 ms/iter step 4x (dt=0.0127 s for 5 iters) with
     upper_bound=False — autotune warm-up residue drained inside the next

@@ -1,4 +1,4 @@
-"""Checkpoint/resume conventions (VERDICT item 10; reference
+"""Checkpoint/resume conventions (reference
 ``examples/keras_imagenet_resnet50.py:85-103``): rank-0-only writes,
 broadcast resume step, broadcast params/opt_state on restore. The kill
 test crashes a 2-proc run mid-training and verifies the resumed run

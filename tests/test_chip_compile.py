@@ -1,0 +1,94 @@
+"""The main path's kernels compile for the chip, without the chip.
+
+The TPU compiler is installed here and compiles for a device that is
+described and not attached (``/opt/skills/guides/on-chip-measurement``
+section 2). Interpret mode, which the rest of the CPU suite uses, cannot
+see what Mosaic refuses — a slice off the tiling, too much VMEM — so the
+flash kernel's forward, forward+backward and the lse-returning variant
+with the blockwise backward that ring attention composes are compiled
+here with ``interpret=False`` at the head shapes ``chip_smoke.py`` runs.
+Nothing executes; a pass is not a chip run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.experimental.compilation_cache import (  # noqa: E402
+    compilation_cache)
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from horovod_tpu.ops import flash_attention as fa  # noqa: E402
+
+# [B, S, H, D] of the d2048 16-head LM and the d768 12-head LM
+SHAPES = [(8, 2048, 16, 128), (8, 2048, 12, 64)]
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip. The persistent compile cache is off
+    around these compiles: it would store executables no CPU process
+    can read back, and warn on every later run."""
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except (RuntimeError, NotImplementedError) as e:
+        pytest.skip(f"no TPU compiler to describe a v5e topology: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _forward(q, k, v):
+    return fa.flash_attention(q, k, v, interpret=False)
+
+
+def _forward_backward(q, k, v):
+    return jax.grad(
+        lambda q, k, v: jnp.sum(_forward(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _lse_and_blockwise_backward(q, k, v):
+    # what parallel/ring.py runs per rotated K/V block: the lse-returning
+    # forward, then the fused backward against a supplied (lse, delta)
+    out, lse = fa.flash_attention_with_lse(q, k, v, interpret=False)
+    delta = jnp.sum(out.astype(jnp.float32) ** 2, axis=-1)
+    return fa.flash_attention_bwd_block(q, k, v, out, lse, delta,
+                                        interpret=False)
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("fn,kernels", [
+    (_forward, 1), (_forward_backward, 3), (_lse_and_blockwise_backward, 3)],
+    ids=["forward", "forward_backward", "lse_blockwise_backward"])
+def test_flash_kernel_compiles_for_v5e(v5e, shape, fn, kernels):
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+    text = jax.jit(fn).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= kernels, (
+        f"{fn.__name__} at {shape}: expected {kernels} Mosaic kernel(s) "
+        "in the compiled program")
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    """chip_smoke.py on a machine whose jax finds no TPU runs no phase,
+    prints no result and exits non-zero naming the platform it found —
+    the refusal is what keeps a later run from passing without the
+    chip."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    rv = subprocess.run([sys.executable, "chip_smoke.py"], cwd=repo, env=env,
+                        capture_output=True, text=True, timeout=120)
+    assert rv.returncode != 0
+    assert rv.stdout == ""
+    assert "found platform 'cpu'" in rv.stderr
+    assert "no phase was run" in rv.stderr

@@ -1,8 +1,7 @@
 """Driver/task services: HMAC auth + NIC discovery.
 
 Mirrors the reference's service-layer test intent (driver/task
-registration, interface matching, secret checks) with multi-NIC fakes,
-per VERDICT round-1 item 4.
+registration, interface matching, secret checks) with multi-NIC fakes.
 """
 
 import json

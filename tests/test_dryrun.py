@@ -1,6 +1,6 @@
 """The driver-facing dry run must PROVE parity, not just finiteness:
 every parallelism section compares its step against a single-device
-oracle replay (VERDICT r4 #6). These tests pin both directions — a clean
+oracle replay. These tests pin both directions — a clean
 run passes, a deliberately broken sharding fails the parity gate."""
 
 import os

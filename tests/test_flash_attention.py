@@ -174,13 +174,17 @@ def test_attention_fallback_on_odd_shapes():
     assert not fa.kernel_supported(100, 100, 32)
     q = jnp.asarray(rng.standard_normal((1, 100, 2, 32)), jnp.float32)
     k, v = q + 1, q - 1
-    out = fa.attention(q, k, v, causal=True)
+    # the caller asked for flash: it is told, by name and shape
+    with pytest.warns(fa.FlashFallbackWarning,
+                      match=r"attention: .*q\(1, 100, 2, 32\)"):
+        out = fa.attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_oracle(q, k, v)), atol=2e-5)
     # d % 8 != 0 -> jnp path
     assert not fa.kernel_supported(128, 128, 30)
     q2 = jnp.asarray(rng.standard_normal((1, 90, 2, 30)), jnp.float32)
-    out2 = fa.attention(q2, q2, q2, causal=True)
+    with pytest.warns(fa.FlashFallbackWarning):
+        out2 = fa.attention(q2, q2, q2, causal=True)
     np.testing.assert_allclose(np.asarray(out2),
                                np.asarray(_oracle(q2, q2, q2)), atol=2e-5)
     # aligned sub-128 sequences DO take the kernel

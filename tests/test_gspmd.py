@@ -4,7 +4,7 @@ parity with the explicit overlap+ZeRO pipeline (the dryrun 1b4 contract,
 run here as the tier-1 smoke), the compiled-HLO byte accounting, the
 compiled-in-place wire compression (the shard_map island for chunked
 quantizers, dtype-narrowed constraints for casts — ISSUE 17), the
-compat gate — and the tier-1 GUARD that
+optimizer gate — and the tier-1 GUARD that
 keeps the hot path ON the mesh: no new ``pmap(``/``shard_map(`` call
 sites may appear in ``horovod_tpu/`` outside the pinned baseline
 (``compat.py`` and ``parallel/gspmd.py`` excluded as the shim layers)."""
@@ -333,18 +333,29 @@ def test_spmd_step_warns_on_late_wire_install(hvd):
         basics._state.config.wire_dtype = old
 
 
-def test_spmd_gate_reports_reason(hvd, monkeypatch):
-    monkeypatch.setattr(compat, "gspmd_supported",
-                        lambda: (False, "synthetic: no NamedSharding"))
+def test_spmd_gate_rejects_plain_optax_optimizer(hvd):
+    """The GSPMD step routes its gradient reduction through the plan the
+    DistributedOptimizer carries: a bare optax transform has none, and
+    the gate must say which optimizer it needs."""
     model = MLP(features=(4,))
-    tx = hvd_api.DistributedOptimizer(optax.sgd(0.1))
-    with pytest.raises(RuntimeError, match="synthetic: no NamedSharding"):
-        training.make_train_step(model, tx, spmd=True)
+    with pytest.raises(ValueError, match="hvd.DistributedOptimizer"):
+        training.make_train_step(model, optax.sgd(0.1), spmd=True)
 
 
-def test_gspmd_supported_on_this_jax():
-    ok, reason = compat.gspmd_supported()
-    assert ok, reason
+def test_bound_axis_names_tracks_the_trace_context(hvd):
+    """compat.bound_axis_names is how collectives tell a named-axis
+    trace from top level: empty outside, the mesh's axes inside
+    shard_map."""
+    assert compat.bound_axis_names() == ()
+    seen = []
+
+    def body(x):
+        seen.append(compat.bound_axis_names())
+        return x
+
+    jax.shard_map(body, mesh=hvd.mesh(), in_specs=P("data"),
+                  out_specs=P("data"))(jnp.zeros((8,)))
+    assert seen == [("data",)]
 
 
 # ---- compiled-HLO byte accounting -------------------------------------

@@ -156,7 +156,7 @@ def test_lm_loss_exact_under_seq_parallel(hvd, n_devices):
     Uses a positionwise LM (logits depend only on the local token) so the
     only cross-shard coupling is the loss stitching itself: shard i's final
     target must be shard i+1's first token, and normalization must be by
-    the global target count (VERDICT r1 item 8)."""
+    the global target count."""
     import flax.linen as nn
 
     ndata = 2

@@ -239,3 +239,32 @@ def test_cxx_unit_tests():
                         capture_output=True, text=True)
     assert rv.returncode == 0, rv.stdout + rv.stderr
     assert "ALL CXX UNIT TESTS PASSED" in rv.stdout
+
+
+def test_library_is_stale_when_a_source_is_newer(tmp_path, monkeypatch):
+    """horovod_tpu/lib/ and cxx/build/ are git-ignored, so a library left
+    in a working tree by an older commit must not be what runs: the load
+    path rebuilds when anything under cxx/ is newer than the library (and
+    when there is no library). A tree without cxx/ — an installed
+    package — ships its library built and is never stale."""
+    from horovod_tpu import _core
+
+    cxx = tmp_path / "cxx"
+    (cxx / "src").mkdir(parents=True)
+    (cxx / "include" / "hvd").mkdir(parents=True)
+    lib = tmp_path / "libhvdcore.so"
+    monkeypatch.setattr(_core, "_CXX_DIR", str(cxx))
+    monkeypatch.setattr(_core, "_LIB_PATH", str(lib))
+    for name in ("Makefile", "src/a.cc", "include/hvd/a.h"):
+        (cxx / name).write_text("")
+        os.utime(cxx / name, (1000, 1000))
+    assert _core._stale()  # no library yet
+    lib.write_text("")
+    os.utime(lib, (2000, 2000))
+    assert not _core._stale()
+    for name in ("Makefile", "src/a.cc", "include/hvd/a.h"):
+        os.utime(cxx / name, (3000, 3000))
+        assert _core._stale(), name
+        os.utime(cxx / name, (1000, 1000))
+    monkeypatch.setattr(_core, "_CXX_DIR", str(tmp_path / "absent"))
+    assert not _core._stale()

@@ -13,7 +13,6 @@ import pytest
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu import compat
 from horovod_tpu.parallel.pipeline import (_schedule_1f1b,
                                            pipeline_train_1f1b,
                                            pipelined_forward, stack_params)
@@ -215,9 +214,6 @@ def test_1f1b_input_grad_matches(rng):
                                rtol=2e-4, atol=1e-5)
 
 
-@pytest.mark.skipif(not compat.NATIVE_VMA, reason=(
-    "1F1B composed with a data axis relies on the vma pcast<->psum AD "
-    "transpose pair; pre-vma jax has no faithful equivalent"))
 def test_1f1b_composes_with_data_parallel(rng):
     block_fn, stacked, x = _setup(rng, n_layers=4, batch=16)
     mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(2, 4),
@@ -253,9 +249,6 @@ def _tp_setup(rng, d=8, ff=16, n_layers=4):
     return stack_params(trees), x, specs
 
 
-@pytest.mark.skipif(not compat.NATIVE_VMA, reason=(
-    "1F1B composed with a data axis relies on the vma pcast<->psum AD "
-    "transpose pair; pre-vma jax has no faithful equivalent"))
 def test_1f1b_composes_with_tensor_and_data_parallel(rng):
     """PP x TP x DP on a (data, stage, model) mesh: loss and grads equal
     the single-device dense oracle."""

@@ -1,4 +1,4 @@
-"""Real-pyspark smoke (VERDICT r3 #8): ``run_on_cluster`` through the
+"""Real-pyspark smoke: ``run_on_cluster`` through the
 REAL SparkBackend against a ``local[2]`` SparkContext — the same shape
 the reference proves with a local SparkSession
 (``/root/reference/horovod/spark/__init__.py:101-236``,
