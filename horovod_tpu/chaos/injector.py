@@ -86,8 +86,11 @@ class ChaosMonkey:
             t.join(timeout=5.0)
 
     def done(self):
-        return max(self._attempted, len(self.injections_done)) \
-            >= len(self.plan.injections) or self._stop.is_set()
+        # by injections attempted, never by done-entries: a host kind
+        # appends one entry per felled rank and would claim the plan
+        # complete with injections still pending
+        return self._attempted >= len(self.plan.injections) \
+            or self._stop.is_set()
 
     # -- scheduler ---------------------------------------------------------
 

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from horovod_tpu.ops import collective
 from horovod_tpu.ops.collective import Adasum, Average, Sum
 from horovod_tpu.ops.fusion import fused_allreduce
+from horovod_tpu.telemetry import scopes
 
 
 def DistributedGradientTransform(op=Average, axes=None, compression=None,
@@ -50,6 +51,19 @@ def DistributedGradientTransform(op=Average, axes=None, compression=None,
         return reduced, state
 
     return optax.GradientTransformation(init_fn, update_fn)
+
+
+def _scoped_optimizer(inner):
+    """``inner`` with its update traced under ``hvd_optimizer``. ``init`` is
+    the inner's own, so the state tree (and every checkpoint of it) is
+    what it was."""
+    import optax
+
+    def update_fn(updates, state, params=None):
+        with scopes.device(scopes.OPTIMIZER):
+            return inner.update(updates, state, params)
+
+    return optax.GradientTransformation(inner.init, update_fn)
 
 
 class HorovodOptimizer:
@@ -166,7 +180,7 @@ class HorovodOptimizer:
                     op=self.op, axes=self.axes, compression=wire,
                     threshold_bytes=self.threshold_bytes,
                     hierarchical=self.hierarchical),
-                self.inner,
+                _scoped_optimizer(self.inner),
             )
             if self.backward_passes_per_step > 1:
                 chained = optax.MultiSteps(
@@ -204,8 +218,9 @@ class HorovodOptimizer:
         if self.sharded_update or self.backward_passes_per_step > 1:
             raise ValueError("update_preaveraged is the plain-optimizer "
                              "tail of the overlap pipeline")
-        inner_updates, inner_state = self.inner.update(grads, state[1],
-                                                       params)
+        with scopes.device(scopes.OPTIMIZER):
+            inner_updates, inner_state = self.inner.update(grads, state[1],
+                                                           params)
         return inner_updates, (state[0], inner_state)
 
     def update_spmd(self, grads, state, params, plan, wire=None,

@@ -18,6 +18,7 @@ executable.
 """
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from horovod_tpu.ops import collective
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,6 +185,18 @@ def _pack_padded(schedule, idx, leaves):
     return flat
 
 
+def _in_bucket_scope(fn):
+    """Trace ``fn(schedule, idx, ...)`` under ``hvd_exchange/bucket<idx>``:
+    the overlap and ZeRO pipelines reach the bucket functions directly,
+    so each names its own work on the device."""
+    @functools.wraps(fn)
+    def scoped(schedule, idx, *args, **kwargs):
+        with scopes.bucket(idx):
+            return fn(schedule, idx, *args, **kwargs)
+    return scoped
+
+
+@_in_bucket_scope
 def reduce_scatter_bucket(schedule, idx, leaves, op=collective.Average):
     """Pack bucket ``idx`` from ``leaves``, pad to the schedule's padded
     size, and reduce-scatter it over the schedule's scatter order. Returns
@@ -200,6 +214,7 @@ def reduce_scatter_bucket(schedule, idx, leaves, op=collective.Average):
     return out
 
 
+@_in_bucket_scope
 def reduce_scatter_bucket_compressed(schedule, idx, leaves, wire,
                                      op=collective.Average, residual=None):
     """Wire-compressed :func:`reduce_scatter_bucket`: the interconnect
@@ -296,6 +311,7 @@ def reduce_scatter_bucket_compressed(schedule, idx, leaves, wire,
     return out, new_residual
 
 
+@_in_bucket_scope
 def all_gather_bucket(schedule, idx, shard):
     """Inverse of :func:`reduce_scatter_bucket`: all-gather the per-rank
     shards of bucket ``idx`` back into the full (padded) flat bucket.
@@ -313,6 +329,7 @@ def all_gather_bucket(schedule, idx, shard):
     return out
 
 
+@_in_bucket_scope
 def all_gather_bucket_compressed(schedule, idx, shard_vals, wire,
                                  residual=None):
     """Wire-compressed :func:`all_gather_bucket`: each rank narrows ITS
@@ -382,6 +399,7 @@ def all_gather_bucket_compressed(schedule, idx, shard_vals, wire,
     return flat, new_residual
 
 
+@_in_bucket_scope
 def unpack_bucket(schedule, idx, flat, leaves):
     """Scatter the flat bucket back into leaf positions: returns
     ``{leaf_index: array}`` with each array cast to its leaf's dtype
@@ -474,50 +492,54 @@ def fused_allreduce(tree, op=collective.Average, axes=None,
                 stacklevel=2)
 
     new_leaves = [None] * len(leaves)
-    for bucket in buckets:
-        if chunked and jnp.issubdtype(bucket.dtype, jnp.floating):
-            size = sum(bucket.sizes)
-            sched1 = BucketSchedule(
-                buckets=(bucket,), padded_sizes=(size + (-size) % world,),
-                world=world, axes=axes)
-            shard, _ = reduce_scatter_bucket_compressed(
-                sched1, 0, leaves, compression, op=op)
-            flat, _ = all_gather_bucket_compressed(sched1, 0, shard,
-                                                   compression)
+    for n, bucket in enumerate(buckets):
+        # pack, collective, unpack and an average's division, by bucket
+        # (the chunked route's bucket functions nest their own name for
+        # their one-bucket schedule inside it)
+        with scopes.bucket(n):
+            if chunked and jnp.issubdtype(bucket.dtype, jnp.floating):
+                size = sum(bucket.sizes)
+                sched1 = BucketSchedule(
+                    buckets=(bucket,), padded_sizes=(size + (-size) % world,),
+                    world=world, axes=axes)
+                shard, _ = reduce_scatter_bucket_compressed(
+                    sched1, 0, leaves, compression, op=op)
+                flat, _ = all_gather_bucket_compressed(sched1, 0, shard,
+                                                       compression)
+                for i, arr in _unpack(bucket, flat).items():
+                    new_leaves[i] = arr.astype(jnp.asarray(leaves[i]).dtype)
+                continue
+            flat = _pack(bucket, leaves)
+            logical = flat.shape[0] * flat.dtype.itemsize
+            if compression is not None:
+                flat, ctx = compression.compress(flat)
+            # the RS->AR->AG hierarchy only exists for sum/average; every
+            # other op falls through to collective.allreduce, which computes
+            # Min/Max flat and already runs Adasum's OWN 2-level composite
+            # on a multi-axis mesh (ops/adasum.py) — one dispatch copy
+            if (hierarchical and op in (collective.Sum, collective.Average)
+                    and DCN_AXIS in axes and len(axes) > 1):
+                from horovod_tpu import telemetry
+
+                # hierarchical_allreduce composes raw lax collectives that
+                # record nothing themselves — account the dispatch here so a
+                # cast-compressed payload keeps its wire-vs-logical
+                # attribution on this path too
+                telemetry.record_collective(
+                    "hier_allreduce", flat.shape[0] * flat.dtype.itemsize,
+                    logical_nbytes=logical)
+                ici_axes = tuple(a for a in axes if a != DCN_AXIS)
+                flat = hier_lib.hierarchical_allreduce(
+                    flat, ici_axes=ici_axes, dcn_axis=DCN_AXIS, op=op)
+            else:
+                flat = collective.allreduce(
+                    flat, op=op, axes=axes,
+                    logical_nbytes=(logical if compression is not None
+                                    else None))
+            if compression is not None:
+                flat = compression.decompress(flat, ctx)
             for i, arr in _unpack(bucket, flat).items():
                 new_leaves[i] = arr.astype(jnp.asarray(leaves[i]).dtype)
-            continue
-        flat = _pack(bucket, leaves)
-        logical = flat.shape[0] * flat.dtype.itemsize
-        if compression is not None:
-            flat, ctx = compression.compress(flat)
-        # the RS->AR->AG hierarchy only exists for sum/average; every
-        # other op falls through to collective.allreduce, which computes
-        # Min/Max flat and already runs Adasum's OWN 2-level composite
-        # on a multi-axis mesh (ops/adasum.py) — one dispatch copy
-        if (hierarchical and op in (collective.Sum, collective.Average)
-                and DCN_AXIS in axes and len(axes) > 1):
-            from horovod_tpu import telemetry
-
-            # hierarchical_allreduce composes raw lax collectives that
-            # record nothing themselves — account the dispatch here so a
-            # cast-compressed payload keeps its wire-vs-logical
-            # attribution on this path too
-            telemetry.record_collective(
-                "hier_allreduce", flat.shape[0] * flat.dtype.itemsize,
-                logical_nbytes=logical)
-            ici_axes = tuple(a for a in axes if a != DCN_AXIS)
-            flat = hier_lib.hierarchical_allreduce(
-                flat, ici_axes=ici_axes, dcn_axis=DCN_AXIS, op=op)
-        else:
-            flat = collective.allreduce(
-                flat, op=op, axes=axes,
-                logical_nbytes=(logical if compression is not None
-                                else None))
-        if compression is not None:
-            flat = compression.decompress(flat, ctx)
-        for i, arr in _unpack(bucket, flat).items():
-            new_leaves[i] = arr.astype(jnp.asarray(leaves[i]).dtype)
     return jax.tree_util.tree_unflatten(treedef, new_leaves)
 
 

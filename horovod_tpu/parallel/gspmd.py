@@ -49,6 +49,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -258,7 +259,9 @@ def apply_shards_spmd(tx, grads, zstate, params, plan, wire=None,
             grad_rows[f"b{i}"] = constrain(rows, plan, row_spec)
         param_rows[f"b{i}"] = constrain(
             zero_lib.bucket_rows(schedule, i, leaves), plan, row_spec)
-    update_rows, new_inner = tx.update(grad_rows, zstate.inner, param_rows)
+    with scopes.device(scopes.OPTIMIZER):
+        update_rows, new_inner = tx.update(grad_rows, zstate.inner,
+                                           param_rows)
 
     new_residuals = list(ag_residuals) if ag_residuals is not None else None
     new_leaves = [None] * len(leaves)

@@ -36,6 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import collective, fusion
 from horovod_tpu.ops.reduction import Average, Sum
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -208,8 +209,10 @@ def apply_shards(tx, grad_rows, zstate, params, wire=None,
     new_ag_residuals)``."""
     schedule = zstate.plan.schedule
     leaves, treedef = jax.tree_util.tree_flatten(params)
-    param_rows = _local_param_rows(schedule, leaves)
-    update_rows, new_inner = tx.update(grad_rows, zstate.inner, param_rows)
+    with scopes.device(scopes.OPTIMIZER):
+        param_rows = _local_param_rows(schedule, leaves)
+        update_rows, new_inner = tx.update(grad_rows, zstate.inner,
+                                           param_rows)
 
     new_residuals = list(ag_residuals) if ag_residuals is not None else None
     new_leaves = [None] * len(leaves)

@@ -1,0 +1,52 @@
+"""The names a step carries from inside, on the profiler's own two
+instruments and nothing else.
+
+Device work: ``jax.named_scope`` writes its name into the ``op_name`` of
+every HLO instruction traced under it, which is what the device trace and
+``compiled.as_text()`` already carry (``jit(hvd_lm_train_step)/shard_map/
+hvd_exchange/bucket3/psum``). Compile-time metadata only: the optimised
+program is the same with or without it (tests/test_scopes.py).
+
+Host work: ``jax.profiler.StepTraceAnnotation`` / ``TraceAnnotation`` land
+on ``/host:CPU`` of the same ``.xplane.pb`` as the device events, on their
+clock. With no trace running each costs a TraceMe activity check.
+
+Both are always on: no knob, no registry metric, no exporter. The names
+are the contract with ``benchmark/harness/phases.py``. A caller opens a
+scope as ``scopes.device(scopes.LOSS)``, through the module, so that a test
+can put a null context in its place.
+"""
+
+import jax
+
+# device scopes
+EXCHANGE = "hvd_exchange"    # pack, pad, compress, collective, unpack, divide
+OPTIMIZER = "hvd_optimizer"  # the inner transform's update, apply_updates
+LOSS = "hvd_loss"            # the loss after the model's last layer
+# host spans
+STEP = "hvd_step"      # one whole step(...) call; carries step_num
+PLACE = "hvd_place"    # device_put of every leaf onto its sharding
+LAUNCH = "hvd_launch"  # the call of the jitted / compiled step
+
+
+def device(name):
+    """Everything traced inside is ``.../<name>/...`` on the device."""
+    return jax.named_scope(name)
+
+
+def bucket(idx):
+    """One bucket of the exchange's schedule: ``hvd_exchange/bucket<idx>``
+    (both levels in one name: the bucket functions of ``ops/fusion.py`` are
+    reached from several pipelines, none of which opens the outer one)."""
+    return device(f"{EXCHANGE}/bucket{idx}")
+
+
+def host(name):
+    """A host span inside the step's ``hvd_step``."""
+    return jax.profiler.TraceAnnotation(name)
+
+
+def step(n):
+    """The host span of step ``n``: what the profiler's step view groups
+    by, and the number a step's spans share."""
+    return jax.profiler.StepTraceAnnotation(STEP, step_num=n)

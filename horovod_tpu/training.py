@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.ops import collective
 from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass
@@ -305,10 +306,14 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
                 logits, mutated = model.apply(
                     variables, inputs, train=True, mutable=["batch_stats"],
                     rngs={"dropout": dropout_rng})
-                return loss_fn(logits, labels), mutated["batch_stats"]
+                with scopes.device(scopes.LOSS):
+                    loss = loss_fn(logits, labels)
+                return loss, mutated["batch_stats"]
             logits = model.apply(variables, inputs, train=True,
                                  rngs={"dropout": dropout_rng})
-            return loss_fn(logits, labels), {}
+            with scopes.device(scopes.LOSS):
+                loss = loss_fn(logits, labels)
+            return loss, {}
 
         return jax.value_and_grad(compute_loss, has_aux=True)(state.params)
 
@@ -445,13 +450,16 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
             updates, opt_state = tx.update(grads, state.opt_state,
                                            state.params)
 
-        params = optax.apply_updates(state.params, updates)
+        with scopes.device(scopes.OPTIMIZER):
+            params = optax.apply_updates(state.params, updates)
         if stats:
             stats = jax.tree_util.tree_map(
                 lambda x: collective.allreduce(x, op=collective.Average,
                                                axes=data_axes), stats)
-        loss = collective.allreduce(loss_sum * inv_k,
-                                    op=collective.Average, axes=data_axes)
+        with scopes.device(scopes.LOSS):
+            loss = collective.allreduce(loss_sum * inv_k,
+                                        op=collective.Average,
+                                        axes=data_axes)
         new_state = TrainState(params=params, opt_state=opt_state,
                                batch_stats=stats, step=state.step + 1)
         new_wire = {"rs": [r[None] for r in rs_res],
@@ -462,7 +470,7 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
 
     wire_spec = P(tuple(reduce_axes))
 
-    def outer(state, wire_state, inputs, labels):
+    def hvd_train_step(state, wire_state, inputs, labels):
         specs = state_specs(state)
         wspecs = jax.tree_util.tree_map(lambda _: wire_spec, wire_state)
         out_specs = ((specs, wspecs, P(), P()) if tele_on
@@ -477,7 +485,10 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
     # wire_state is an EMPTY pytree unless error feedback is on, so the
     # extra jit argument contributes zero buffers and the compiled
     # program stays byte-identical to the uncompressed build.
-    jitted = jax.jit(outer, donate_argnums=(0, 1) if donate else ())
+    # the function's name is the compiled module's (jit_hvd_train_step)
+    # and, unlike a scope, part of the persistent compile cache's key
+    jitted = jax.jit(hvd_train_step,
+                     donate_argnums=(0, 1) if donate else ())
     place_data = _placer(mesh, P(data_axes))
 
     def place_state(state):
@@ -570,26 +581,29 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
             # recorder installed these are a None check each, and they
             # never touch the traced computation — the compiled program
             # stays byte-identical either way, tests/test_diag.py)
-            if inputs is None:
-                inputs, labels = _loader_batch()
             n = _step_no[0]
-            _step_no[0] = n + 1
-            _check_wire_drift()
-            _flightrec.step_begin(n)
-            try:
-                new_state, new_wire, loss = jitted(
-                    place_state(state), _wire_state(state),
-                    place_data(inputs), place_data(labels))
-                _wire_holder[0] = new_wire
-            except BaseException:
-                # the residuals were donated into the failed dispatch and
-                # may already be invalidated — drop them so the retry
-                # path (elastic rollback) rebuilds zeros instead of
-                # dying on deleted arrays forever
-                _wire_holder[0] = None
-                raise
-            _flightrec.step_end(n)
-            _goodput().settle_step()
+            with scopes.step(n):
+                if inputs is None:
+                    inputs, labels = _loader_batch()
+                _step_no[0] = n + 1
+                _check_wire_drift()
+                _flightrec.step_begin(n)
+                try:
+                    with scopes.host(scopes.PLACE):
+                        placed = (place_state(state), _wire_state(state),
+                                  place_data(inputs), place_data(labels))
+                    with scopes.host(scopes.LAUNCH):
+                        new_state, new_wire, loss = jitted(*placed)
+                    _wire_holder[0] = new_wire
+                except BaseException:
+                    # the residuals were donated into the failed dispatch
+                    # and may already be invalidated — drop them so the
+                    # retry path (elastic rollback) rebuilds zeros instead
+                    # of dying on deleted arrays forever
+                    _wire_holder[0] = None
+                    raise
+                _flightrec.step_end(n)
+                _goodput().settle_step()
             return new_state, loss
     else:
         from horovod_tpu import basics as _basics
@@ -599,44 +613,47 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
         first_trace = [True]
 
         def step(state, inputs=None, labels=None):
-            if inputs is None:
-                inputs, labels = _loader_batch()
             step_no = int(instruments.steps.value)
-            _check_wire_drift()
-            _flightrec.step_begin(step_no)
-            tl = _basics._state.timeline
-            flow = None
-            if tl is not None and first_trace[0]:
-                # the first call traces: open an enclosing slice + flow
-                # on the marker tid so the bucket markers emitted during
-                # tracing link back to this dispatch (ops/fusion reads
-                # _step_flow_id; flows need a B/E slice on their tid to
-                # bind in Perfetto's legacy-JSON importer)
-                tl.start_activity("marker", "step_trace_dispatch")
-                flow = tl.flow_start("step_dispatch")
-                tl._step_flow_id = flow
-            t0 = _time.perf_counter()
-            try:
-                new_state, new_wire, loss, gnorm = jitted(
-                    place_state(state), _wire_state(state),
-                    place_data(inputs), place_data(labels))
-                _wire_holder[0] = new_wire
-            except BaseException:
-                _wire_holder[0] = None  # donated into the failed dispatch
-                raise
-            finally:
-                if flow is not None:
-                    first_trace[0] = False
-                    tl._step_flow_id = None
-                    tl.flow_end("step_dispatch", flow)
-                    tl.end_activity("marker")
-            _flightrec.step_end(step_no)
-            _goodput().settle_step()
-            instruments.record_step(
-                batch=int(inputs.shape[0]),
-                dispatch_s=_time.perf_counter() - t0,
-                loss=loss, grad_norm=gnorm, timeline=tl,
-                step_no=instruments.steps.value)
+            with scopes.step(step_no):
+                if inputs is None:
+                    inputs, labels = _loader_batch()
+                _check_wire_drift()
+                _flightrec.step_begin(step_no)
+                tl = _basics._state.timeline
+                flow = None
+                if tl is not None and first_trace[0]:
+                    # the first call traces: open an enclosing slice + flow
+                    # on the marker tid so the bucket markers emitted during
+                    # tracing link back to this dispatch (ops/fusion reads
+                    # _step_flow_id; flows need a B/E slice on their tid to
+                    # bind in Perfetto's legacy-JSON importer)
+                    tl.start_activity("marker", "step_trace_dispatch")
+                    flow = tl.flow_start("step_dispatch")
+                    tl._step_flow_id = flow
+                t0 = _time.perf_counter()
+                try:
+                    with scopes.host(scopes.PLACE):
+                        placed = (place_state(state), _wire_state(state),
+                                  place_data(inputs), place_data(labels))
+                    with scopes.host(scopes.LAUNCH):
+                        new_state, new_wire, loss, gnorm = jitted(*placed)
+                    _wire_holder[0] = new_wire
+                except BaseException:
+                    _wire_holder[0] = None  # donated into the failed dispatch
+                    raise
+                finally:
+                    if flow is not None:
+                        first_trace[0] = False
+                        tl._step_flow_id = None
+                        tl.flow_end("step_dispatch", flow)
+                        tl.end_activity("marker")
+                _flightrec.step_end(step_no)
+                _goodput().settle_step()
+                instruments.record_step(
+                    batch=int(inputs.shape[0]),
+                    dispatch_s=_time.perf_counter() - t0,
+                    loss=loss, grad_norm=gnorm, timeline=tl,
+                    step_no=instruments.steps.value)
             return new_state, loss
 
         step.instruments = instruments
@@ -889,10 +906,14 @@ def _make_spmd_train_step(model, tx, mesh=None,
                     logits, mutated = model.apply(
                         variables, inputs, train=True,
                         mutable=["batch_stats"], rngs={"dropout": rng})
-                    return loss_fn(logits, labels), mutated["batch_stats"]
+                    with scopes.device(scopes.LOSS):
+                        loss = loss_fn(logits, labels)
+                    return loss, mutated["batch_stats"]
                 logits = model.apply(variables, inputs, train=True,
                                      rngs={"dropout": rng})
-                return loss_fn(logits, labels), {}
+                with scopes.device(scopes.LOSS):
+                    loss = loss_fn(logits, labels)
+                return loss, {}
 
             (loss, stats), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(state.params)
@@ -950,13 +971,15 @@ def _make_spmd_train_step(model, tx, mesh=None,
                                                           new_leaves)
                 updates, opt_state = tx.update_preaveraged(
                     grads_full, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with scopes.device(scopes.OPTIMIZER):
+                params = optax.apply_updates(state.params, updates)
             if stats:
                 stats = jax.tree_util.tree_map(
                     lambda x: collective.allreduce(
                         x, op=collective.Average, axes=data_axes), stats)
-            loss = collective.allreduce(loss, op=collective.Average,
-                                        axes=data_axes)
+            with scopes.device(scopes.LOSS):
+                loss = collective.allreduce(loss, op=collective.Average,
+                                            axes=data_axes)
             new_state = TrainState(params=params, opt_state=opt_state,
                                    batch_stats=stats,
                                    step=state.step + 1)
@@ -993,10 +1016,14 @@ def _make_spmd_train_step(model, tx, mesh=None,
                     logits, mutated = model.apply(
                         variables, inputs, train=True,
                         mutable=["batch_stats"], rngs={"dropout": rng})
-                    return loss_fn(logits, labels), mutated["batch_stats"]
+                    with scopes.device(scopes.LOSS):
+                        loss = loss_fn(logits, labels)
+                    return loss, mutated["batch_stats"]
                 logits = model.apply(variables, inputs, train=True,
                                      rngs={"dropout": rng})
-                return loss_fn(logits, labels), {}
+                with scopes.device(scopes.LOSS):
+                    loss = loss_fn(logits, labels)
+                return loss, {}
 
             (loss, stats), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(state.params)
@@ -1034,7 +1061,8 @@ def _make_spmd_train_step(model, tx, mesh=None,
                 updates, opt_state = tx.update_spmd(
                     grads, state.opt_state, state.params, plan)
                 new_wire = {"rs": [], "ag": []}
-            params = optax.apply_updates(state.params, updates)
+            with scopes.device(scopes.OPTIMIZER):
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state,
                                    batch_stats=stats,
                                    step=state.step + 1)
@@ -1154,48 +1182,51 @@ def _make_spmd_train_step(model, tx, mesh=None,
     _step_no = [0]
 
     def step(state, inputs=None, labels=None):
-        if inputs is None:
-            inputs, labels = _loader_batch()
         n = _step_no[0]
-        _step_no[0] = n + 1
-        _flightrec.step_begin(n)
-        if use_ef:
-            placed = (place_state(state), _wire_state(state),
-                      place_data(inputs), place_data(labels))
-        else:
-            placed = (place_state(state), place_data(inputs),
-                      place_data(labels))
-        _check_wire_drift()
-        ex = prog.executable(placed)  # one compile per shape signature
-        step.jitted = prog.jitted
-        step.compiled_collectives = prog.compiled_collectives
-        step.compiled_axis_collectives = prog.compiled_axis_collectives
-        t0 = _time.perf_counter()
-        try:
-            outs = ex(*placed)
-        except BaseException:
-            # the residuals were donated into the failed dispatch —
-            # drop them so a retry rebuilds zeros instead of dying on
-            # deleted arrays
-            _wire_holder[0] = None
-            raise
-        if use_ef:
-            new_state, rest = outs[0], outs[2:]
-            _wire_holder[0] = outs[1]
-        else:
-            new_state, rest = outs[0], outs[1:]
-        loss = rest[0]
-        gnorm = rest[1] if tele_on else None
-        _flightrec.step_end(n)
-        ledger = _ledger_lib.get_ledger()
-        ledger.note_compiled_path()
-        ledger.settle_step()
-        if instruments is not None:
-            instruments.record_step(
-                batch=int(inputs.shape[0]),
-                dispatch_s=_time.perf_counter() - t0,
-                loss=loss, grad_norm=gnorm,
-                step_no=instruments.steps.value)
+        with scopes.step(n):
+            if inputs is None:
+                inputs, labels = _loader_batch()
+            _step_no[0] = n + 1
+            _flightrec.step_begin(n)
+            with scopes.host(scopes.PLACE):
+                if use_ef:
+                    placed = (place_state(state), _wire_state(state),
+                              place_data(inputs), place_data(labels))
+                else:
+                    placed = (place_state(state), place_data(inputs),
+                              place_data(labels))
+            _check_wire_drift()
+            ex = prog.executable(placed)  # one compile per shape signature
+            step.jitted = prog.jitted
+            step.compiled_collectives = prog.compiled_collectives
+            step.compiled_axis_collectives = prog.compiled_axis_collectives
+            t0 = _time.perf_counter()
+            try:
+                with scopes.host(scopes.LAUNCH):
+                    outs = ex(*placed)
+            except BaseException:
+                # the residuals were donated into the failed dispatch —
+                # drop them so a retry rebuilds zeros instead of dying on
+                # deleted arrays
+                _wire_holder[0] = None
+                raise
+            if use_ef:
+                new_state, rest = outs[0], outs[2:]
+                _wire_holder[0] = outs[1]
+            else:
+                new_state, rest = outs[0], outs[1:]
+            loss = rest[0]
+            gnorm = rest[1] if tele_on else None
+            _flightrec.step_end(n)
+            ledger = _ledger_lib.get_ledger()
+            ledger.note_compiled_path()
+            ledger.settle_step()
+            if instruments is not None:
+                instruments.record_step(
+                    batch=int(inputs.shape[0]),
+                    dispatch_s=_time.perf_counter() - t0,
+                    loss=loss, grad_norm=gnorm,
+                    step_no=instruments.steps.value)
         return new_state, loss
 
     def xray(state, inputs=None, labels=None, k=3, profile_dir=None):
@@ -1273,11 +1304,12 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
         logits_t = (logits[:, :-1]
                     if targets.shape[1] == logits.shape[1] - 1
                     else logits)
-        logp = jax.nn.log_softmax(logits_t.astype(jnp.float32),
-                                  axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None],
-                                 axis=-1)[..., 0]
-        return -jnp.mean(ll)
+        with scopes.device(scopes.LOSS):
+            logp = jax.nn.log_softmax(logits_t.astype(jnp.float32),
+                                      axis=-1)
+            ll = jnp.take_along_axis(logp, targets[..., None],
+                                     axis=-1)[..., 0]
+            return -jnp.mean(ll)
 
     if chunked:
         def local_step(state, tokens):
@@ -1323,9 +1355,11 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
                                                           new_leaves)
                 updates, opt_state = tx.update_preaveraged(
                     grads_full, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            loss = collective.allreduce(loss, op=collective.Average,
-                                        axes=(batch_axis,))
+            with scopes.device(scopes.OPTIMIZER):
+                params = optax.apply_updates(state.params, updates)
+            with scopes.device(scopes.LOSS):
+                loss = collective.allreduce(loss, op=collective.Average,
+                                            axes=(batch_axis,))
             new_state = TrainState(params=params, opt_state=opt_state,
                                    batch_stats=state.batch_stats,
                                    step=state.step + 1)
@@ -1358,7 +1392,8 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
             else:
                 updates, opt_state = tx.update_spmd(
                     grads, state.opt_state, state.params, plan)
-            params = optax.apply_updates(state.params, updates)
+            with scopes.device(scopes.OPTIMIZER):
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state,
                                    batch_stats=state.batch_stats,
                                    step=state.step + 1)
@@ -1383,18 +1418,21 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
     def step(state, tokens):
         n = _step_no[0]
         _step_no[0] = n + 1
-        _flightrec.step_begin(n)
-        placed = (place_state(state), place_tokens(tokens))
-        _check_wire_drift()
-        ex = prog.executable(placed)  # one compile per shape signature
-        step.jitted = prog.jitted
-        step.compiled_collectives = prog.compiled_collectives
-        step.compiled_axis_collectives = prog.compiled_axis_collectives
-        out = ex(*placed)
-        _flightrec.step_end(n)
-        ledger = _ledger_lib.get_ledger()
-        ledger.note_compiled_path()
-        ledger.settle_step()
+        with scopes.step(n):
+            _flightrec.step_begin(n)
+            with scopes.host(scopes.PLACE):
+                placed = (place_state(state), place_tokens(tokens))
+            _check_wire_drift()
+            ex = prog.executable(placed)  # one compile per shape signature
+            step.jitted = prog.jitted
+            step.compiled_collectives = prog.compiled_collectives
+            step.compiled_axis_collectives = prog.compiled_axis_collectives
+            with scopes.host(scopes.LAUNCH):
+                out = ex(*placed)
+            _flightrec.step_end(n)
+            ledger = _ledger_lib.get_ledger()
+            ledger.note_compiled_path()
+            ledger.settle_step()
         return out
 
     def lower(state, tokens):
@@ -1593,22 +1631,27 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
                 logits_t = logits[:, :-1]
             else:
                 logits_t = logits
-            logp = jax.nn.log_softmax(logits_t.astype(jnp.float32), axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-            local_sum = -jnp.sum(ll * mask)
-            global_count = collective.allreduce(
-                jnp.asarray(jnp.sum(mask), jnp.float32), op=collective.Sum,
-                axes=grad_axes)
-            # scaled so that the Average-allreduce of per-shard losses (and
-            # of per-shard gradients, inside ``tx``) equals the exact
-            # global-mean loss/gradient
-            return local_sum * n_shards / global_count
+            with scopes.device(scopes.LOSS):
+                logp = jax.nn.log_softmax(logits_t.astype(jnp.float32),
+                                          axis=-1)
+                ll = jnp.take_along_axis(logp, targets[..., None],
+                                         axis=-1)[..., 0]
+                local_sum = -jnp.sum(ll * mask)
+                global_count = collective.allreduce(
+                    jnp.asarray(jnp.sum(mask), jnp.float32),
+                    op=collective.Sum, axes=grad_axes)
+                # scaled so that the Average-allreduce of per-shard losses
+                # (and of per-shard gradients, inside ``tx``) equals the
+                # exact global-mean loss/gradient
+                return local_sum * n_shards / global_count
 
         loss, grads = jax.value_and_grad(compute_loss)(state.params)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        loss = collective.allreduce(loss, op=collective.Average,
-                                    axes=grad_axes)
+        with scopes.device(scopes.OPTIMIZER):
+            params = optax.apply_updates(state.params, updates)
+        with scopes.device(scopes.LOSS):
+            loss = collective.allreduce(loss, op=collective.Average,
+                                        axes=grad_axes)
         new_state = TrainState(params=params, opt_state=opt_state,
                                batch_stats=state.batch_stats,
                                step=state.step + 1)
@@ -1616,7 +1659,7 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
 
     token_spec = P(batch_axis, seq_axis) if seq_axis else P(batch_axis)
 
-    def outer(state, tokens):
+    def hvd_lm_train_step(state, tokens):
         specs = state_specs(state)
         sharded = jax.shard_map(
             local_step, mesh=mesh,
@@ -1625,7 +1668,9 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
             check_vma=False)
         return sharded(state, tokens)
 
-    jitted = jax.jit(outer, donate_argnums=(0,) if donate else ())
+    # named for what it is: jit_hvd_lm_train_step (see make_train_step)
+    jitted = jax.jit(hvd_lm_train_step,
+                     donate_argnums=(0,) if donate else ())
     place_tokens = _placer(mesh, token_spec)
 
     def place_state(state):
@@ -1638,10 +1683,14 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
     def step(state, tokens):
         n = _step_no[0]
         _step_no[0] = n + 1
-        _flightrec.step_begin(n)
-        out = jitted(place_state(state), place_tokens(tokens))
-        _flightrec.step_end(n)
-        _ledger_lib.get_ledger().settle_step()
+        with scopes.step(n):
+            _flightrec.step_begin(n)
+            with scopes.host(scopes.PLACE):
+                placed = (place_state(state), place_tokens(tokens))
+            with scopes.host(scopes.LAUNCH):
+                out = jitted(*placed)
+            _flightrec.step_end(n)
+            _ledger_lib.get_ledger().settle_step()
         return out
 
     step.jitted = jitted  # AOT access (lower/compile/cost_analysis)

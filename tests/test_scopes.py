@@ -1,0 +1,253 @@
+"""The names a step carries from inside (``horovod_tpu/telemetry/scopes.py``):
+device scopes in the compiled text, host spans in the profiler's own trace.
+
+Every compile here runs with the persistent compile cache off. jax strips
+debug locations from the cache key (``jax_compilation_cache_include_
+metadata_in_key`` is off), and a ``named_scope`` lives only there: with the
+cache on, a scoped program hits the entry of the same program without
+scopes and ``as_text()`` shows that one's ``op_name``s.
+"""
+
+import contextlib
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+
+import horovod_tpu as hvd_api
+from horovod_tpu import training
+from horovod_tpu.models.transformer import Transformer, TransformerConfig
+from horovod_tpu.telemetry import scopes
+
+WORLD = 4
+
+
+@pytest.fixture(autouse=True, scope="module")
+def fresh_compiles():
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _mesh():
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:WORLD]), ("data",))
+
+
+def _lm(threshold_bytes=None):
+    """``(step, state, (tokens,))`` of a two-layer LM on the data mesh."""
+    cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=2,
+                            d_model=16, d_ff=32, dtype=jnp.float32,
+                            sequence_axis=None)
+    model = Transformer(cfg)
+    tx = hvd_api.DistributedOptimizer(optax.adamw(1e-3), axes=("data",),
+                                      threshold_bytes=threshold_bytes)
+    tokens = jnp.asarray(np.random.default_rng(0).integers(
+        0, 64, size=(WORLD * 2, 8)), jnp.int32)
+    state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
+                                        tokens[:1])
+    step = training.make_lm_train_step(model, tx, mesh=_mesh(),
+                                       batch_axis="data", donate=False)
+    return step, state, (tokens,)
+
+
+def _classifier():
+    """``(step, state, (inputs, labels))`` of a small MLP through
+    ``make_train_step`` with SGD momentum."""
+    import flax.linen as nn
+
+    class MLP(nn.Module):
+        @nn.compact
+        def __call__(self, x, train=False):
+            return nn.Dense(10)(nn.relu(nn.Dense(32)(x)))
+
+    model = MLP()
+    tx = hvd_api.DistributedOptimizer(optax.sgd(0.1, momentum=0.9),
+                                      axes=("data",))
+    rng = np.random.default_rng(0)
+    inputs = jnp.asarray(rng.normal(size=(WORLD * 2, 12)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, 10, size=(WORLD * 2,)), jnp.int32)
+    state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
+                                        inputs[:1])
+    step = training.make_train_step(model, tx, mesh=_mesh(), donate=False)
+    return step, state, (inputs, labels)
+
+
+BUILDERS = {"lm": _lm, "classifier": _classifier}
+
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = .*?\s([a-z][a-z\-]*)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+
+
+def _compiled_text(build):
+    step, state, batch = build()
+    return step.lower(state, *batch).compile().as_text()
+
+
+def _op_names(text, opcode=None):
+    """The ``op_name`` of every instruction of the module (of ``opcode``
+    alone if given); fused computations included."""
+    out = []
+    for line in text.splitlines():
+        m, name = _INSTR_RE.match(line), _OP_NAME_RE.search(line)
+        if m and name and opcode in (None, m.group(1)):
+            out.append(name.group(1))
+    return out
+
+
+@pytest.fixture(scope="module")
+def texts():
+    """Each builder's optimised HLO, compiled once for the module."""
+    hvd_api.shutdown()
+    hvd_api.init()
+    try:
+        yield {name: _compiled_text(build)
+               for name, build in BUILDERS.items()}
+    finally:
+        hvd_api.shutdown()
+
+
+@pytest.mark.parametrize("builder", sorted(BUILDERS))
+def test_exchange_scope_on_collective_pack_and_unpack(texts, builder):
+    text = texts[builder]
+    reduces = [n for n in _op_names(text, "all-reduce")
+               if scopes.EXCHANGE in n]
+    assert reduces, "no all-reduce of the module is under hvd_exchange"
+    assert all(f"{scopes.EXCHANGE}/bucket0" in n for n in reduces), reduces
+    under = [n for n in _op_names(text) if scopes.EXCHANGE in n]
+    # pack: the leaves flattened and concatenated; unpack: sliced and
+    # reshaped back (the compiler fuses them, a fusion keeps one name)
+    assert any(n.endswith(("/concatenate", "/ravel", "/reshape"))
+               for n in under), under
+    assert any(n.endswith(("/slice", "/reshape", "/dynamic_slice"))
+               and "bucket0" in n for n in under), under
+    assert any("/psum" in n for n in under), under
+
+
+@pytest.mark.parametrize("builder", sorted(BUILDERS))
+def test_optimizer_scope_on_the_update_arithmetic(texts, builder):
+    names = [n for n in _op_names(texts[builder])
+             if scopes.OPTIMIZER in n]
+    assert names, "nothing of the module is under hvd_optimizer"
+    # Adam's second moment / SGD's momentum trace, and the parameter write
+    assert any(n.endswith(("/mul", "/add", "/sqrt", "/div", "/integer_pow"))
+               for n in names), names
+    # the exchange is not the optimizer's: the chain runs it before
+    assert not any(scopes.EXCHANGE in n for n in names), names
+
+
+@pytest.mark.parametrize("builder", sorted(BUILDERS))
+def test_loss_scope_forward_and_backward(texts, builder):
+    names = _op_names(texts[builder])
+    forward = [n for n in names if f"jvp({scopes.LOSS})" in n
+               and "transpose(" not in n]
+    backward = [n for n in names
+                if f"transpose(jvp({scopes.LOSS}))" in n]
+    assert forward and backward, (forward, backward)
+    assert any("log_softmax" in n or "reduce_max" in n or "exp" in n
+               for n in forward), forward
+
+
+@pytest.mark.parametrize("builder", sorted(BUILDERS))
+def test_scopes_change_no_program(monkeypatch, builder):
+    """Without its ``metadata={...}`` the optimised HLO is the same, byte
+    for byte, with every device scope replaced by a null context."""
+    def strip(text):
+        # every instruction's metadata, and the tables of files, functions
+        # and stack frames it points into (the module's tail)
+        text = text.split("\nFileNames\n")[0]
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+
+    scoped = _compiled_text(BUILDERS[builder])
+    monkeypatch.setattr(scopes, "device",
+                        lambda name: contextlib.nullcontext())
+    bare = _compiled_text(BUILDERS[builder])
+    names = re.compile(r"hvd_(exchange|optimizer|loss)")
+    assert names.search(scoped) and not names.search(strip(scoped))
+    assert not names.search(bare)
+    assert strip(scoped) == strip(bare)
+
+
+def test_buckets_are_numbered_under_the_exchange(hvd):
+    """A threshold that splits the gradients: each bucket's packing and
+    unpacking carries its own index under ``hvd_exchange`` (the CPU
+    compiler combines the buckets' all-reduces into one instruction, which
+    keeps one name)."""
+    text = _compiled_text(lambda: _lm(threshold_bytes=4096))
+    found = {m for n in _op_names(text)
+             for m in re.findall(r"hvd_exchange/(bucket\d+)/", n)}
+    assert {"bucket0", "bucket1", "bucket2"} <= found, found
+
+
+@pytest.mark.parametrize("inner", [optax.adamw(1e-3),
+                                   optax.sgd(0.1, momentum=0.9)],
+                         ids=["adamw", "sgd_momentum"])
+def test_optimizer_state_tree_is_the_chains(hvd, inner):
+    """The ``hvd_optimizer`` wrapper adds no level and no leaf: the state
+    is ``(EmptyState(), inner.init(params))``, so a checkpoint written
+    before the wrapper restores."""
+    params = {"w": jnp.ones((3, 2)), "b": jnp.zeros((2,))}
+    tx = hvd_api.DistributedOptimizer(inner)
+    got = jax.tree_util.tree_structure(tx.init(params))
+    want = jax.tree_util.tree_structure(
+        (optax.EmptyState(), inner.init(params)))
+    assert got == want
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("hvd_"):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_host_spans_in_the_profilers_trace(hvd, tmp_path):
+    """Two steps under ``jax.profiler``: two ``hvd_step`` spans numbered 0
+    and 1, each holding one ``hvd_place`` and one ``hvd_launch``."""
+    step, state, batch = _lm()
+    step.lower(state, *batch).compile()  # tracing is not what is timed
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(2):
+            state, loss = step(state, *batch)
+        jax.block_until_ready(loss)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    steps = sorted((e for e in events if e[0] == scopes.STEP),
+                   key=lambda e: e[1])
+    assert [e[3].get("step_num") for e in steps] == [0, 1]
+    for _, start, end, _ in steps:
+        inside = sorted(name for name, s, e, _ in events
+                        if name != scopes.STEP and start <= s and e <= end)
+        assert inside == [scopes.LAUNCH, scopes.PLACE]
+
+
+@pytest.mark.parametrize("builder,module", [
+    ("lm", "jit_hvd_lm_train_step"), ("classifier", "jit_hvd_train_step")])
+def test_jitted_step_is_named_for_what_it_is(texts, builder, module):
+    # The module name is part of the persistent compile cache's key; the
+    # scopes are not (they are debug metadata, which the key leaves out).
+    # A machine whose cache holds the program of a commit without scopes
+    # would hand that executable, and its op_names, to the scoped program
+    # if both were jit_outer: every phase metric would read nothing.
+    assert texts[builder].startswith(f"HloModule {module},")
