@@ -17,11 +17,14 @@ K/V block passes that block's global offset and the causal mask stays
 exact. A query row with no visible keys outputs zeros (not a spurious
 mean of V).
 
-Gradients: custom VJP with **fused backward kernels** — a dQ pass
-(kv-blocks streamed) and a dK/dV pass (q-blocks streamed), each
-recomputing P blockwise from (q, k, lse) saved by the forward — so the
-backward, like the forward, never materializes S x S and stays
-O(S * block) in memory (the flash-attention rematerialization policy).
+Gradients: custom VJP with **one fused backward kernel** on the grid
+(batch*head, kv-block, q-block). For every visible block pair it
+rebuilds the scores and P once from (q, k, lse) saved by the forward —
+five matmuls and one ``exp`` — and feeds dV, dK (block-sized scratch,
+q-blocks streamed) and dQ (an fp32 accumulator for the whole query
+range of one batch*head, resident in VMEM) from them, so the backward,
+like the forward, never materializes S x S and stays O(S * block) in
+HBM (the flash-attention rematerialization policy).
 Kernel matmuls run at the MXU's default precision with fp32
 accumulation, matching XLA's own default on TPU. The ``attention``
 helper gives way to the plain-XLA path when shapes don't tile and says
@@ -38,13 +41,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on v5e (bf16 operands, fwd+bwd, b8 h12 s2048 d64): 512x512
-# blocks run 4x faster than 128x128 — bigger tiles amortize grid/VPU
-# overhead and keep the MXU fed; beyond 512 the curve is flat to slightly
-# worse. Blocks clamp to the sequence, so short inputs still tile.
+# Measured on v5e (bf16 operands, causal, b8 s2048; the backward kernel
+# alone, host clock over 20 calls, PERF.md PR 26): 512x512 blocks run
+# the backward in 2.97 ms at h12 d64 and 3.30 ms at h16 d128; a 256
+# on either side costs 25-36% more (grid steps and re-fetched tiles), a
+# 1024 is within +-5% (3.01-3.12 and 3.13-3.39 ms) and 2048 is slower
+# again, so forward and backward share one size. Blocks clamp to the
+# sequence, so short inputs still tile.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
+_NT = (((1,), (1,)), ((), ()))  # dot_general: a @ b.T
+_TN = (((0,), (0,)), ((), ()))  # dot_general: a.T @ b
+# What a kernel's block-sized tiles and temporaries may take of VMEM:
+# Mosaic's own default scoped limit on v5e, which the 512x512 blocks fit
+# with room. The backward adds its sequence-sized dQ on top.
+_BLOCK_VMEM_BYTES = 16 << 20
 
 
 class FlashFallbackWarning(UserWarning):
@@ -199,61 +211,29 @@ def _reference_attention(q, k, v, offsets, causal, sm_scale):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def _dq_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-               dq_ref, dq_acc, *, block_q, block_k, causal, sm_scale):
-    """Backward dQ pass: grid (bh, q-block, kv-block), kv innermost.
-    Recomputes P from (q, k, lse) blockwise — flash backward proper."""
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nkv = pl.num_programs(2)
-    q_off = off_ref[0]
-    kv_off = off_ref[1]
-
-    @pl.when(j == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    def _update():
-        # native-dtype matmul operands + fp32 accumulation (see _kernel)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        g = g_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = (q_off + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0))
-            kv_pos = (kv_off + j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1))
-            s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
-        # p = exp(s - lse); rows with nothing visible have lse=NEG_INF
-        p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_acc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    if causal:
-        q_last = q_off + i * block_q + (block_q - 1)
-        pl.when(q_last >= kv_off + j * block_k)(_update)
-    else:
-        _update()
-
-    @pl.when(j == nkv - 1)
-    def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                causal, sm_scale):
-    """Backward dK/dV pass: grid (bh, kv-block, q-block), q innermost."""
+def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_scratch,
+                block_q, block_k, causal, sm_scale):
+    """The whole backward: grid (bh, kv-block, q-block), q innermost.
+    Each visible (kv, q) pair rebuilds its scores once, transposed
+    (S^T = K Q^T, a (block_k, block_q) tile), so P^T and dS^T come out
+    as the left operands dV += P^T dO and dK += dS^T Q want, and feeds
+    dQ from the same dS^T by contracting over its rows. dK/dV live in
+    block-sized scratch for one kv-block; dQ for the WHOLE query range
+    of this bh stays in VMEM across the two inner grid dimensions: in
+    ``dq_ref`` itself when that is fp32, else in an fp32 scratch cast
+    into it on the last step."""
     j = pl.program_id(1)
     i = pl.program_id(2)
+    nkv = pl.num_programs(1)
     nq = pl.num_programs(2)
     q_off = off_ref[0]
     kv_off = off_ref[1]
+    dq_acc = dq_scratch[0] if dq_scratch else dq_ref
+
+    @pl.when((j == 0) & (i == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     @pl.when(i == 0)
     def _init():
@@ -266,21 +246,29 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         k = k_ref[0]
         v = v_ref[0]
         g = g_ref[0]
+        # (1, block_q) rows: one statistic per column of the transposed
+        # tile, broadcast down its sublanes
         lse = lse_ref[0]
         delta = delta_ref[0]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * sm_scale
+        s = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
         if causal:
             q_pos = (q_off + i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0))
+                jnp.int32, (1, block_q), 1))
             kv_pos = (kv_off + j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1))
+                jnp.int32, (block_k, 1), 0))
             s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
+        # p = exp(s - lse); rows with nothing visible have lse=NEG_INF
         p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dv_acc[:] += jnp.dot(p.astype(g.dtype).T, g,
-                             preferred_element_type=jnp.float32)
-        dp = jnp.dot(g, v.T, preferred_element_type=jnp.float32)
+        dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
+                               preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v, g, _NT,
+                                 preferred_element_type=jnp.float32)
         ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_acc[0, rows, :] += jax.lax.dot_general(
+            ds, k, _TN, preferred_element_type=jnp.float32)
 
     if causal:
         q_last = q_off + i * block_q + (block_q - 1)
@@ -293,85 +281,75 @@ def _dkv_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    if dq_scratch:
+        @pl.when((j == nkv - 1) & (i == nq - 1))
+        def _finalize_dq():
+            dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+
 
 def _flash_bwd_impl(q, k, v, g, out, lse, offsets, causal, sm_scale,
                     block_q, block_k, interpret):
-    """Fused flash backward: dq pass then dk/dv pass, each streaming the
-    other operand; memory is O(S * block), never O(S^2)."""
+    """Fused flash backward from the forward's residuals; memory is
+    O(S * block), never O(S^2)."""
     # delta_i = sum_d dO * O — the softmax-jacobian row correction
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [BH, Sq, 1]
-    return _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal,
+                    axis=-1)  # [BH, Sq]
+    return _flash_bwd_core(q, k, v, g, lse[..., 0], delta, offsets, causal,
                            sm_scale, block_q, block_k, interpret)
 
 
 def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
                     block_q, block_k, interpret, out_dtype=None):
-    """The two backward kernel launches, with (lse, delta) supplied by
-    the caller. Ring attention calls this per rotated K/V block with the
-    globally-merged lse and the once-computed global delta — the
-    per-block partials then sum to the exact global-softmax gradient
-    (softmax over the union of blocks factorizes as p = exp(s - LSE)).
-    ``out_dtype`` lets accumulating callers request fp32 partials."""
+    """The one backward kernel launch, with (lse, delta) — fp32
+    [BH, Sq] — supplied by the caller. Ring attention calls this per
+    rotated K/V block with the globally-merged lse and the once-computed
+    global delta — the per-block partials then sum to the exact
+    global-softmax gradient (softmax over the union of blocks factorizes
+    as p = exp(s - LSE)). ``out_dtype`` lets accumulating callers
+    request fp32 partials.
+
+    The only quantity that grows with the sequence is the resident dQ:
+    two output buffers of ``sq * d`` elements plus, unless dQ is fp32,
+    the fp32 accumulator (1 MiB at s2048 d64 bf16, 2 MiB at s2048 d128,
+    32 MiB for ring attention's fp32 partials at 32k a chip and d128 —
+    of the 128 MiB a v5e core has; past ``sq * d`` = 8 Mi elements the
+    compile fails with Mosaic's out-of-VMEM message)."""
     bh, sq, d = q.shape
     skv = k.shape[1]
     # grads mirror their primal dtypes (custom_vjp aval contract) unless
     # the caller wants uniform fp32 partials for accumulation
-    dq_dtype = out_dtype or q.dtype
+    dq_dtype = jnp.dtype(out_dtype or q.dtype)
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
-    kw = dict(block_q=block_q, block_k=block_k, causal=causal,
-              sm_scale=sm_scale)
-    qspec = lambda b, i, j, *_: (b, i, 0)      # noqa: E731
-    kspec = lambda b, i, j, *_: (b, j, 0)      # noqa: E731
-    rowspec = lambda b, i, j, *_: (b, i, 0)    # noqa: E731
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(bh, sq // block_q, skv // block_k),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), qspec),
-                pl.BlockSpec((1, block_k, d), kspec),
-                pl.BlockSpec((1, block_k, d), kspec),
-                pl.BlockSpec((1, block_q, d), qspec),
-                pl.BlockSpec((1, block_q, 1), rowspec),
-                pl.BlockSpec((1, block_q, 1), rowspec),
-            ],
-            out_specs=pl.BlockSpec((1, block_q, d), qspec),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), dq_dtype),
-        interpret=interpret,
-    )(offsets, q, k, v, g, lse, delta)
-
-    # second pass: kv-block outer, q-block inner
-    qspec2 = lambda b, j, i, *_: (b, i, 0)     # noqa: E731
-    kspec2 = lambda b, j, i, *_: (b, j, 0)     # noqa: E731
-    rowspec2 = lambda b, j, i, *_: (b, i, 0)   # noqa: E731
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kw),
+    qspec = pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0))
+    kspec = pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0))
+    dqspec = pl.BlockSpec((1, sq, d), lambda b, j, i, *_: (b, 0, 0))
+    # row statistics ride as [BH, 1, Sq]: lane-dense (1, block_q) rows
+    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, j, i, *_: (b, 0, i))
+    scratch = [pltpu.VMEM((block_k, d), jnp.float32),   # dk
+               pltpu.VMEM((block_k, d), jnp.float32)]   # dv
+    resident = 2 * sq * d * dq_dtype.itemsize
+    if dq_dtype != jnp.float32:
+        scratch.append(pltpu.VMEM((1, sq, d), jnp.float32))
+        resident += sq * d * 4
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
+                          causal=causal, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, skv // block_k, sq // block_q),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), qspec2),
-                pl.BlockSpec((1, block_k, d), kspec2),
-                pl.BlockSpec((1, block_k, d), kspec2),
-                pl.BlockSpec((1, block_q, d), qspec2),
-                pl.BlockSpec((1, block_q, 1), rowspec2),
-                pl.BlockSpec((1, block_q, 1), rowspec2),
-            ],
-            out_specs=(pl.BlockSpec((1, block_k, d), kspec2),
-                       pl.BlockSpec((1, block_k, d), kspec2)),
-            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+            out_specs=(dqspec, kspec, kspec),
+            scratch_shapes=scratch,
         ),
-        out_shape=(jax.ShapeDtypeStruct((bh, skv, d), dk_dtype),
+        out_shape=(jax.ShapeDtypeStruct((bh, sq, d), dq_dtype),
+                   jax.ShapeDtypeStruct((bh, skv, d), dk_dtype),
                    jax.ShapeDtypeStruct((bh, skv, d), dv_dtype)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BLOCK_VMEM_BYTES + resident),
         interpret=interpret,
-    )(offsets, q, k, v, g, lse, delta)
-    return dq, dk, dv
+    )(offsets, q, k, v, g, lse[:, None, :], delta[:, None, :])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -519,7 +497,7 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
     [B,Skv,H,D], the upstream ``g`` = dO, the **globally merged**
     ``lse`` [B,Sq,H] (from ``flash_attention_with_lse`` + lse merging)
     and ``delta`` [B,Sq,H] = sum_d(dO * O) over the final output, runs
-    the fused dQ and dK/dV kernels and returns fp32 partials
+    the fused backward kernel and returns fp32 partials
     ``(dq, dk, dv)`` for exactly this block's contribution. Summing the
     partials over all blocks (rotating dk/dv with their K/V blocks
     around the ring) reproduces the exact global-softmax gradient,
@@ -531,8 +509,8 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
 
-    def rows_bh(x):  # [B,Sq,H] -> [BH,Sq,1]
-        return x.transpose(0, 2, 1).reshape(b * h, sq, 1)
+    def rows_bh(x):  # [B,Sq,H] -> [BH,Sq]
+        return x.transpose(0, 2, 1).reshape(b * h, sq)
 
     dq, dk, dv = _flash_bwd_core(
         to_bh(q), to_bh(k), to_bh(v), to_bh(g), rows_bh(lse),

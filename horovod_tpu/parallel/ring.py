@@ -113,7 +113,8 @@ def _ring_attention_flash(q, k, v, axis_name, causal):
     """Ring attention whose per-block compute is the Pallas flash kernel
     in BOTH directions. Forward: blocks merge by the standard
     log-sum-exp composition ``out = sum_j exp(lse_j - LSE) * out_j``.
-    Backward: a second ring pass runs the fused dQ/dKV kernels per
+    Backward: a second ring pass runs the one fused backward kernel
+    (dQ, dK and dV from a single rebuild of each score block) per
     rotated K/V block against the globally-merged lse (saved from the
     forward) and the once-computed ``delta = sum_d dO*O``; the dK/dV
     partial accumulators rotate WITH their K/V blocks, so after n steps

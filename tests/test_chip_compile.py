@@ -6,7 +6,10 @@ section 2). Interpret mode, which the rest of the CPU suite uses, cannot
 see what Mosaic refuses — a slice off the tiling, too much VMEM — so the
 flash kernel's forward, forward+backward and the lse-returning variant
 with the blockwise backward that ring attention composes are compiled
-here with ``interpret=False`` at the head shapes ``chip_smoke.py`` runs.
+here with ``interpret=False`` at the head shapes ``chip_smoke.py`` runs,
+and at long sequences whose resident dQ (the backward's one
+sequence-sized VMEM buffer) nears and passes Mosaic's default scoped
+limit.
 Nothing executes; a pass is not a chip run.
 """
 
@@ -24,8 +27,14 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from horovod_tpu.ops import flash_attention as fa  # noqa: E402
 
-# [B, S, H, D] of the d2048 16-head LM and the d768 12-head LM
-SHAPES = [(8, 2048, 16, 128), (8, 2048, 12, 64)]
+# [B, S, H, D] of the d2048 16-head LM and the d768 12-head LM, and two
+# long ones for the backward's one sequence-sized VMEM buffer, the
+# resident dQ: at s8192 d128 it is 8-12 MiB beside the 512x512 tiles,
+# at the edge of the 16 MiB a kernel gets unasked; at 32k a chip
+# (ROADMAP R6's longest local sequence) 32-48 MiB, which compiles only
+# because ``vmem_limit_bytes`` follows from the shapes
+SHAPES = [(8, 2048, 16, 128), (8, 2048, 12, 64), (1, 8192, 16, 128),
+          (1, 32768, 2, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -66,12 +75,14 @@ def _lse_and_blockwise_backward(q, k, v):
 
 @pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
 @pytest.mark.parametrize("fn,kernels", [
-    (_forward, 1), (_forward_backward, 3), (_lse_and_blockwise_backward, 3)],
+    (_forward, 1), (_forward_backward, 2), (_lse_and_blockwise_backward, 2)],
     ids=["forward", "forward_backward", "lse_blockwise_backward"])
 def test_flash_kernel_compiles_for_v5e(v5e, shape, fn, kernels):
     x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
     text = jax.jit(fn).lower(x, x, x).compile().as_text()
-    assert text.count("tpu_custom_call") >= kernels, (
+    # one forward kernel and ONE backward kernel: a third call would be
+    # the dQ pass come back (each pass rebuilds every block's scores)
+    assert text.count("tpu_custom_call") == kernels, (
         f"{fn.__name__} at {shape}: expected {kernels} Mosaic kernel(s) "
         "in the compiled program")
 

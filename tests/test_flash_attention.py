@@ -318,30 +318,140 @@ def test_transformer_ring_flash_trains(hvd, n_devices):
     assert losses[-1] < losses[0]
 
 
-def test_gradients_multi_block_and_offsets():
+def _to_bh(x):
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _ref_loss(q, k, v, qo, ko, causal=True):
+    """sum(out**2) of the plain-XLA oracle on [B,S,H,D] inputs."""
+    off = jnp.asarray([qo, ko], jnp.int32)
+    r = fa._reference_attention(_to_bh(q), _to_bh(k), _to_bh(v), off,
+                                causal, 1.0 / (q.shape[-1] ** 0.5))
+    return jnp.sum(r ** 2)
+
+
+@pytest.mark.parametrize("qo,ko", [(0, 0), (512, 0), (256, 256)])
+def test_gradients_multi_block_and_offsets(qo, ko):
     """s=512 with block 128 -> 4x4 backward grid: exercises scratch
-    init/finalize, cross-block accumulation, and the causal block-skip;
-    offset variant exercises the shifted-mask gradient paths."""
+    init/finalize, cross-block accumulation (dK/dV over q-blocks, the
+    resident dQ over kv-blocks), and the causal block-skip; the offset
+    variants exercise the shifted-mask gradient paths."""
     rng = np.random.default_rng(9)
     q, k, v = _qkv(rng, s=512, h=2, d=32)
 
-    def f_flash(q, k, v, qo=0, ko=0):
-        return jnp.sum(fa.flash_attention(q, k, v, q_offset=qo,
-                                          kv_offset=ko) ** 2)
+    def f_flash(q, k, v):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, q_offset=qo, kv_offset=ko, block_q=128,
+            block_k=128) ** 2)
 
-    def f_ref(q, k, v, qo=0, ko=0):
-        b, s, h, d = q.shape
-        bh = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-        off = jnp.asarray([qo, ko], jnp.int32)
-        r = fa._reference_attention(bh(q), bh(k), bh(v), off, True,
-                                    1.0 / (d ** 0.5))
-        return jnp.sum(r ** 2)
+    gf = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda q, k, v: _ref_loss(q, k, v, qo, ko),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-3)
 
-    for qo, ko in [(0, 0), (512, 0), (256, 256)]:
-        gf = jax.grad(lambda q, k, v: f_flash(q, k, v, qo, ko),
-                      argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(lambda q, k, v: f_ref(q, k, v, qo, ko),
-                      argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=2e-3)
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("sq,skv,bq,bk,qo,ko", [
+    (256, 512, 128, 128, 256, 0),    # 2 x 4 blocks, queries the later half
+    (512, 256, 128, 64, 0, 128),     # 4 x 4, early queries see no key
+    (384, 256, 128, 128, 64, 192),   # 3 x 2, the diagonal off the blocks
+    (256, 256, 64, 128, 0, 0),       # rectangular blocks, 4 x 2
+], ids=["sq256-skv512", "sq512-skv256", "sq384-skv256", "rect-blocks"])
+def test_one_pass_backward_matches_reference(sq, skv, bq, bk, qo, ko,
+                                             causal, traced):
+    """The one backward kernel against ``_reference_attention``'s
+    gradients at more than one block on each axis, sq != skv, causal
+    and not, with static and traced non-zero offsets."""
+    rng = np.random.default_rng(sq + skv + qo)
+    b, h, d = 2, 2, 32
+    q = jnp.asarray(rng.standard_normal((b, sq, h, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, skv, h, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, skv, h, d)), jnp.float32)
+
+    def f_flash(q, k, v, qo, ko):
+        return jnp.sum(fa.flash_attention(
+            q, k, v, causal=causal, q_offset=qo, kv_offset=ko, block_q=bq,
+            block_k=bk) ** 2)
+
+    grad = jax.grad(f_flash, argnums=(0, 1, 2))
+    if traced:
+        gf = jax.jit(grad)(q, k, v, jnp.int32(qo), jnp.int32(ko))
+    else:
+        gf = grad(q, k, v, qo, ko)
+    gr = jax.grad(lambda q, k, v: _ref_loss(q, k, v, qo, ko, causal),
+                  argnums=(0, 1, 2))(q, k, v)
+    for a, r in zip(gf, gr):
+        assert a.dtype == r.dtype and a.shape == r.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=2e-3)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_backward_of_a_wholly_masked_kv_block_is_exactly_zero(dtype):
+    """A K/V block wholly after the queries: the kernel skips every
+    (kv, q) pair, and dq, dk, dv must still be exact zeros — the
+    accumulators are set before and written after the skipped steps —
+    in the dtype of their primals (bf16 goes through the fp32 dQ
+    scratch, fp32 accumulates in the output block itself)."""
+    rng = np.random.default_rng(13)
+    q, k, v = (x.astype(dtype) for x in _qkv(rng, s=256, h=2, d=32))
+
+    def f(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=True, q_offset=0,
+                                 kv_offset=256, block_q=128, block_k=128)
+        return jnp.sum(out.astype(jnp.float32) * 3.0)
+
+    for grad, x in zip(jax.grad(f, argnums=(0, 1, 2))(q, k, v), (q, k, v)):
+        assert grad.dtype == x.dtype
+        np.testing.assert_array_equal(np.asarray(grad, np.float32), 0.0)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-3),
+                                        (jnp.bfloat16, 8e-2)],
+                         ids=["f32", "bf16"])
+def test_bwd_block_partials_are_f32_and_sum_to_the_full_gradient(dtype,
+                                                                 atol):
+    """What ring attention composes: per K/V block, the lse-returning
+    forward, lse merging, then ``flash_attention_bwd_block`` against the
+    global (lse, delta). The partials are fp32 whatever the inputs are,
+    dq sums over blocks and dk/dv concatenate to the gradient of
+    attention over the whole sequence; the last block lies wholly after
+    the first half of the queries."""
+    rng = np.random.default_rng(17)
+    s, nblk = 512, 2
+    q32, k32, v32 = _qkv(rng, b=1, s=s, h=2, d=32)
+    q, k, v = (x.astype(dtype) for x in (q32, k32, v32))
+    g = jnp.asarray(rng.standard_normal(q.shape), dtype)
+    kw = dict(causal=True, block_q=128, block_k=128)
+    sb = s // nblk
+    blocks = [(k[:, n * sb:(n + 1) * sb], v[:, n * sb:(n + 1) * sb], n * sb)
+              for n in range(nblk)]
+    outs, lses = zip(*[fa.flash_attention_with_lse(q, kb, vb, kv_offset=o,
+                                                   **kw)
+                       for kb, vb, o in blocks])
+    lse = jax.nn.logsumexp(jnp.stack(lses), axis=0)  # [B,S,H]
+    out = sum(o.astype(jnp.float32) * jnp.exp(l - lse)[..., None]
+              for o, l in zip(outs, lses))
+    delta = jnp.sum(g.astype(jnp.float32) * out, axis=-1)
+    parts = [fa.flash_attention_bwd_block(q, kb, vb, g, lse, delta,
+                                          kv_offset=o, **kw)
+             for kb, vb, o in blocks]
+    for part in parts:
+        assert all(x.dtype == jnp.float32 for x in part)
+    dq = sum(p[0] for p in parts)
+    dk = jnp.concatenate([p[1] for p in parts], axis=1)
+    dv = jnp.concatenate([p[2] for p in parts], axis=1)
+
+    def f_ref(q, k, v):
+        r = fa._reference_attention(
+            _to_bh(q), _to_bh(k), _to_bh(v), jnp.zeros(2, jnp.int32), True,
+            1.0 / (q.shape[-1] ** 0.5))
+        return jnp.sum(r * _to_bh(g.astype(jnp.float32)))
+
+    ref = jax.grad(f_ref, argnums=(0, 1, 2))(q32, k32, v32)
+    for a, r in zip((dq, dk, dv), ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=atol)
