@@ -1,0 +1,13 @@
+"""Share of the traced window that is device self time under the
+program's ``hvd_moe_experts`` scope, forward, recomputation and backward,
+worst chip: the routed experts' grouped products and their SwiGLU,
+whatever implements them. Left out when the scope is not in the
+executable."""
+
+from benchmark.harness import scope_time
+
+LAYER, UNIT, MOVES = "model", "%", "step_ms"
+
+
+def read(run):
+    return scope_time.pct(run, "hvd_moe_experts")

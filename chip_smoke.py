@@ -10,8 +10,12 @@ points users call, at the full width of two models the repo supports:
                     as a child, before this process touches jax: the
                     launcher parent must not hold the chip its worker needs.
 * ``flash_kernel``  the Pallas flash kernel's forward and gradients against
-                    ``_reference_attention`` at the two head shapes of the
-                    main path, on the chip (not the interpreter).
+                    ``_reference_attention`` at the head shapes of the
+                    main path and of latent attention (192 / 128), on the
+                    chip (not the interpreter).
+* ``grouped_kernel`` the expert share's grouped products (megablox under
+                    ``models/experts._gmm``'s VJP) against a loop over the
+                    groups: result, input and weight gradient.
 * ``lm``            the decoder LM, 8 layers d2048 16 heads, vocab 32000,
                     sequence 2048, batch 8, flash on (``make_lm_bench`` ->
                     ``make_lm_train_step``): loss finite and falling on a
@@ -29,7 +33,7 @@ against the same global batch and seed on the first chip alone.
 
 Depth is cut and the weights are random, made from a seed; widths are not
 cut. Every phase prints one JSON line. A phase that fails raises: nothing
-here turns a failure into a result, a fallback from the flash kernel is an
+here turns a failure into a result, a fallback from a Pallas kernel is an
 error, and nothing runs unless jax's first device is a TPU. The last line
 of standard output is the contract's
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -54,15 +58,23 @@ REQUIRED_PLATFORM = "tpu"
 
 HVDRUN = dict(model="resnet50", batch=32, image=224, warmup=1,
               batches_per_iter=3, iters=1, timeout=600)
-# kernel vs reference: the main path's head shapes [B, S, H, D]; batch 2
-# keeps the plain-XLA reference's S x S scores (and their gradients)
-# small beside the kernel's operands
-KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64))
+# kernel vs reference: the main path's head shapes [B, S, H, D] (a fifth
+# number: v and o that wide, q and k at D; latent attention's 192 / 128 at
+# the sequence of its benchmark cell); batch 2, or 1 at s4096, keeps the
+# plain-XLA reference's S x S scores (and their gradients) small beside the
+# kernel's operands
+KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64),
+                 (1, 4096, 32, 192, 128))
 # bf16 operands, fp32 accumulation: a tensor agrees when its error is
 # under 2% of the reference in L2 and no element is off by more than
 # tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
 KERNEL_REL_L2 = 2e-2
 KERNEL_ATOL = dict(out=5e-2, dq=8e-2, dk=8e-2, dv=8e-2)
+# the expert share's grouped products at its benchmark cell's sizes: all
+# T * k = 16,384 * 6 token-slots as rows, an eighth of them live, in 16
+# ragged groups; gate and up as one product [d, 2f], then down [f, d]
+GROUPED = dict(rows=98304, live=12288, groups=16,
+               products=((2048, 1536), (768, 2048)))
 LM = dict(layers=8, d_model=2048, heads=16, vocab=32000, seq_len=2048,
           batch=8, steps=4)
 RESNET = dict(model="resnet101", batch=256, image=224, steps=3)
@@ -258,11 +270,12 @@ def phase_flash_kernel():
     results = []
     with CompileWatch() as watch:
         for shape in KERNEL_SHAPES:
-            b, s, h, d = shape
+            b, s, h, d = shape[:4]
+            d_v = shape[-1]
             rng = np.random.default_rng(0)
-            q, k, v = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-                       for _ in range(3))
-            w = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+            q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, width)),
+                                   jnp.bfloat16) for width in (d, d, d_v))
+            w = jnp.asarray(rng.standard_normal(v.shape), jnp.float32)
 
             # w is an argument, not a closure: a captured array becomes a
             # constant of the executable, and four 30 MB constants push
@@ -273,12 +286,12 @@ def phase_flash_kernel():
 
             def reference_loss(q, k, v, w):
                 def to_bh(x):
-                    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+                    return x.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
 
                 out = fa._reference_attention(
                     to_bh(q), to_bh(k), to_bh(v),
                     jnp.zeros((2,), jnp.int32), True, 1.0 / d ** 0.5)
-                out = out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+                out = out.reshape(b, h, s, d_v).transpose(0, 2, 1, 3)
                 return jnp.sum(out.astype(jnp.float32) * w), out
 
             grad = lambda f: jax.jit(jax.value_and_grad(  # noqa: E731
@@ -313,6 +326,80 @@ def phase_flash_kernel():
     _emit("flash_kernel", shapes=results,
           tolerance={"rel_l2": KERNEL_REL_L2, "max_abs": KERNEL_ATOL},
           **watch.fields())
+
+
+def phase_grouped_kernel():
+    """megablox under ``models/experts._gmm``'s own VJP (a tiling for each
+    of its three products, ``transpose_rhs``, ``tgmm`` told the number of
+    groups) against a loop over the groups in float32: the result, the
+    input gradient and the weight gradient, over the live rows. The rows
+    past the groups' end carry numbers like any other, in the operand and
+    in the cotangent: the weight gradient must not see them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models import experts
+
+    a = GROUPED
+    rng = np.random.default_rng(0)
+    sizes = rng.multinomial(a["live"],
+                            rng.dirichlet(np.full(a["groups"], 0.5)))
+    sizes[0] += sizes[3]
+    sizes[3] = 0  # an expert no token chose
+    group_of_row = jnp.asarray(np.repeat(np.arange(a["groups"]), sizes))
+    sizes = jnp.asarray(sizes, jnp.int32)
+
+    def kernel(xs, w, g):
+        out, vjp = jax.vjp(lambda xs, w: experts._gmm(xs, w, sizes), xs, w)
+        return (out,) + vjp(g)
+
+    def loop(xs, w, g):
+        live = lambda x: x[:a["live"]].astype(jnp.float32)  # noqa: E731
+
+        def product(xs, w):
+            def one(out, group):
+                i, w_i = group
+                return out + jnp.where((group_of_row == i)[:, None],
+                                       xs @ w_i, 0.0), None
+            return jax.lax.scan(
+                one, jnp.zeros((a["live"], w.shape[2]), jnp.float32),
+                (jnp.arange(a["groups"]), w))[0]
+
+        with jax.default_matmul_precision("highest"):
+            out, vjp = jax.vjp(product, live(xs), w.astype(jnp.float32))
+            return (out,) + vjp(live(g))
+
+    results = []
+    with CompileWatch() as watch:
+        for k, n in a["products"]:
+            xs = jnp.asarray(rng.standard_normal((a["rows"], k)),
+                             jnp.bfloat16)
+            w = jnp.asarray(rng.standard_normal((a["groups"], k, n))
+                            / k ** 0.5, jnp.bfloat16)
+            g = jnp.asarray(rng.standard_normal((a["rows"], n)),
+                            jnp.bfloat16)
+            compiled = jax.jit(kernel).lower(xs, w, g).compile()
+            kernels = assert_kernel_compiled(
+                compiled.as_text(), f"grouped product {k} x {n}")
+            got, want = compiled(xs, w, g), jax.jit(loop)(xs, w, g)
+            errors = {}
+            for name, x, y in zip(("out", "d_xs", "d_w"), got, want):
+                x = np.asarray(x, np.float32)[:len(y)]
+                errors[name] = round(float(
+                    np.linalg.norm(x - np.asarray(y))
+                    / np.linalg.norm(y)), 5)
+                if not errors[name] <= KERNEL_REL_L2:
+                    raise RuntimeError(
+                        f"grouped product {name} at {a['rows']} x {k} x "
+                        f"{n} disagrees with the loop over groups: rel_l2 "
+                        f"{errors[name]} (allowed {KERNEL_REL_L2})")
+            results.append({"shape": [a["rows"], k, n],
+                            "live_rows": a["live"], "groups": a["groups"],
+                            "dtype": "bfloat16",
+                            "tpu_custom_calls": kernels, "rel_l2": errors})
+    _emit("grouped_kernel", products=results,
+          tolerance={"rel_l2": KERNEL_REL_L2}, **watch.fields())
 
 
 def _lm_run(mesh, a):
@@ -544,18 +631,20 @@ def main():
         phase_hvdrun()  # a child: must come before this process has jax
 
     import horovod_tpu as hvd
+    from horovod_tpu.models.experts import GroupedFallbackWarning
     from horovod_tpu.ops.flash_attention import FlashFallbackWarning
 
-    # asked for flash and got the reference: an error in every phase
+    # asked for a kernel and got plain XLA: an error in every phase
     warnings.simplefilter("error", FlashFallbackWarning)
+    warnings.simplefilter("error", GroupedFallbackWarning)
     hvd.init()  # first backend touch: libtpu starts under its flags
     device = _device()
     if device != found:
         sys.exit(f"chip_smoke: this process sees {device}, the probe saw "
                  f"{found}")
     phases = ([phase_init, phase_data_parallel] if args.four_chips else
-              [phase_init, phase_flash_kernel, phase_lm, phase_resnet,
-               phase_serve])
+              [phase_init, phase_flash_kernel, phase_grouped_kernel,
+               phase_lm, phase_resnet, phase_serve])
     for phase in phases:
         phase()
         gc.collect()  # the next phase needs the device memory back

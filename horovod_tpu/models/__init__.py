@@ -13,7 +13,14 @@ the framework is benchmarkable and usable standalone:
 * ``vgg``         — VGG-16, the bandwidth-bound scaling stress test.
 * ``simple``      — MNIST-scale ConvNet/MLP for the example suite.
 * ``transformer`` — decoder-only Transformer with sequence-parallel (ring
-  attention) support; not in the 2019 reference, first-class here.
+  attention) support; not in the 2019 reference, first-class here. A
+  layer is a (mixer, feed-forward) pair read from a layer pattern.
+* ``mla``         — multi-head latent attention (the DeepSeek-V3 family's
+  mixer), through the one flash kernel at two head sizes.
+* ``moe``         — capacity-dispatch mixture of experts over an expert
+  mesh axis.
+* ``experts``     — a no-drop expert layer that holds one chip's share of
+  the experts, and the SwiGLU of dense and shared feed-forwards.
 
 All models are NHWC, bf16-compute/fp32-param by default — the layout the
 MXU wants.
@@ -31,9 +38,12 @@ from horovod_tpu.models.simple import MNISTConvNet, MLP
 from horovod_tpu.models.vgg import VGG16
 from horovod_tpu.models.transformer import Transformer, TransformerConfig
 from horovod_tpu.models.moe import MoE
+from horovod_tpu.models.mla import LatentAttention, LatentAttentionConfig
+from horovod_tpu.models.experts import ExpertShare, ExpertShareConfig, SwiGLU
 
 __all__ = [
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "MNISTConvNet", "MLP", "VGG16", "Transformer", "TransformerConfig",
-    "MoE",
+    "MoE", "LatentAttention", "LatentAttentionConfig", "ExpertShare",
+    "ExpertShareConfig", "SwiGLU",
 ]

@@ -6,6 +6,11 @@ of the TPU framework. Design:
 
 * Pre-RMSNorm, rotary position embeddings, GELU MLP — the standard modern
   decoder block, all shapes static and MXU-friendly (bf16 compute).
+* A layer is a pair (mixer, feed-forward) read from
+  ``TransformerConfig.layer_pattern``: multi-head attention or latent
+  attention (``models/mla.py``); GELU, SwiGLU, the capacity-dispatch MoE
+  (``models/moe.py``) or the no-drop expert share with its shared experts
+  (``models/experts.py``). The default pattern is the block above.
 * ``sequence_axis``: when set (inside shard_map over that mesh axis), the
   sequence dimension is sharded across the axis and attention runs as
   **ring attention** (``horovod_tpu.parallel.ring``): K/V blocks rotate
@@ -69,6 +74,32 @@ class TransformerConfig:
     # group dim (usually the data axis) so EP composes with DP
     moe_num_groups: int = 1
     moe_group_axis: Optional[str] = None
+    # A layer is a pair (mixer, feed-forward), one pair a layer:
+    #   mixer         "mha" (Attention below) | "mla" (models/mla.py,
+    #                 sized by ``mla``)
+    #   feed-forward  "gelu" (two matrices, width d_ff) | "moe" (the
+    #                 capacity dispatch of models/moe.py) | "swiglu"
+    #                 (gated, width d_ff) | "experts" (the no-drop share
+    #                 of models/experts.py plus its shared experts, sized
+    #                 by ``experts``)
+    # None: ("mha", "moe" on every moe_every-th layer, else "gelu"), the
+    # block this file has always built, parameter for parameter.
+    layer_pattern: Optional[tuple] = None
+    mla: Any = None      # models.mla.LatentAttentionConfig
+    experts: Any = None  # models.experts.ExpertShareConfig
+
+    def layers(self):
+        """The (mixer, feed-forward) pair of every layer."""
+        if self.layer_pattern is not None:
+            if len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern names {len(self.layer_pattern)} layers "
+                    f"and num_layers is {self.num_layers}")
+            return tuple(tuple(pair) for pair in self.layer_pattern)
+        return tuple(
+            ("mha", "moe" if self.moe_every > 0
+             and (i + 1) % self.moe_every == 0 else "gelu")
+            for i in range(self.num_layers))
 
 
 def _rotary(x, positions):
@@ -82,6 +113,14 @@ def _rotary(x, positions):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1)
+
+
+def wants_flash(cfg, seq_len):
+    """``cfg.flash_attention``, or its auto rule: TPU only, and only past
+    the measured sequence crossover (see the field's comment)."""
+    if cfg.flash_attention is not None:
+        return cfg.flash_attention
+    return jax.devices()[0].platform == "tpu" and seq_len >= 1024
 
 
 def dense_attention(q, k, v, *, causal, q_positions, kv_positions):
@@ -132,12 +171,7 @@ class Attention(nn.Module):
                                   dtype=cfg.dtype, use_bias=False,
                                   name="out")(out)
             return out, (k, v)
-        use_flash = cfg.flash_attention
-        if use_flash is None:
-            # auto: TPU only, and only past the measured seq crossover
-            # (see TransformerConfig.flash_attention)
-            use_flash = (jax.devices()[0].platform == "tpu"
-                         and x.shape[1] >= 1024)
+        use_flash = wants_flash(cfg, x.shape[1])
         from horovod_tpu.ops import flash_attention as fa
         if cfg.flash_attention and not contiguous_positions:
             # the kernel masks by offset-contiguous positions; arbitrary
@@ -170,25 +204,32 @@ class Attention(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    use_moe: bool = False
+    mixer: str = "mha"
+    feed_forward: str = "gelu"
 
     @nn.compact
     def __call__(self, x, positions, contiguous_positions=False,
                  cache=None):
         cfg = self.cfg
         y = nn.RMSNorm(dtype=cfg.dtype)(x)
+        if self.mixer == "mha":
+            attention = Attention(cfg, name="attn")
+        elif self.mixer == "mla":
+            from horovod_tpu.models.mla import LatentAttention
+            attention = LatentAttention(cfg, name="attn")
+        else:
+            raise ValueError(f"unknown mixer {self.mixer!r}")
         new_kv = None
         if cache is not None:
-            attn_out, new_kv = Attention(cfg, name="attn")(
-                y, positions, contiguous_positions, cache)
+            attn_out, new_kv = attention(y, positions, contiguous_positions,
+                                         cache)
             x = x + attn_out
         else:
-            x = x + Attention(cfg, name="attn")(y, positions,
-                                                contiguous_positions)
+            x = x + attention(y, positions, contiguous_positions)
         y = nn.RMSNorm(dtype=cfg.dtype)(x)
-        if self.use_moe:
+        b, s, d = y.shape
+        if self.feed_forward == "moe":
             from horovod_tpu.models.moe import MoE
-            b, s, d = y.shape
             y = MoE(num_experts=cfg.num_experts, d_model=d,
                     d_ff=cfg.d_ff, dtype=cfg.dtype, mesh=cfg.expert_mesh,
                     expert_axis=cfg.expert_axis,
@@ -196,10 +237,26 @@ class Block(nn.Module):
                     group_axis=cfg.moe_group_axis, top_k=cfg.moe_top_k,
                     capacity_factor=cfg.moe_capacity_factor,
                     name="moe")(y.reshape(b * s, d)).reshape(b, s, d)
-        else:
+        elif self.feed_forward == "gelu":
             y = nn.Dense(cfg.d_ff, dtype=cfg.dtype, use_bias=False)(y)
             y = nn.gelu(y)
             y = nn.Dense(cfg.d_model, dtype=cfg.dtype, use_bias=False)(y)
+        elif self.feed_forward == "swiglu":
+            from horovod_tpu.models.experts import SwiGLU
+            y = SwiGLU(cfg.d_ff, dtype=cfg.dtype, name="mlp")(y)
+        elif self.feed_forward == "experts":
+            from horovod_tpu.models.experts import ExpertShare, SwiGLU
+            e = cfg.experts
+            # the share's buffers hold every token-slot, k times the
+            # tokens, most of them an absent expert's: recomputed in the
+            # backward pass, never kept
+            routed = nn.remat(ExpertShare)(e, dtype=cfg.dtype,
+                                           name="experts")(
+                y.reshape(b * s, d)).reshape(b, s, d)
+            y = routed + SwiGLU(e.n_shared_experts * e.moe_d_ff,
+                                dtype=cfg.dtype, name="shared_experts")(y)
+        else:
+            raise ValueError(f"unknown feed-forward {self.feed_forward!r}")
         if cache is not None:
             return x + y, new_kv
         return x + y
@@ -246,10 +303,8 @@ class Transformer(nn.Module):
             x = nn.Embed(cfg.vocab_size, cfg.d_model,
                          dtype=cfg.dtype, name="embed")(tokens)
             new_ks, new_vs = [], []
-            for i in range(cfg.num_layers):
-                use_moe = (cfg.moe_every > 0
-                           and (i + 1) % cfg.moe_every == 0)
-                x, (nk, nv) = Block(cfg, use_moe=use_moe,
+            for i, (mixer, feed_forward) in enumerate(cfg.layers()):
+                x, (nk, nv) = Block(cfg, mixer, feed_forward,
                                     name=f"block_{i}")(
                     x, positions, False,
                     (ctx_k[i], ctx_v[i], ctx_positions))
@@ -267,9 +322,8 @@ class Transformer(nn.Module):
                                           tokens.shape[0], tokens.shape[1])
         x = nn.Embed(cfg.vocab_size, cfg.d_model,
                      dtype=cfg.dtype, name="embed")(tokens)
-        for i in range(cfg.num_layers):
-            use_moe = cfg.moe_every > 0 and (i + 1) % cfg.moe_every == 0
-            x = Block(cfg, use_moe=use_moe,
+        for i, (mixer, feed_forward) in enumerate(cfg.layers()):
+            x = Block(cfg, mixer, feed_forward,
                       name=f"block_{i}")(x, positions, contiguous)
         x = nn.RMSNorm(dtype=cfg.dtype)(x)
         logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=False,

@@ -9,6 +9,11 @@ matrix never exists and VMEM usage is bounded by the block sizes, not
 the sequence length (reference role: the fused attention kernels every
 CUDA framework hand-writes; see /opt/skills/guides/pallas_guide.md).
 
+Two head sizes: q and k are ``d_qk`` wide, v, the output, dO and dV
+``d_v`` wide. Every caller but latent attention (models/mla.py: keys
+carry a 64-wide rotary part the values lack, 192 against 128) passes
+one size for both, and the kernels are the same program then.
+
 Sequence-parallel composition: ``q_offset``/``kv_offset`` give the
 absolute position of the first query/key token. They ride a
 scalar-prefetch argument (SMEM), so traced values — e.g. derived from
@@ -150,16 +155,19 @@ def _kernel_lse(off_ref, q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref,
 
 def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
                     interpret, with_lse=False):
-    """q: [BH, Sq, D]; k/v: [BH, Skv, D]; offsets: int32[2] -> [BH, Sq, D]
-    (plus fp32 [BH, Sq, 1] log-sum-exp rows when ``with_lse`` — the
-    trailing singleton satisfies Mosaic's last-two-dims tiling rule)."""
+    """q: [BH, Sq, Dqk]; k: [BH, Skv, Dqk]; v: [BH, Skv, Dv]; offsets:
+    int32[2] -> [BH, Sq, Dv] (plus fp32 [BH, Sq, 1] log-sum-exp rows when
+    ``with_lse`` — the trailing singleton satisfies Mosaic's
+    last-two-dims tiling rule). The two head sizes are one for every
+    caller but latent attention, whose keys carry a rotary part the
+    values lack."""
     bh, sq, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[2]
     kw = dict(block_q=block_q, block_k=block_k, causal=causal,
               sm_scale=sm_scale)
     kern = functools.partial(_kernel_lse if with_lse else _kernel, **kw)
-    out_specs = pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0))
-    out_shape = jax.ShapeDtypeStruct((bh, sq, d), q.dtype)
+    out_specs = pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0))
+    out_shape = jax.ShapeDtypeStruct((bh, sq, dv), q.dtype)
     if with_lse:
         # lse rides as [BH, Sq, 1]: a (1, bq, 1) block satisfies the
         # Mosaic last-two-dims tiling rule where a 2-D (1, bq) cannot
@@ -174,13 +182,13 @@ def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j, *_: (b, j, 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # m
             pltpu.VMEM((block_q, 1), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),   # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),  # acc
         ],
     )
     return pl.pallas_call(
@@ -315,19 +323,22 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     of the 128 MiB a v5e core has; past ``sq * d`` = 8 Mi elements the
     compile fails with Mosaic's out-of-VMEM message)."""
     bh, sq, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[2]
     # grads mirror their primal dtypes (custom_vjp aval contract) unless
     # the caller wants uniform fp32 partials for accumulation
     dq_dtype = jnp.dtype(out_dtype or q.dtype)
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
+    # q, k, dq, dk at the score width d; v, dO, dv at the value width
     qspec = pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0))
+    gspec = pl.BlockSpec((1, block_q, dv), lambda b, j, i, *_: (b, i, 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0))
+    vspec = pl.BlockSpec((1, block_k, dv), lambda b, j, i, *_: (b, j, 0))
     dqspec = pl.BlockSpec((1, sq, d), lambda b, j, i, *_: (b, 0, 0))
     # row statistics ride as [BH, 1, Sq]: lane-dense (1, block_q) rows
     rowspec = pl.BlockSpec((1, 1, block_q), lambda b, j, i, *_: (b, 0, i))
     scratch = [pltpu.VMEM((block_k, d), jnp.float32),   # dk
-               pltpu.VMEM((block_k, d), jnp.float32)]   # dv
+               pltpu.VMEM((block_k, dv), jnp.float32)]  # dv
     resident = 2 * sq * d * dq_dtype.itemsize
     if dq_dtype != jnp.float32:
         scratch.append(pltpu.VMEM((1, sq, d), jnp.float32))
@@ -338,13 +349,13 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(bh, skv // block_k, sq // block_q),
-            in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
-            out_specs=(dqspec, kspec, kspec),
+            in_specs=[qspec, kspec, vspec, gspec, rowspec, rowspec],
+            out_specs=(dqspec, kspec, vspec),
             scratch_shapes=scratch,
         ),
         out_shape=(jax.ShapeDtypeStruct((bh, sq, d), dq_dtype),
                    jax.ShapeDtypeStruct((bh, skv, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, skv, d), dv_dtype)),
+                   jax.ShapeDtypeStruct((bh, skv, dv), dv_dtype)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_BLOCK_VMEM_BYTES + resident),
@@ -404,9 +415,10 @@ def _block_ok(n, preferred):
 
 
 def kernel_supported(sq, skv, d, block_q=DEFAULT_BLOCK_Q,
-                     block_k=DEFAULT_BLOCK_K):
+                     block_k=DEFAULT_BLOCK_K, d_v=None):
     """True when these shapes tile onto the kernel (callers use this to
-    fall back to the plain-XLA path)."""
+    fall back to the plain-XLA path). ``d`` is the head size of q and k,
+    ``d_v`` that of v and the output where it differs."""
     # incremental-decode shapes (q_len == 1 — one new token per sequence
     # against a long cached K/V, the serve/engine.py hot loop) can never
     # tile onto an MXU-floor block: route them to the dense path
@@ -418,13 +430,14 @@ def kernel_supported(sq, skv, d, block_q=DEFAULT_BLOCK_Q,
     # blocks must respect the fp32 sublane tile (8) or Mosaic can
     # reject the lowering — the fallback contract depends on this gate —
     # and clear the MXU floor, or the dense fallback is faster
-    return (d % 8 == 0 and _block_ok(sq, block_q)
+    return (d % 8 == 0 and (d_v or d) % 8 == 0 and _block_ok(sq, block_q)
             and _block_ok(skv, block_k))
 
 
 def _prep(q, k, v, sm_scale, block_q, block_k, interpret):
-    """Shared prologue: defaulting, tiling validation, and the
-    [B,S,H,D] -> [BH,S,D] relayout."""
+    """Shared prologue: defaulting and tiling validation. q and k share
+    one head size, v may have another (the output's); the default scale
+    is the scores'."""
     on_tpu = jax.devices()[0].platform == "tpu"
     if interpret is None:
         interpret = not on_tpu
@@ -433,19 +446,29 @@ def _prep(q, k, v, sm_scale, block_q, block_k, interpret):
             "flash attention kernels are not interpreted in a process "
             "whose devices are TPUs; drop interpret=True")
     b, sq, h, d = q.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[-1]
+    if k.shape[-1] != d:
+        raise ValueError(f"flash_attention: q and k must share a head "
+                         f"size (q {d}, k {k.shape[-1]})")
     sm_scale = sm_scale if sm_scale is not None else 1.0 / (float(d) ** 0.5)
     bq, bk = _fit_block(sq, block_q), _fit_block(skv, block_k)
-    if bq == 0 or bk == 0 or d % 8 != 0:
+    if bq == 0 or bk == 0 or d % 8 != 0 or dv % 8 != 0:
         raise ValueError(
             f"flash_attention needs a block (divisible by 8) that divides "
-            f"S, and d % 8 == 0 (sq={sq}, skv={skv}, d={d}); use "
+            f"S, and d % 8 == 0 (sq={sq}, skv={skv}, d={d}, d_v={dv}); use "
             f"ops.flash_attention.attention for automatic fallback")
 
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+    return (b, sq, h), sm_scale, bq, bk, interpret
 
-    return to_bh, (b, sq, h, d), sm_scale, bq, bk, interpret
+
+def _to_bh(x):
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _from_bh(x, b):
+    bh, s, d = x.shape
+    return x.reshape(b, bh // b, s, d).transpose(0, 2, 1, 3)
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
@@ -457,13 +480,13 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
     query/key token; ints or traced int32 scalars both work (they ride a
     scalar-prefetch argument), so a sequence-parallel shard can pass
     ``lax.axis_index(...) * s_local`` for a rotated K/V block."""
-    to_bh, (b, sq, h, d), sm_scale, bq, bk, interpret = _prep(
+    (b, _, _), sm_scale, bq, bk, interpret = _prep(
         q, k, v, sm_scale, block_q, block_k, interpret)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
-    out = _flash(to_bh(q), to_bh(k), to_bh(v), offsets, causal, sm_scale,
+    out = _flash(_to_bh(q), _to_bh(k), _to_bh(v), offsets, causal, sm_scale,
                  bq, bk, interpret)
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return _from_bh(out, b)
 
 
 def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
@@ -476,14 +499,14 @@ def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
     attention runs it per rotated K/V block and merges results by lse
     weighting (parallel/ring.py). Differentiation happens at the ring
     level, so this call is deliberately VJP-free."""
-    to_bh, (b, sq, h, d), sm_scale, bq, bk, interpret = _prep(
+    (b, sq, h), sm_scale, bq, bk, interpret = _prep(
         q, k, v, sm_scale, block_q, block_k, interpret)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
-    out, lse = _flash_fwd_impl(to_bh(q), to_bh(k), to_bh(v), offsets,
+    out, lse = _flash_fwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), offsets,
                                causal, sm_scale, bq, bk, interpret,
                                with_lse=True)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    out = _from_bh(out, b)
     lse = lse.reshape(b, h, sq).transpose(0, 2, 1)  # [BH,Sq,1] -> [B,S,H]
     return out, lse
 
@@ -504,7 +527,7 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
     because p = exp(s - LSE) factorizes per block once LSE is global —
     the ring backward never materializes an S x S score matrix
     (parallel/ring.py ``_ring_attention_flash``)."""
-    to_bh, (b, sq, h, d), sm_scale, bq, bk, interpret = _prep(
+    (b, sq, h), sm_scale, bq, bk, interpret = _prep(
         q, k, v, sm_scale, block_q, block_k, interpret)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
@@ -513,33 +536,25 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
         return x.transpose(0, 2, 1).reshape(b * h, sq)
 
     dq, dk, dv = _flash_bwd_core(
-        to_bh(q), to_bh(k), to_bh(v), to_bh(g), rows_bh(lse),
+        _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g), rows_bh(lse),
         rows_bh(delta), offsets, causal, sm_scale, bq, bk, interpret,
         out_dtype=jnp.float32)
 
-    def from_bh(x, s):
-        return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
-
-    skv = k.shape[1]
-    return from_bh(dq, sq), from_bh(dk, skv), from_bh(dv, skv)
+    return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b)
 
 
 def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0):
     """flash_attention, giving way to the plain-XLA path (with a
     :class:`FlashFallbackWarning`) when shapes don't tile onto the
     kernel blocks."""
-    b, sq, h, d = q.shape
+    b, sq, _, d = q.shape
     skv = k.shape[1]
-    if kernel_supported(sq, skv, d):
+    if kernel_supported(sq, skv, d, d_v=v.shape[-1]):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
                                kv_offset=kv_offset)
     warn_fallback("ops.flash_attention.attention", q.shape, skv,
                   "the shapes do not tile onto the kernel's blocks")
     offsets = jnp.asarray([q_offset, kv_offset], jnp.int32)
-
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
-
-    out = _reference_attention(to_bh(q), to_bh(k), to_bh(v), offsets,
+    out = _reference_attention(_to_bh(q), _to_bh(k), _to_bh(v), offsets,
                                causal, 1.0 / (float(d) ** 0.5))
-    return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return _from_bh(out, b)

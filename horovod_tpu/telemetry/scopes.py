@@ -23,6 +23,13 @@ import jax
 EXCHANGE = "hvd_exchange"    # pack, pad, compress, collective, unpack, divide
 OPTIMIZER = "hvd_optimizer"  # the inner transform's update, apply_updates
 LOSS = "hvd_loss"            # the loss after the model's last layer
+# latent attention outside its kernel: the latent projections and norm,
+# rotary, k from k_nope and the shared k_pe, the output projection
+MLA = "hvd_mla"
+# router product, scores, top-k, sort, gather into expert order, the
+# weighted way back
+MOE_ROUTE = "hvd_moe_route"
+MOE_EXPERTS = "hvd_moe_experts"  # the grouped products and their SwiGLU
 # host spans
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding

@@ -87,6 +87,41 @@ def test_flash_kernel_compiles_for_v5e(v5e, shape, fn, kernels):
         "in the compiled program")
 
 
+@pytest.mark.parametrize("fn,kernels", [
+    (_forward, 1), (_forward_backward, 2), (_lse_and_blockwise_backward, 2)],
+    ids=["forward", "forward_backward", "lse_blockwise_backward"])
+def test_flash_kernel_compiles_for_v5e_at_two_head_sizes(v5e, fn, kernels):
+    """Latent attention's heads at the shape of the
+    ``kanana-2-30b-a3b-train-s4096`` cell: q and k 192 wide (not a
+    multiple of the 128 lanes), v and o 128 wide, 4 x 4096 x 32 heads."""
+    qk = jax.ShapeDtypeStruct((4, 4096, 32, 192), jnp.bfloat16, sharding=v5e)
+    v = jax.ShapeDtypeStruct((4, 4096, 32, 128), jnp.bfloat16, sharding=v5e)
+    text = jax.jit(fn).lower(qk, qk, v).compile().as_text()
+    assert text.count("tpu_custom_call") == kernels
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1536), (768, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_product_compiles_for_v5e(v5e, k, n):
+    """The expert share's grouped products under ``experts._gmm``'s own
+    VJP, at the sizes of the ``kanana-2-30b-a3b-train-s4096`` cell
+    (98,304 token-slots, 16 experts held): three Mosaic kernels, the
+    product, the input gradient (``transpose_rhs``) and the weight
+    gradient (``tgmm``), each with the tiling ``experts._tile`` picks."""
+    from horovod_tpu.models import experts
+
+    def forward_backward(xs, w, sizes, g):
+        out, vjp = jax.vjp(lambda xs, w: experts._gmm(xs, w, sizes), xs, w)
+        return (out,) + vjp(g)
+
+    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    text = jax.jit(forward_backward).lower(
+        shaped(98304, k), shaped(16, k, n), shaped(16, dtype=jnp.int32),
+        shaped(98304, n)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
 def test_chip_smoke_refuses_to_run_without_a_chip():
     """chip_smoke.py on a machine whose jax finds no TPU runs no phase,
     prints no result and exits non-zero naming the platform it found —

@@ -455,3 +455,66 @@ def test_bwd_block_partials_are_f32_and_sum_to_the_full_gradient(dtype,
     ref = jax.grad(f_ref, argnums=(0, 1, 2))(q32, k32, v32)
     for a, r in zip((dq, dk, dv), ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r), atol=atol)
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-3),
+                                        (jnp.bfloat16, 6e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_two_head_sizes_match_reference(causal, dtype, atol):
+    """Latent attention's heads: q and k 192 wide (128 + 64 rotary), v,
+    o, dO and dV 128 wide. Forward and all three gradients against
+    ``_reference_attention`` at 2 x 2 blocks; the default scale is the
+    scores' (1/sqrt(192))."""
+    rng = np.random.default_rng(192128)
+    b, s, h, d_qk, d_v = 1, 256, 2, 192, 128
+    q = jnp.asarray(rng.standard_normal((b, s, h, d_qk)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, s, h, d_qk)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s, h, d_v)), dtype)
+
+    def f_flash(q, k, v):
+        out = fa.flash_attention(q, k, v, causal=causal, block_q=128,
+                                 block_k=128)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    (_, out), gf = jax.value_and_grad(f_flash, argnums=(0, 1, 2),
+                                      has_aux=True)(q, k, v)
+    assert out.shape == (b, s, h, d_v) and out.dtype == dtype
+    f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+    off = jnp.zeros(2, jnp.int32)
+
+    def f_ref(q, k, v):
+        r = fa._reference_attention(_to_bh(q), _to_bh(k), _to_bh(v), off,
+                                    causal, 1.0 / d_qk ** 0.5)
+        return jnp.sum(r ** 2), r
+
+    (_, ref), gr = jax.value_and_grad(f_ref, argnums=(0, 1, 2),
+                                      has_aux=True)(f32(q), f32(k), f32(v))
+    np.testing.assert_allclose(
+        np.asarray(f32(_to_bh(out))), np.asarray(ref), atol=atol)
+    for a, r, x in zip(gf, gr, (q, k, v)):
+        assert a.shape == x.shape and a.dtype == dtype
+        scale = float(jnp.max(jnp.abs(r)))
+        np.testing.assert_allclose(np.asarray(f32(a)), np.asarray(r),
+                                   atol=atol * max(1.0, scale))
+
+
+def test_two_head_sizes_fallback_and_gate():
+    """The fallback rules cover the new shape: the gate takes ``d_v``,
+    ``attention`` runs the kernel at 192 / 128 where the blocks fit and
+    the plain path (same numbers, with a warning) where they do not,
+    and q and k of different widths are refused."""
+    assert fa.kernel_supported(4096, 4096, 192, d_v=128)
+    assert not fa.kernel_supported(4096, 4096, 192, d_v=100)
+    rng = np.random.default_rng(5)
+    mk = lambda s, d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, s, 2, d)), jnp.float32)
+    q, k, v = mk(1048, 192), mk(1048, 192), mk(1048, 128)  # block 8
+    with pytest.warns(fa.FlashFallbackWarning):
+        plain = fa.attention(q, k, v)
+    assert plain.shape == (1, 1048, 2, 128)
+    q, k, v = q[:, :128], k[:, :128], v[:, :128]
+    np.testing.assert_allclose(np.asarray(fa.attention(q, k, v)),
+                               np.asarray(plain[:, :128]), atol=2e-5)
+    with pytest.raises(ValueError, match="share a head size"):
+        fa.flash_attention(q, k[..., :128], v)
