@@ -12,7 +12,9 @@ points users call, at the full width of two models the repo supports:
 * ``flash_kernel``  the Pallas flash kernel's forward and gradients against
                     ``_reference_attention`` at the head shapes of the
                     main path and of latent attention (192 / 128), on the
-                    chip (not the interpreter).
+                    chip (not the interpreter); then the forward alone
+                    and forward + backward timed, beside the kernel's
+                    ``block_schedule`` (interior / diagonal / skipped pairs).
 * ``grouped_kernel`` the expert share's grouped products (megablox under
                     ``models/experts._gmm``'s VJP) against a loop over the
                     groups: result, input and weight gradient.
@@ -260,6 +262,19 @@ def _timed_steps(step_once, n):
     return losses, seconds
 
 
+def _ms_per_call(fn, args, calls=10):
+    """Host milliseconds a call of ``fn(*args)``, ``calls`` of them queued
+    back to back behind a warm one and ended by one wait."""
+    import jax
+
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / calls * 1e3, 3)
+
+
 def phase_flash_kernel():
     import jax
     import jax.numpy as jnp
@@ -321,8 +336,22 @@ def phase_flash_kernel():
                         f"_reference_attention: {errors[name]} (allowed "
                         f"rel_l2 {KERNEL_REL_L2}, max_abs "
                         f"{KERNEL_ATOL[name]})")
+            # information, not claims: the forward alone and forward +
+            # backward, each with the [B, S, H, D] <-> [BH, S, D] copies
+            # around its kernels, and how many block pairs of a
+            # batch*head lie under the diagonal, on it, and are skipped
+            forward = jax.jit(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True))
             results.append({"shape": list(shape), "dtype": "bfloat16",
-                            "tpu_custom_calls": kernels, "errors": errors})
+                            "tpu_custom_calls": kernels, "errors": errors,
+                            "block_schedule": {
+                                "forward": fa.block_schedule(
+                                    s, s, *fa.FORWARD_BLOCKS),
+                                "backward": fa.block_schedule(
+                                    s, s, *fa.BACKWARD_BLOCKS)},
+                            "forward_ms": _ms_per_call(forward, (q, k, v)),
+                            "forward_backward_ms": _ms_per_call(
+                                kernel, (q, k, v, w))})
     _emit("flash_kernel", shapes=results,
           tolerance={"rel_l2": KERNEL_REL_L2, "max_abs": KERNEL_ATOL},
           **watch.fields())

@@ -52,9 +52,9 @@ class TransformerConfig:
     # gives way to plain XLA attention, with a FlashFallbackWarning,
     # when shapes don't tile or explicit positions are passed.
     # None (default) = auto: ON when running on TPU with local seq >=
-    # 1024 (with bf16 operands and 512x512 blocks the kernel's lead over
-    # dense attention grows with sequence length and is gone by 512; not
-    # measured in this round). OFF elsewhere (interpret mode would
+    # 1024 (with bf16 operands and its default blocks the kernel's lead
+    # over dense attention grows with sequence length and is gone by 512;
+    # not measured in this round). OFF elsewhere (interpret mode would
     # crawl). Set True/False to force.
     flash_attention: Optional[bool] = None
     # Sparse-FFN blocks: every `moe_every`-th block (1-based; 0 = dense

@@ -9,6 +9,26 @@ matrix never exists and VMEM usage is bounded by the block sizes, not
 the sequence length (reference role: the fused attention kernels every
 CUDA framework hand-writes; see /opt/skills/guides/pallas_guide.md).
 
+Both kernels build a block's scores TRANSPOSED (S^T = K Q^T, a
+(block_k, block_q) tile), so everything that exists once per query — the
+running maximum and sum, the rescale of the accumulator, ``lse``,
+``delta`` — is one lane of a (1, block_q) row: reductions run down
+sublanes, element-wise over registers, and a row broadcasts down
+sublanes for free. The forward accumulates acc^T = V^T P^T and
+transposes once when a q-block is done; ``lse`` leaves it as the rows
+the backward reads ([BH, 1, Sq]).
+
+The causal block schedule is read from the block's position (the
+offsets ride in SMEM), never from a caller: a block pair is *skipped*
+(its last query before its first key), *interior* (every score visible)
+or *diagonal* (``_block_kind``; ``block_schedule`` counts them). A
+skipped grid step runs nothing AND fetches nothing: the index maps of
+the tiles that stream (k, v in the forward; q, dO, lse, delta in the
+backward) clamp to the nearest block that does run, so the pipeline
+sees an unchanged block index and issues no copy. Interior and diagonal
+pairs run ONE body, masked: the mask's iota, compare and select hide
+behind the matmuls, and a second unmasked body measured slower.
+
 Two head sizes: q and k are ``d_qk`` wide, v, the output, dO and dV
 ``d_v`` wide. Every caller but latent attention (models/mla.py: keys
 carry a 64-wide rotary part the values lack, 192 against 128) passes
@@ -20,7 +40,7 @@ scalar-prefetch argument (SMEM), so traced values — e.g. derived from
 ``lax.axis_index`` inside a shard_map — work; a shard holding a rotated
 K/V block passes that block's global offset and the causal mask stays
 exact. A query row with no visible keys outputs zeros (not a spurious
-mean of V).
+mean of V) and ``lse = NEG_INF``.
 
 Gradients: custom VJP with **one fused backward kernel** on the grid
 (batch*head, kv-block, q-block). For every visible block pair it
@@ -43,24 +63,38 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on v5e (bf16 operands, causal, b8 s2048; the backward kernel
-# alone, host clock over 20 calls, PERF.md PR 26): 512x512 blocks run
-# the backward in 2.97 ms at h12 d64 and 3.30 ms at h16 d128; a 256
-# on either side costs 25-36% more (grid steps and re-fetched tiles), a
-# 1024 is within +-5% (3.01-3.12 and 3.13-3.39 ms) and 2048 is slower
-# again, so forward and backward share one size. Blocks clamp to the
-# sequence, so short inputs still tile.
+# Block sizes, measured on v5e (bf16 operands, causal; each kernel alone,
+# host clock over 20 calls, which reads 0.3-0.5 ms over the kernel's
+# device time; PERF.md PR 26 and PR 28). Blocks clamp to the sequence, so
+# short inputs still tile, and each kernel fits its default to the shapes
+# it sees (``_fit_block``).
+#
+# Backward, 512 x 512: 2.85 ms at b8 h12 s2048 d64, 2.93 at h16 d128,
+# 18.30 at b4 h32 s4096 192 / 128. A 256 on either side costs 25-36%
+# more (grid steps and re-fetched tiles); a 1024 on one side +1-6%, on
+# both +1.3%, +2.0%, -1.6%: not better at all three, so it stays.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
+BACKWARD_BLOCKS = (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
+# Forward, 1024 x 1024: 1.48 / 1.88 / 9.75 ms at the same three shapes,
+# against 1.64 / 2.19 / 10.76 at 512 x 512, 1.58 / 2.04 / 10.51 at
+# 512 x 1024 and 1.59 / 2.12 / 10.28 at 1024 x 512; a 256 on either side
+# 2.00-3.69 / 12.75-13.61, a 2048 on either side 1.85-1.94 / 2.19-2.34 /
+# 10.20-11.02. With two matmuls a step the forward's fixed cost a grid
+# step (about 0.35 us, skipped steps too) weighs more than the larger
+# masked share of a 1024-wide diagonal block. The 4 MiB score tile and
+# its temporaries fit Mosaic's default scoped VMEM, fp32 operands too.
+FORWARD_BLOCKS = (1024, 1024)
 NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))  # dot_general: a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # dot_general: a.T @ b
 # What a kernel's block-sized tiles and temporaries may take of VMEM:
-# Mosaic's own default scoped limit on v5e, which the 512x512 blocks fit
-# with room. The backward adds its sequence-sized dQ on top.
+# Mosaic's own default scoped limit on v5e, which both kernels' default
+# blocks fit. The backward adds its sequence-sized dQ on top.
 _BLOCK_VMEM_BYTES = 16 << 20
 
 
@@ -78,25 +112,84 @@ def warn_fallback(caller, q_shape, kv_len, reason):
         f"{kv_len} keys: {reason}", FlashFallbackWarning, stacklevel=3)
 
 
-def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            *, block_q, block_k, causal, sm_scale, lse_ref=None):
-    """One (bh, q-block, kv-block) grid step. Scratch (m, l, acc) carries
-    the online-softmax state across the innermost kv dimension."""
+def _block_kind(q_first, kv_first, block_q, block_k, causal):
+    """Where one (q-block, kv-block) pair lies against the causal
+    diagonal, from the absolute positions of its first query and first
+    key: ``(skipped, interior)``. *Skipped*: the block's last query comes
+    before its first key, nothing is visible and nothing runs.
+    *Interior*: the first query is at or after the last key, every score
+    is visible and no mask is needed. Neither: the diagonal crosses the
+    block. Without ``causal`` every pair is interior. Plain comparisons,
+    so Python ints, numpy arrays (``block_schedule``) and the kernels'
+    traced int32 scalars all pass through it; the two index-map helpers
+    below are the same ``skipped`` inequality solved for a block index."""
+    if not causal:
+        return False, True
+    skipped = q_first + (block_q - 1) < kv_first
+    interior = q_first >= kv_first + (block_k - 1)
+    return skipped, interior
+
+
+def _last_visible_kv(i, off_ref, block_q, block_k, nkv):
+    """Index of the last kv block that q block ``i`` does not skip
+    (``kv_first <= q_last``), clamped into the grid. The forward's k and
+    v index maps take ``min(j, this)``: a skipped step then names the
+    block already in VMEM and the pipeline issues no copy."""
+    reach = off_ref[0] + (i + 1) * block_q - 1 - off_ref[1]
+    return jax.lax.div(jnp.clip(reach, 0, nkv * block_k - 1), block_k)
+
+
+def _first_visible_q(j, off_ref, block_q, block_k, nq):
+    """Index of the first q block that kv block ``j`` does not skip,
+    clamped into the grid; the backward's q, dO, lse and delta index maps
+    take ``max(i, this)`` (q is its inner grid dimension)."""
+    reach = off_ref[1] + j * block_k - off_ref[0]
+    return jax.lax.div(jnp.clip(reach, 0, nq * block_q - 1), block_q)
+
+
+def block_schedule(sq, skv, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                   q_offset=0, kv_offset=0, causal=True):
+    """How many block pairs of one batch*head lie wholly under the causal
+    diagonal, are crossed by it, and are skipped (nothing run, nothing
+    fetched): counts from the classification the kernels themselves use,
+    at the blocks they would fit. At 512 x 512
+    ``{"interior": 6, "diagonal": 4, "skipped": 6}`` at s2048 and
+    28 / 8 / 28 at s4096."""
+    bq, bk = _fit_block(sq, block_q), _fit_block(skv, block_k)
+    q_first = q_offset + np.arange(sq // bq)[:, None] * bq
+    kv_first = kv_offset + np.arange(skv // bk)[None, :] * bk
+    skipped, interior = (
+        np.broadcast_to(kind, (sq // bq, skv // bk))
+        for kind in _block_kind(q_first, kv_first, bq, bk, causal))
+    return {"interior": int(interior.sum()),
+            "diagonal": int((~skipped & ~interior).sum()),
+            "skipped": int(skipped.sum())}
+
+
+def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
+            causal, sm_scale):
+    """One (bh, q-block, kv-block) grid step. The score tile is built
+    transposed (S^T = K Q^T, ``(block_k, block_q)``, as in the backward),
+    so one query's statistics are one lane of a ``(1, block_q)`` row: the
+    running maximum and sum reduce down sublanes (element-wise over
+    registers) and ``s - m``, the rescale of the accumulator and the
+    final division broadcast a row down sublanes, which is free, where a
+    ``(block_q, 1)`` column costs a cross-lane reduction and a lane
+    broadcast each. Scratch (m, l, acc^T) carries the online-softmax
+    state across the innermost kv dimension; ``rest`` is (lse, m, l, acc)
+    when the log-sum-exp rows are an output, else (m, l, acc)."""
+    *lse_out, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(1)
     j = pl.program_id(2)
     nkv = pl.num_programs(2)
-    q_off = off_ref[0]
-    kv_off = off_ref[1]
+    q_first = off_ref[0] + i * block_q
+    kv_first = off_ref[1] + j * block_k
 
     @pl.when(j == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    q_pos = (q_off + i * block_q
-             + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
-    kv_start = kv_off + j * block_k
 
     def _update():
         # matmuls run on NATIVE-dtype operands (bf16 inputs hit the
@@ -107,88 +200,94 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
-        s = jnp.dot(q, k.T,
-                    preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            kv_pos = (kv_start +
-                      jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-            s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
+        s = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32) * sm_scale
         m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # rows with nothing visible yet keep p = 0, so a fully-masked
-        # query outputs zeros instead of a spurious mean of V
-        p = jnp.where(m_new <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
-        scale = jnp.where(m <= NEG_INF / 2, 0.0, jnp.exp(m - m_new))
-        l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=-1, keepdims=True)
+        if causal:
+            q_pos = q_first + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_q), 1)
+            kv_pos = kv_first + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0)
+            s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        # a query with nothing visible yet (m_new still NEG_INF)
+        # subtracts 0 from its all-NEG_INF scores, so p = 0 and a
+        # fully-masked query outputs zeros, not a spurious mean of V:
+        # the guard is a row, not a second select over the tile
+        p = jnp.exp(s - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
+        # m starts at the finite NEG_INF: exp(m - m_new) is 0 once a
+        # query has seen a key, and 1 times l = acc = 0 before that
+        scale = jnp.exp(m - m_new)
+        l_ref[:] = l_ref[:] * scale + jnp.sum(p, axis=0, keepdims=True)
         # p cast to the value dtype for the MXU (the standard flash
-        # choice); accumulation stays fp32 in scratch
-        acc_ref[:] = acc_ref[:] * scale + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        # choice); accumulation stays fp32 in scratch: acc^T += V^T P^T
+        acc_ref[:] = acc_ref[:] * scale + jax.lax.dot_general(
+            v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
     if causal:
         # skip kv blocks the causal mask kills entirely (scalar math
-        # only — extracting from a vector is a Mosaic dynamic_slice)
-        q_last = q_off + i * block_q + (block_q - 1)
-        pl.when(q_last >= kv_start)(_update)
+        # only — extracting from a vector is a Mosaic dynamic_slice).
+        # Every block that runs is masked: a second, unmasked body for
+        # interior blocks measured 0.5-1% SLOWER at all three benchmark
+        # shapes (PERF.md, PR 28) — the mask hides behind the matmuls
+        skipped, _ = _block_kind(q_first, kv_first, block_q, block_k, causal)
+        pl.when(jnp.logical_not(skipped))(_update)
     else:
         _update()
 
     @pl.when(j == nkv - 1)
     def _finalize():
         l = l_ref[:]
-        o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
-        if lse_ref is not None:
-            # log-sum-exp per query row; NEG_INF marks "nothing visible"
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_ref[:] / l_safe).T.astype(o_ref.dtype)
+        for lse_ref in lse_out:
+            # log-sum-exp per query; NEG_INF marks "nothing visible"
             # so cross-block combination gives this block zero weight
-            lse_ref[0] = jnp.where(
-                l == 0.0, NEG_INF,
-                m_ref[:] + jnp.log(jnp.where(l == 0.0, 1.0, l)))
-
-
-def _kernel_lse(off_ref, q_ref, k_ref, v_ref, o_ref, lse_out_ref, m_ref,
-                l_ref, acc_ref, **kw):
-    _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-            lse_ref=lse_out_ref, **kw)
+            lse_ref[0] = jnp.where(l == 0.0, NEG_INF,
+                                   m_ref[:] + jnp.log(l_safe))
 
 
 def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
                     interpret, with_lse=False):
     """q: [BH, Sq, Dqk]; k: [BH, Skv, Dqk]; v: [BH, Skv, Dv]; offsets:
-    int32[2] -> [BH, Sq, Dv] (plus fp32 [BH, Sq, 1] log-sum-exp rows when
-    ``with_lse`` — the trailing singleton satisfies Mosaic's
-    last-two-dims tiling rule). The two head sizes are one for every
-    caller but latent attention, whose keys carry a rotary part the
-    values lack."""
+    int32[2] -> [BH, Sq, Dv] (plus the fp32 log-sum-exp of every query as
+    lane-dense rows, [BH, 1, Sq], when ``with_lse``: what the backward's
+    ``rowspec`` reads). The two head sizes are one for every caller but
+    latent attention, whose keys carry a rotary part the values lack."""
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[2]
-    kw = dict(block_q=block_q, block_k=block_k, causal=causal,
-              sm_scale=sm_scale)
-    kern = functools.partial(_kernel_lse if with_lse else _kernel, **kw)
+    nkv = skv // block_k
+    kern = functools.partial(_kernel, block_q=block_q, block_k=block_k,
+                             causal=causal, sm_scale=sm_scale)
+
+    def kv_index(b, i, j, off_ref):
+        if causal:
+            j = jnp.minimum(
+                j, _last_visible_kv(i, off_ref, block_q, block_k, nkv))
+        return b, j, 0
+
     out_specs = pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0))
     out_shape = jax.ShapeDtypeStruct((bh, sq, dv), q.dtype)
     if with_lse:
-        # lse rides as [BH, Sq, 1]: a (1, bq, 1) block satisfies the
-        # Mosaic last-two-dims tiling rule where a 2-D (1, bq) cannot
         out_specs = (out_specs,
-                     pl.BlockSpec((1, block_q, 1),
-                                  lambda b, i, j, *_: (b, i, 0)))
+                     pl.BlockSpec((1, 1, block_q),
+                                  lambda b, i, j, *_: (b, 0, i)))
         out_shape = (out_shape,
-                     jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32))
+                     jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, sq // block_q, skv // block_k),
+        grid=(bh, sq // block_q, nkv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, *_: (b, j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # m
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l
-            pltpu.VMEM((block_q, dv), jnp.float32),  # acc
+            pltpu.VMEM((1, block_q), jnp.float32),   # m
+            pltpu.VMEM((1, block_q), jnp.float32),   # l
+            pltpu.VMEM((dv, block_q), jnp.float32),  # acc^T
         ],
     )
     return pl.pallas_call(
@@ -279,8 +378,9 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             ds, k, _TN, preferred_element_type=jnp.float32)
 
     if causal:
-        q_last = q_off + i * block_q + (block_q - 1)
-        pl.when(q_last >= kv_off + j * block_k)(_update)
+        skipped, _ = _block_kind(q_off + i * block_q, kv_off + j * block_k,
+                                 block_q, block_k, causal)
+        pl.when(jnp.logical_not(skipped))(_update)
     else:
         _update()
 
@@ -302,7 +402,7 @@ def _flash_bwd_impl(q, k, v, g, out, lse, offsets, causal, sm_scale,
     # delta_i = sum_d dO * O — the softmax-jacobian row correction
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [BH, Sq]
-    return _flash_bwd_core(q, k, v, g, lse[..., 0], delta, offsets, causal,
+    return _flash_bwd_core(q, k, v, g, lse[:, 0], delta, offsets, causal,
                            sm_scale, block_q, block_k, interpret)
 
 
@@ -329,14 +429,27 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     dq_dtype = jnp.dtype(out_dtype or q.dtype)
     dk_dtype = out_dtype or k.dtype
     dv_dtype = out_dtype or v.dtype
+    nq = sq // block_q
+
+    def q_block(j, i, off_ref):
+        # a skipped step names the first q block this kv block sees: the
+        # tiles already in VMEM, so the pipeline copies nothing for it
+        if causal:
+            i = jnp.maximum(
+                i, _first_visible_q(j, off_ref, block_q, block_k, nq))
+        return i
+
     # q, k, dq, dk at the score width d; v, dO, dv at the value width
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, j, i, *_: (b, i, 0))
-    gspec = pl.BlockSpec((1, block_q, dv), lambda b, j, i, *_: (b, i, 0))
+    qspec = pl.BlockSpec((1, block_q, d),
+                         lambda b, j, i, off: (b, q_block(j, i, off), 0))
+    gspec = pl.BlockSpec((1, block_q, dv),
+                         lambda b, j, i, off: (b, q_block(j, i, off), 0))
     kspec = pl.BlockSpec((1, block_k, d), lambda b, j, i, *_: (b, j, 0))
     vspec = pl.BlockSpec((1, block_k, dv), lambda b, j, i, *_: (b, j, 0))
     dqspec = pl.BlockSpec((1, sq, d), lambda b, j, i, *_: (b, 0, 0))
     # row statistics ride as [BH, 1, Sq]: lane-dense (1, block_q) rows
-    rowspec = pl.BlockSpec((1, 1, block_q), lambda b, j, i, *_: (b, 0, i))
+    rowspec = pl.BlockSpec((1, 1, block_q),
+                           lambda b, j, i, off: (b, 0, q_block(j, i, off)))
     scratch = [pltpu.VMEM((block_k, d), jnp.float32),   # dk
                pltpu.VMEM((block_k, dv), jnp.float32)]  # dv
     resident = 2 * sq * d * dq_dtype.itemsize
@@ -364,23 +477,23 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, offsets, causal, sm_scale, block_q, block_k,
+def _flash(q, k, v, offsets, causal, sm_scale, fwd_blocks, bwd_blocks,
            interpret):
-    return _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q,
-                           block_k, interpret)
+    return _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, *fwd_blocks,
+                           interpret)
 
 
-def _flash_fwd(q, k, v, offsets, causal, sm_scale, block_q, block_k,
+def _flash_fwd(q, k, v, offsets, causal, sm_scale, fwd_blocks, bwd_blocks,
                interpret):
     out, lse = _flash_fwd_impl(q, k, v, offsets, causal, sm_scale,
-                               block_q, block_k, interpret, with_lse=True)
+                               *fwd_blocks, interpret, with_lse=True)
     return out, (q, k, v, offsets, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, sm_scale, fwd_blocks, bwd_blocks, interpret, res, g):
     q, k, v, offsets, out, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, g, out, lse, offsets, causal,
-                                 sm_scale, block_q, block_k, interpret)
+                                 sm_scale, *bwd_blocks, interpret)
     return dq, dk, dv, None
 
 
@@ -434,10 +547,12 @@ def kernel_supported(sq, skv, d, block_q=DEFAULT_BLOCK_Q,
             and _block_ok(skv, block_k))
 
 
-def _prep(q, k, v, sm_scale, block_q, block_k, interpret):
+def _prep(q, k, v, sm_scale, block_q, block_k, interpret, *passes):
     """Shared prologue: defaulting and tiling validation. q and k share
     one head size, v may have another (the output's); the default scale
-    is the scores'."""
+    is the scores'. ``passes`` are the default ``(block_q, block_k)`` of
+    the kernels the caller will launch; each gets the caller's sizes, or
+    where it gave none its own default, fitted to the sequences."""
     on_tpu = jax.devices()[0].platform == "tpu"
     if interpret is None:
         interpret = not on_tpu
@@ -451,14 +566,16 @@ def _prep(q, k, v, sm_scale, block_q, block_k, interpret):
         raise ValueError(f"flash_attention: q and k must share a head "
                          f"size (q {d}, k {k.shape[-1]})")
     sm_scale = sm_scale if sm_scale is not None else 1.0 / (float(d) ** 0.5)
-    bq, bk = _fit_block(sq, block_q), _fit_block(skv, block_k)
-    if bq == 0 or bk == 0 or d % 8 != 0 or dv % 8 != 0:
+    blocks = [(_fit_block(sq, block_q or default_q),
+               _fit_block(skv, block_k or default_k))
+              for default_q, default_k in passes]
+    if 0 in sum(blocks, ()) or d % 8 != 0 or dv % 8 != 0:
         raise ValueError(
             f"flash_attention needs a block (divisible by 8) that divides "
             f"S, and d % 8 == 0 (sq={sq}, skv={skv}, d={d}, d_v={dv}); use "
             f"ops.flash_attention.attention for automatic fallback")
 
-    return (b, sq, h), sm_scale, bq, bk, interpret
+    return (b, sq, h), sm_scale, interpret, *blocks
 
 
 def _to_bh(x):
@@ -472,49 +589,49 @@ def _from_bh(x, b):
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
-                    kv_offset=0, block_q=DEFAULT_BLOCK_Q,
-                    block_k=DEFAULT_BLOCK_K, interpret=None):
+                    kv_offset=0, block_q=None, block_k=None, interpret=None):
     """Fused attention on [B, S, H, D] tensors (the transformer layout).
+    ``block_q``/``block_k`` left unset give each kernel its own default
+    (``FORWARD_BLOCKS``, ``BACKWARD_BLOCKS``); a given size holds for both.
 
     ``q_offset``/``kv_offset`` are the absolute positions of the first
     query/key token; ints or traced int32 scalars both work (they ride a
     scalar-prefetch argument), so a sequence-parallel shard can pass
     ``lax.axis_index(...) * s_local`` for a rotated K/V block."""
-    (b, _, _), sm_scale, bq, bk, interpret = _prep(
-        q, k, v, sm_scale, block_q, block_k, interpret)
+    (b, _, _), sm_scale, interpret, fwd_blocks, bwd_blocks = _prep(
+        q, k, v, sm_scale, block_q, block_k, interpret, FORWARD_BLOCKS,
+        BACKWARD_BLOCKS)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
     out = _flash(_to_bh(q), _to_bh(k), _to_bh(v), offsets, causal, sm_scale,
-                 bq, bk, interpret)
+                 fwd_blocks, bwd_blocks, interpret)
     return _from_bh(out, b)
 
 
 def flash_attention_with_lse(q, k, v, *, causal=True, sm_scale=None,
-                             q_offset=0, kv_offset=0,
-                             block_q=DEFAULT_BLOCK_Q,
-                             block_k=DEFAULT_BLOCK_K, interpret=None):
+                             q_offset=0, kv_offset=0, block_q=None,
+                             block_k=None, interpret=None):
     """Forward-only kernel call returning ``(out, lse)`` with
     ``lse[b, s, h]`` the log-sum-exp of each query row (NEG_INF when the
     row sees no keys). This is the blockwise-composition primitive: ring
     attention runs it per rotated K/V block and merges results by lse
     weighting (parallel/ring.py). Differentiation happens at the ring
     level, so this call is deliberately VJP-free."""
-    (b, sq, h), sm_scale, bq, bk, interpret = _prep(
-        q, k, v, sm_scale, block_q, block_k, interpret)
+    (b, sq, h), sm_scale, interpret, (bq, bk) = _prep(
+        q, k, v, sm_scale, block_q, block_k, interpret, FORWARD_BLOCKS)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
     out, lse = _flash_fwd_impl(_to_bh(q), _to_bh(k), _to_bh(v), offsets,
                                causal, sm_scale, bq, bk, interpret,
                                with_lse=True)
     out = _from_bh(out, b)
-    lse = lse.reshape(b, h, sq).transpose(0, 2, 1)  # [BH,Sq,1] -> [B,S,H]
+    lse = lse.reshape(b, h, sq).transpose(0, 2, 1)  # [BH,1,Sq] -> [B,S,H]
     return out, lse
 
 
 def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
                               sm_scale=None, q_offset=0, kv_offset=0,
-                              block_q=DEFAULT_BLOCK_Q,
-                              block_k=DEFAULT_BLOCK_K, interpret=None):
+                              block_q=None, block_k=None, interpret=None):
     """Per-block fused backward for blockwise/ring composition: given
     this rank's queries ``q`` [B,Sq,H,D], one rotated K/V block
     [B,Skv,H,D], the upstream ``g`` = dO, the **globally merged**
@@ -527,8 +644,8 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
     because p = exp(s - LSE) factorizes per block once LSE is global —
     the ring backward never materializes an S x S score matrix
     (parallel/ring.py ``_ring_attention_flash``)."""
-    (b, sq, h), sm_scale, bq, bk, interpret = _prep(
-        q, k, v, sm_scale, block_q, block_k, interpret)
+    (b, sq, h), sm_scale, interpret, (bq, bk) = _prep(
+        q, k, v, sm_scale, block_q, block_k, interpret, BACKWARD_BLOCKS)
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
 

@@ -100,6 +100,63 @@ def test_flash_kernel_compiles_for_v5e_at_two_head_sizes(v5e, fn, kernels):
     assert text.count("tpu_custom_call") == kernels
 
 
+def _index_maps(fn, *args):
+    """For every ``pallas_call`` under ``fn``: operand -> the primitives
+    of its block index map (empty for a map that only forwards grid
+    indices)."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    return [{bm.origin: {e.primitive.name
+                         for e in bm.index_map_jaxpr.jaxpr.eqns}
+             for bm in eqn.params["grid_mapping"].block_mappings}
+            for eqn in calls(jax.make_jaxpr(fn)(*args).jaxpr)]
+
+
+@pytest.mark.parametrize("shape", [(8, 2048, 12, 64), (4, 4096, 32, 192, 128)],
+                         ids=["8x2048x12x64", "4x4096x32x192-128"])
+def test_flash_kernel_skipped_steps_fetch_nothing(v5e, shape):
+    """With TRACED offsets (what ring attention passes for a rotated
+    block) forward, backward and the lse variant compile for the chip,
+    and the index maps are the clamped ones: the forward's k and v maps
+    take ``min(j, last visible kv block)`` and the backward's q, dO, lse
+    and delta maps ``max(i, first visible q block)``, so a skipped grid
+    step names the tile already in VMEM and the pipeline copies nothing;
+    q, the outputs and the backward's k, v keep their plain maps."""
+    qk = jax.ShapeDtypeStruct(shape[:3] + (shape[3],), jnp.bfloat16,
+                              sharding=v5e)
+    v = jax.ShapeDtypeStruct(shape[:3] + (shape[-1],), jnp.bfloat16,
+                             sharding=v5e)
+    off = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+
+    def train(q, k, v, qo, ko):
+        return jax.grad(lambda *x: jnp.sum(fa.flash_attention(
+            *x, q_offset=qo, kv_offset=ko, interpret=False).astype(
+                jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    def ring_block(q, k, v, qo, ko):
+        kw = dict(q_offset=qo, kv_offset=ko, interpret=False)
+        out, lse = fa.flash_attention_with_lse(q, k, v, **kw)
+        delta = jnp.sum(out.astype(jnp.float32) ** 2, axis=-1)
+        return fa.flash_attention_bwd_block(q, k, v, out, lse, delta, **kw)
+
+    for fn in (train, ring_block):
+        text = jax.jit(fn).lower(qk, qk, v, off, off).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        forward, backward = _index_maps(fn, qk, qk, v, off, off)
+        clamped = {name for name, prims in forward.items() if "min" in prims}
+        assert clamped == {"args[2]", "args[3]"}, forward       # k, v
+        assert not forward["args[1]"] and not forward["outputs[0]"]
+        clamped = {name for name, prims in backward.items() if "max" in prims}
+        assert clamped == {"args[1]", "args[4]", "args[5]", "args[6]"}, (
+            backward)                                           # q, dO, lse, delta
+        assert not backward["args[2]"] and not backward["args[3]"]
+
+
 @pytest.mark.parametrize("k,n", [(2048, 1536), (768, 2048)],
                          ids=["gate_up", "down"])
 def test_grouped_product_compiles_for_v5e(v5e, k, n):

@@ -518,3 +518,113 @@ def test_two_head_sizes_fallback_and_gate():
                                np.asarray(plain[:, :128]), atol=2e-5)
     with pytest.raises(ValueError, match="share a head size"):
         fa.flash_attention(q, k[..., :128], v)
+
+
+def _brute_force_schedule(sq, skv, bq, bk, qo, ko, causal):
+    """Block kinds counted from the element-wise mask itself."""
+    visible = np.ones((sq, skv), bool)
+    if causal:
+        visible = (qo + np.arange(sq))[:, None] >= (ko + np.arange(skv))[None]
+    blocks = visible.reshape(sq // bq, bq, skv // bk, bk)
+    some, every = blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
+    return {"interior": int(every.sum()),
+            "diagonal": int((some & ~every).sum()),
+            "skipped": int((~some).sum())}
+
+
+@pytest.mark.parametrize("sq,skv,bq,bk,qo,ko,causal,want", [
+    (2048, 2048, 512, 512, 0, 0, True, (6, 4, 6)),      # the lm-* cells
+    (4096, 4096, 512, 512, 0, 0, True, (28, 8, 28)),    # kanana-...
+    (2048, 2048, 512, 512, 0, 0, False, (16, 0, 0)),
+    (512, 512, 128, 128, 0, 0, True, (6, 4, 6)),
+    (512, 512, 128, 128, 256, 0, True, (13, 2, 1)),
+    (512, 512, 128, 128, 0, 256, True, (1, 2, 13)),
+    (512, 512, 128, 128, 0, 512, True, (0, 0, 16)),
+    (512, 512, 128, 128, 512, 0, True, (16, 0, 0)),     # a ring's earlier shard
+    (384, 256, 128, 128, 64, 192, True, None),           # diagonal off the blocks
+    (256, 512, 64, 128, 100, 37, True, None),            # rectangular, odd offsets
+    (1280, 1280, 512, 512, 0, 0, True, None),            # blocks fit down to 256
+], ids=lambda x: None if isinstance(x, tuple) else str(x))
+def test_block_schedule_counts_match_the_mask(sq, skv, bq, bk, qo, ko, causal,
+                                              want):
+    """``block_schedule`` — the classification the kernels branch on —
+    against a brute-force count over the element-wise mask, and the two
+    index-map clamps against the same classification: for every q block
+    the k/v map never names a block past the last one it runs, for every
+    kv block the q map never one before the first."""
+    got = fa.block_schedule(sq, skv, bq, bk, qo, ko, causal)
+    fbq, fbk = fa._fit_block(sq, bq), fa._fit_block(skv, bk)
+    assert got == _brute_force_schedule(sq, skv, fbq, fbk, qo, ko, causal)
+    assert sum(got.values()) == (sq // fbq) * (skv // fbk)
+    if want is not None:
+        assert (got["interior"], got["diagonal"], got["skipped"]) == want
+    if not causal:
+        return
+    nq, nkv = sq // fbq, skv // fbk
+    off = np.asarray([qo, ko], np.int32)
+    skipped = np.array([[bool(fa._block_kind(qo + i * fbq, ko + j * fbk, fbq,
+                                             fbk, True)[0])
+                         for j in range(nkv)] for i in range(nq)])
+    for i in range(nq):
+        last = int(fa._last_visible_kv(i, off, fbq, fbk, nkv))
+        run = np.flatnonzero(~skipped[i])
+        assert last == (run[-1] if run.size else 0)
+    for j in range(nkv):
+        first = int(fa._first_visible_q(j, off, fbq, fbk, nq))
+        run = np.flatnonzero(~skipped[:, j])
+        assert first == (run[0] if run.size else nq - 1)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 3e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("d_qk,d_v", [(64, 64), (192, 128)],
+                         ids=["d64", "d192-128"])
+@pytest.mark.parametrize("qo,ko", [(0, 0), (256, 0), (0, 256), (0, 512)],
+                         ids=["diag", "q-later", "kv-later", "kv-after"])
+def test_forward_and_lse_on_all_three_block_kinds(qo, ko, d_qk, d_v, causal,
+                                                  dtype, atol, traced):
+    """sq = skv = 512 in 128 x 128 blocks: interior, diagonal and skipped
+    pairs in one grid ((0, 0): 6 / 4 / 6), queries wholly after the keys
+    (256, 0), query rows with no visible key beside rows with some
+    (0, 256) and every pair skipped (0, 512). Output against
+    ``_reference_attention`` and ``lse`` against a plain ``logsumexp``;
+    a row that sees nothing gives zeros and ``NEG_INF``."""
+    rng = np.random.default_rng(qo + 2 * ko + d_qk)
+    b, s, h = 1, 512, 2
+    mk = lambda d: jnp.asarray(  # noqa: E731
+        rng.standard_normal((b, s, h, d)), dtype)
+    q, k, v = mk(d_qk), mk(d_qk), mk(d_v)
+
+    def f(q, k, v, qo, ko):
+        return fa.flash_attention_with_lse(
+            q, k, v, causal=causal, q_offset=qo, kv_offset=ko, block_q=128,
+            block_k=128)
+
+    if traced:
+        out, lse = jax.jit(f)(q, k, v, jnp.int32(qo), jnp.int32(ko))
+    else:
+        out, lse = f(q, k, v, qo, ko)
+    assert out.shape == (b, s, h, d_v) and out.dtype == dtype
+    assert lse.shape == (b, s, h) and lse.dtype == jnp.float32
+
+    sm_scale = 1.0 / d_qk ** 0.5
+    f32 = lambda x: _to_bh(x).astype(jnp.float32)  # noqa: E731
+    ref = fa._reference_attention(f32(q), f32(k), f32(v),
+                                  jnp.asarray([qo, ko], jnp.int32), causal,
+                                  sm_scale)
+    scores = jnp.einsum("bqd,bkd->bqk", f32(q), f32(k)) * sm_scale
+    visible = jnp.ones((s, s), bool)
+    if causal:
+        visible = (qo + jnp.arange(s))[:, None] >= (ko + jnp.arange(s))[None]
+    ref_lse = jax.nn.logsumexp(jnp.where(visible, scores, -jnp.inf), axis=-1)
+    seen = np.asarray(visible.any(axis=-1))
+    got = np.asarray(f32(out))
+    got_lse = np.asarray(lse.transpose(0, 2, 1).reshape(b * h, s))
+    np.testing.assert_allclose(got, np.asarray(ref), atol=atol)
+    np.testing.assert_allclose(got_lse[:, seen], np.asarray(ref_lse)[:, seen],
+                               atol=atol)
+    np.testing.assert_array_equal(got[:, ~seen], 0.0)
+    np.testing.assert_array_equal(got_lse[:, ~seen], np.float32(fa.NEG_INF))
