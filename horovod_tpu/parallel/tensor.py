@@ -37,7 +37,7 @@ import optax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.training import TrainState
+from horovod_tpu.training import TrainState, _next_token_ll
 
 
 def transformer_param_specs(params, model_axis="model", expert_axis=None):
@@ -111,11 +111,7 @@ def make_tp_lm_train_step(model, tx, mesh, model_axis="model",
         def compute_loss(params):
             logits, mutated = model.apply({"params": params}, tokens,
                                           mutable=["losses"])
-            targets = tokens[:, 1:]
-            logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32),
-                                      axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None],
-                                     axis=-1)[..., 0]
+            ll = _next_token_ll(logits, tokens[:, 1:])
             from horovod_tpu.models.moe import aux_loss
             return -jnp.mean(ll) + aux_loss(
                 mutated, load_balance_weight=moe_aux_weight,

@@ -204,7 +204,7 @@ class TimeLedger:
         collectives live inside the compiled step. Snapshots, dumps and
         ``hvd-doctor perf`` annotate the zero instead of implying no
         exposed comms — the device-side answer is ``hvd-doctor xray``.
-        Called by the spmd step wrappers; idempotent, a bool store."""
+        Called by the GSPMD steps' host scaffold; idempotent, a bool store."""
         self.compiled_path = True
 
     def phase(self, label, charge=None, health=True):

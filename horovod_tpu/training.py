@@ -13,7 +13,10 @@ writing their own steps.
 """
 
 import dataclasses
-from typing import Any, Optional
+import functools
+import time
+import warnings
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +24,14 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.ops import collective
+from horovod_tpu import basics, hvd_jax
+from horovod_tpu import telemetry as telemetry_lib
+from horovod_tpu.diag import recorder as _flightrec
+from horovod_tpu.ops import collective, fusion
+from horovod_tpu.parallel import gspmd as gspmd_lib
 from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.parallel import zero as zero_lib
+from horovod_tpu.telemetry import ledger as _ledger_lib
 from horovod_tpu.telemetry import scopes
 
 
@@ -77,8 +86,6 @@ def state_specs(state):
     Delegates to ``parallel/gspmd.state_partition_specs`` — ONE spec
     authority, shared by the explicit shard_map path, the GSPMD jit
     path, placement and checkpointing."""
-    from horovod_tpu.parallel import gspmd as gspmd_lib
-
     return gspmd_lib.state_partition_specs(state)
 
 
@@ -122,6 +129,423 @@ def _placer(mesh, spec):
                 x, jax.sharding.NamedSharding(mesh, s)), tree, spec)
 
     return place
+
+
+def _classification_grads(model, loss_fn, params, stats, inputs, labels,
+                          dropout_rng):
+    """Forward, loss and backward of one (micro)batch at fixed
+    ``params``: ``((loss, new batch_stats), grads)`` — ``{}`` for a
+    model that keeps no statistics."""
+    def compute_loss(params):
+        variables = {"params": params}
+        if stats:
+            variables["batch_stats"] = stats
+            logits, mutated = model.apply(
+                variables, inputs, train=True, mutable=["batch_stats"],
+                rngs={"dropout": dropout_rng})
+            new_stats = mutated["batch_stats"]
+        else:
+            logits = model.apply(variables, inputs, train=True,
+                                 rngs={"dropout": dropout_rng})
+            new_stats = {}
+        with scopes.device(scopes.LOSS):
+            return loss_fn(logits, labels), new_stats
+
+    return jax.value_and_grad(compute_loss, has_aux=True)(params)
+
+
+def _next_token_ll(logits, targets):
+    """Log-likelihood ``[B, T]`` of ``targets`` under next-token
+    ``logits`` (cut to the targets where they carry one position more),
+    in fp32. The reduction is the caller's: a masked sum over the global
+    count, a mean, a mean plus auxiliary terms."""
+    if targets.shape[1] == logits.shape[1] - 1:
+        logits = logits[:, :-1]
+    with scopes.device(scopes.LOSS):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.take_along_axis(logp, targets[..., None],
+                                   axis=-1)[..., 0]
+
+
+def _next_state(state, updates, opt_state, batch_stats, loss,
+                mean_over=None):
+    """How a step ends, ``(new state, loss)``: ``updates`` applied to
+    the params and the step counted. A per-shard (``shard_map``) step
+    names the axes to average over in ``mean_over``: its BatchNorm
+    statistics (per-shard normalization like the reference, one
+    consistent copy for checkpointing) and its loss are the shards'
+    means."""
+    with scopes.device(scopes.OPTIMIZER):
+        params = optax.apply_updates(state.params, updates)
+    if mean_over is not None:
+        if batch_stats:
+            batch_stats = jax.tree_util.tree_map(
+                lambda x: collective.allreduce(
+                    x, op=collective.Average, axes=mean_over), batch_stats)
+        with scopes.device(scopes.LOSS):
+            loss = collective.allreduce(loss, op=collective.Average,
+                                        axes=mean_over)
+    return TrainState(params=params, opt_state=opt_state,
+                      batch_stats=batch_stats, step=state.step + 1), loss
+
+
+def _grad_schedule(tx, reduce_axes, state, world):
+    """The ONE bucket schedule of a step's gradient exchange — the traced
+    step (world from the named axes) and the error-feedback carry (world
+    from the step's mesh) must shape against the same plan. Under ZeRO-1
+    the optimizer-state partition IS the schedule."""
+    if tx.sharded_update:
+        return state.opt_state.plan.schedule
+    return fusion.bucket_schedule(
+        jax.tree_util.tree_leaves(state.params), world=world,
+        threshold_bytes=tx.threshold_bytes, axes=reduce_axes,
+        hierarchical=tx._hierarchical_resolved())
+
+
+def _reduce_scatter_grads(tx, reduce_axes, state, grads, wire, residuals):
+    """From inside the per-shard step, reduce-scatter every bucket of one
+    gradient tree, at the ``wire`` format when there is one. Returns
+    ``(schedule, shards)``: this shard's 1/N of each bucket. ``residuals``
+    (error feedback: one per bucket, or None) are replaced in place by
+    the new quantization errors."""
+    schedule = _grad_schedule(tx, reduce_axes, state,
+                              collective.mesh_size(reduce_axes))
+    op = state.opt_state.plan.op if tx.sharded_update else tx.op
+    leaves = jax.tree_util.tree_leaves(grads)
+    shards = []
+    for i in range(len(schedule.buckets)):
+        if wire is None:
+            s = fusion.reduce_scatter_bucket(schedule, i, leaves, op=op)
+        else:
+            s, new_r = fusion.reduce_scatter_bucket_compressed(
+                schedule, i, leaves, wire, op=op,
+                residual=None if residuals is None else residuals[i])
+            if residuals is not None:
+                residuals[i] = new_r
+        shards.append(s)
+    return schedule, shards
+
+
+def _update_from_shards(tx, schedule, shards, state, wire, residuals):
+    """The exchange's tail, ``(updates, opt_state)`` from the reduced
+    gradient shards: the ZeRO-1 sharded update (no gradient all-gather
+    at all), or one all-gather per bucket and the inner optimizer.
+    ``residuals`` as in :func:`_reduce_scatter_grads`, for the
+    all-gather direction."""
+    if tx.sharded_update:
+        grad_rows = {f"b{i}": s[None] for i, s in enumerate(shards)}
+        out = zero_lib.apply_shards(
+            tx.inner, grad_rows, state.opt_state, state.params, wire=wire,
+            ag_residuals=residuals)
+        if residuals is not None:
+            residuals[:] = out[2]
+        return out[:2]
+    leaves, treedef = jax.tree_util.tree_flatten(state.params)
+    new_leaves = [None] * len(leaves)
+    for i, s in enumerate(shards):
+        if wire is None:
+            flat = fusion.all_gather_bucket(schedule, i, s)
+        else:
+            flat, new_r = fusion.all_gather_bucket_compressed(
+                schedule, i, s, wire,
+                residual=None if residuals is None else residuals[i])
+            if residuals is not None:
+                residuals[i] = new_r
+        for j, arr in fusion.unpack_bucket(schedule, i, flat,
+                                           leaves).items():
+            new_leaves[j] = arr
+    grads = jax.tree_util.tree_unflatten(treedef, new_leaves)
+    return tx.update_preaveraged(grads, state.opt_state, state.params)
+
+
+def _sum_squares(arrays):
+    return sum(jnp.sum(jnp.square(a.astype(jnp.float32))) for a in arrays)
+
+
+def _shards_norm(shards, reduce_axes):
+    """Shards partition the globally-averaged gradient: the psum of
+    their sum-squares IS its exact norm² (the pad zeros contribute
+    nothing)."""
+    return jnp.sqrt(collective.allreduce(
+        _sum_squares(shards), op=collective.Sum, axes=reduce_axes))
+
+
+def _wire_drift_checker(tx, wire):
+    """Per-step guard of a build that compiled its wire format in: the
+    format is resolved ONCE, when the step is built (bucket collectives
+    and residual shapes of the overlap pipeline; the chunked shard_map
+    island, the cast-narrowed constraints, or neither, on the GSPMD
+    path), but config.wire_dtype binds late — an autotuner that
+    installs its winner AFTER the step was built would otherwise leave
+    tx.compression claiming a format the running program never applies
+    (or vice versa). Warn once, in either drift direction, instead of
+    silently diverging."""
+    warned = [False]
+
+    def check():
+        now = tx.compression
+        if warned[0] or now is wire:
+            return
+        warned[0] = True
+        built = (f"built with {wire.name!r}" if wire is not None
+                 else "built uncompressed")
+        warnings.warn(
+            f"tx.compression now resolves to "
+            f"{getattr(now, 'name', None)!r} but this train step was "
+            f"{built} — the wire format is baked into the compiled "
+            "program at make_train_step time. Rebuild the step (after "
+            "the autotuner / config.wire_dtype install) for the new "
+            "format to take effect.", stacklevel=3)
+
+    return check
+
+
+class _WireCarry:
+    """The error-feedback residuals of a wire-compressed exchange, and
+    their life. One fp32 ``[world, n]`` buffer per bucket and direction
+    (``"rs"``: the reduce-scatter's padded bucket; ``"ag"``: the
+    all-gather's shard), row r = rank r's carry, sharded over the
+    scatter axes. Rebuildable by construction — zeros, built lazily from
+    the live state — so never checkpointed; donated into every dispatch,
+    so dropped when one raises. ``directions`` names those that carry:
+    both for the overlap pipeline and the chunked island, ``("ag",)``
+    for the cast + ZeRO-1 annotation path, none with error feedback off
+    (an empty tree: zero buffers, no effect on a program it is passed
+    to)."""
+
+    def __init__(self, tx, mesh, reduce_axes, directions):
+        self._tx = tx
+        self._mesh = mesh
+        self._axes = tuple(reduce_axes)
+        self._directions = tuple(directions)
+        self._held = None
+        self.spec = P(self._axes)
+
+    def get(self, state):
+        if self._held is None:
+            self._held = self._wire_state_for(state)
+        return self._held
+
+    def keep(self, new):
+        self._held = new
+
+    def reset(self):
+        """Drop the carried residuals; the next step rebuilds zeros.
+        Call after restoring ``state`` to an earlier commit (elastic
+        rollback / checkpoint restore) so the compensation restarts
+        clean instead of carrying a later step's error."""
+        self._held = None
+
+    def _wire_state_for(self, state):
+        if not self._directions:
+            return {"rs": [], "ag": []}
+        # world from the mesh THIS step was built on (the global mesh
+        # can be a different one — e.g. a sub-mesh step built while a
+        # bigger mesh is set — and a mismatched world here would shape
+        # the residual buffers against the wrong schedule)
+        schedule = _grad_schedule(
+            self._tx, self._axes, state,
+            int(np.prod([self._mesh.shape[a] for a in self._axes])))
+        sizes = {"rs": schedule.padded_sizes, "ag": schedule.shard_sizes}
+        sharding = jax.sharding.NamedSharding(self._mesh, self.spec)
+
+        def zeros(i, n):
+            # non-float buckets are never quantized (the bucket ops pass
+            # their residual through untouched) — a zero-width buffer
+            # keeps the per-bucket index alignment without the HBM or
+            # donation traffic of a dead fp32 carry
+            if not jnp.issubdtype(schedule.buckets[i].dtype, jnp.floating):
+                n = 0
+            return _put(jnp.zeros((schedule.world, n), jnp.float32),
+                        sharding)
+
+        return {d: ([zeros(i, n) for i, n in enumerate(sizes[d])]
+                    if d in self._directions else [])
+                for d in ("rs", "ag")}
+
+    @staticmethod
+    def shard_rows(wire_state):
+        """Inside the per-shard step: ``(rs, ag)`` residual lists for
+        the bucket ops (None where a direction carries nothing), each
+        this shard's ``[1, n]`` row of the global buffer, squeezed."""
+        return tuple([r[0] for r in wire_state[d]] or None
+                     for d in ("rs", "ag"))
+
+    @staticmethod
+    def from_rows(rs, ag):
+        """The carry a per-shard step returns: its rows, unsqueezed."""
+        return {"rs": [r[None] for r in rs or ()],
+                "ag": [r[None] for r in ag or ()]}
+
+
+class _HostStep:
+    """The host side of a train step, the same for every builder: what
+    surrounds the dispatch of ``program``. A call opens ``hvd_step``,
+    pulls the batch from the loader if none was given, checks the wire
+    format for drift, brackets the step for the flight recorder, places
+    state, carry and batch under ``hvd_place``, calls the program — and
+    nothing else — under ``hvd_launch``, keeps the new carry (or drops
+    it when the dispatch raises), settles the goodput ledger and feeds
+    the instruments. All of it is host-side floats and None checks: the
+    compiled program is byte-identical with a loader, a recorder, the
+    ledger or instruments present or absent (tests/test_data_plane,
+    test_diag, test_goodput).
+
+    What differs between the builders is data. ``program`` answers
+    ``program(*placed)`` and ``program.lower(*placed)`` (a ``jax.jit``
+    object, or :class:`_SpmdProgram`) and returns ``(state, [carry,]
+    loss[, grad norm])``; ``place_batch`` holds one placer per batch
+    argument; ``carry`` is the :class:`_WireCarry` riding as the second
+    argument and output, or None; ``prepare(placed)`` returns what to
+    launch for these arguments (the GSPMD builds fetch — at a first
+    shape, compile — their AOT executable there, BETWEEN placement and
+    launch, so that ``hvd_launch`` holds the dispatch alone)."""
+
+    # read through to the program (set at its first build) on the builds
+    # whose step does not hold them itself
+    _PROGRAM_ATTRS = ("jitted", "compiled_collectives",
+                      "compiled_axis_collectives")
+
+    def __init__(self, program, mesh, place_batch, carry=None,
+                 check_wire=None, prepare=None, compiled_path=False,
+                 instruments=None, loader=None):
+        self._program = program
+        self._mesh = mesh
+        self._place_batch = tuple(place_batch)
+        self._carry = carry
+        self._check_wire = check_wire
+        self._prepare = prepare
+        self._compiled_path = compiled_path
+        self._instruments = instruments
+        self._loader = loader
+        self._n = 0
+        self._first_trace = True
+        self._settles_ledger = True  # elastic_train_loop must not re-settle
+        if instruments is not None:
+            self.instruments = instruments
+
+    def __getattr__(self, name):
+        if name in self._PROGRAM_ATTRS:
+            return getattr(self._program, name)
+        raise AttributeError(name)
+
+    def place_state(self, state):
+        # once a GSPMD program is built, its cached shardings tree is
+        # reused instead of re-deriving specs on every step
+        shardings = getattr(self._program, "state_shardings", None)
+        if shardings is None:
+            return _placer(self._mesh, state_specs(state))(state)
+        return jax.tree_util.tree_map(_put, state, shardings)
+
+    def _place(self, state, batch):
+        if len(batch) != len(self._place_batch):
+            raise TypeError(
+                f"this step takes {len(self._place_batch)} batch "
+                f"argument(s) after the state, got {len(batch)}")
+        carry = () if self._carry is None else (self._carry.get(state),)
+        return (self.place_state(state),) + carry + tuple(
+            place(x) for place, x in zip(self._place_batch, batch))
+
+    def _loader_batch(self):
+        if self._loader is None:
+            raise TypeError(
+                "step(state) with no batch needs a loader — build the "
+                "step with make_train_step(..., loader=...) or pass "
+                "the batch explicitly")
+        batch = next(self._loader)
+        if not (isinstance(batch, (tuple, list))
+                and len(batch) == len(self._place_batch)):
+            raise TypeError(
+                "the loader's source must yield (inputs, labels) "
+                f"batches for this step; got {type(batch).__name__} "
+                f"of {len(batch) if hasattr(batch, '__len__') else '?'}")
+        return tuple(batch)
+
+    def step(self, state, *batch):
+        n = self._n
+        with scopes.step(n):
+            if not batch:
+                batch = self._loader_batch()
+            self._n = n + 1
+            if self._check_wire is not None:
+                self._check_wire()
+            _flightrec.step_begin(n)  # a None check with no recorder
+            tl = (basics._state.timeline
+                  if self._instruments is not None else None)
+            flow = None
+            if tl is not None and self._first_trace:
+                # the first call traces: open an enclosing slice + flow
+                # on the marker tid so the bucket markers emitted during
+                # tracing link back to this dispatch (ops/fusion reads
+                # _step_flow_id; flows need a B/E slice on their tid to
+                # bind in Perfetto's legacy-JSON importer)
+                tl.start_activity("marker", "step_trace_dispatch")
+                flow = tl.flow_start("step_dispatch")
+                tl._step_flow_id = flow
+            t0 = time.perf_counter()
+            try:
+                with scopes.host(scopes.PLACE):
+                    placed = self._place(state, batch)
+                launch = (self._program if self._prepare is None
+                          else self._prepare(placed))
+                with scopes.host(scopes.LAUNCH):
+                    outs = launch(*placed)
+                if self._carry is not None:
+                    self._carry.keep(outs[1])
+            except BaseException:
+                # the residuals were donated into the failed dispatch
+                # and may already be invalidated — drop them so the
+                # retry path (elastic rollback) rebuilds zeros instead
+                # of dying on deleted arrays forever
+                if self._carry is not None:
+                    self._carry.reset()
+                raise
+            finally:
+                if flow is not None:
+                    self._first_trace = False
+                    tl._step_flow_id = None
+                    tl.flow_end("step_dispatch", flow)
+                    tl.end_activity("marker")
+            _flightrec.step_end(n)
+            # the goodput ledger settles at every step boundary: the
+            # interval since the last settle, minus the stalls other
+            # subsystems charged (data_wait, ckpt_stall, compile, ...),
+            # is booked as compute. Resolved at CALL time (hvd.init opens
+            # a fresh run ledger).
+            ledger = _ledger_lib.get_ledger()
+            if self._compiled_path:
+                ledger.note_compiled_path()
+            ledger.settle_step()
+            loss, *extra = outs[1:] if self._carry is None else outs[2:]
+            if self._instruments is not None:
+                self._instruments.record_step(
+                    batch=int(batch[0].shape[0]),
+                    dispatch_s=time.perf_counter() - t0, loss=loss,
+                    grad_norm=extra[0] if extra else None, timeline=tl,
+                    step_no=n)
+        return outs[0], loss
+
+    __call__ = step
+
+    def lower(self, state, *batch):
+        """AOT lower with the SAME placement the executed path uses, so
+        the compile cache is shared and cost_analysis describes the
+        module that actually runs."""
+        return self._program.lower(*self._place(state, batch))
+
+
+def _xray(step, state, *batch, k=3, profile_dir=None):
+    """Opt-in compiled-step X-ray of a GSPMD step: run K steps of the
+    ALREADY compiled executable under a device trace and attribute
+    where the device time went (telemetry/xprof.py). Capture wraps
+    around the dispatch — the compiled program is byte-identical with
+    X-ray off. State threads through the captured steps (donation as
+    usual): returns ``(new_state, summary)``."""
+    from horovod_tpu.telemetry import xprof as _xprof
+    return _xprof.xray_run(
+        step, state, batch or step._loader_batch(), k=k,
+        profile_dir=profile_dir,
+        compiled_collectives=lambda: step.compiled_collectives)
 
 
 def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
@@ -222,23 +646,14 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
     vanishes and the compiled program is byte-identical to the
     uncompressed build.
     """
-    from horovod_tpu import hvd_jax
-    from horovod_tpu import telemetry as telemetry_lib
-    from horovod_tpu.ops import fusion
-    from horovod_tpu.parallel import zero as zero_lib
-
-    if spmd:
-        return _make_spmd_train_step(
-            model, tx, mesh=mesh, loss_fn=loss_fn, batch_axes=batch_axes,
-            donate=donate, dropout_seed=dropout_seed,
-            accum_steps=accum_steps, overlap_grads=overlap_grads,
-            telemetry=telemetry, error_feedback=error_feedback,
-            loader=loader)
-
     tele_on = (telemetry_lib.enabled() if telemetry is None
                else bool(telemetry))
-
     mesh = mesh if mesh is not None else mesh_lib.get_mesh()
+    if spmd:
+        return _make_spmd_train_step(
+            model, tx, mesh, loss_fn, batch_axes, donate, dropout_seed,
+            accum_steps, overlap_grads, tele_on, error_feedback, loader)
+
     data_axes = batch_axes or mesh_lib.data_axis_names(mesh)
     if accum_steps < 1:
         raise ValueError(f"accum_steps must be >= 1, got {accum_steps}")
@@ -254,7 +669,6 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
             raise ValueError(
                 "accum_steps and backward_passes_per_step are two "
                 "accumulators for the same thing; use accum_steps")
-    sharded_tx = is_hvd_tx and tx.sharded_update
     reduce_axes = (tuple(tx.axes) if is_hvd_tx and tx.axes is not None
                    else data_axes)
     # wire compression rides the bucket collectives of the OVERLAP
@@ -264,66 +678,15 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
     # The wire format is resolved HERE, once: it is baked into the
     # compiled program (bucket collectives, residual shapes), so build
     # the step AFTER the autotuner installs its wire-axis winner. A
-    # config change after build cannot take effect — _check_wire_drift
+    # config change after build cannot take effect — the drift check
     # warns instead of silently diverging from tx.compression.
     wire = tx.compression if (is_hvd_tx and overlap_grads) else None
     use_ef = wire is not None and error_feedback
-
-    def _grad_schedule(params, world):
-        """The ONE bucket-schedule recipe for this step's gradient
-        exchange — local_step (world from the named axes) and the EF
-        residual allocation (world from the step's mesh) must shape
-        against the same plan."""
-        return fusion.bucket_schedule(
-            jax.tree_util.tree_leaves(params), world=world,
-            threshold_bytes=tx.threshold_bytes, axes=reduce_axes,
-            hierarchical=tx._hierarchical_resolved())
-
-    _wire_drift_warned = [False]
-
-    def _check_wire_drift():
-        if not is_hvd_tx or not overlap_grads or _wire_drift_warned[0]:
-            return
-        now = tx.compression
-        if now is not wire:
-            _wire_drift_warned[0] = True
-            import warnings
-            warnings.warn(
-                f"tx.compression resolves to "
-                f"{getattr(now, 'name', None)!r} but this train step was "
-                f"built with {getattr(wire, 'name', None)!r} — the wire "
-                "format is baked into the compiled program at "
-                "make_train_step time. Rebuild the step (after the "
-                "autotuner / config install) for the new format to take "
-                "effect.", stacklevel=3)
-
-    def micro_grads(state, stats, inputs, labels, dropout_rng):
-        """Loss + grads of one microbatch at fixed params."""
-        def compute_loss(params):
-            variables = {"params": params}
-            if stats:
-                variables["batch_stats"] = stats
-                logits, mutated = model.apply(
-                    variables, inputs, train=True, mutable=["batch_stats"],
-                    rngs={"dropout": dropout_rng})
-                with scopes.device(scopes.LOSS):
-                    loss = loss_fn(logits, labels)
-                return loss, mutated["batch_stats"]
-            logits = model.apply(variables, inputs, train=True,
-                                 rngs={"dropout": dropout_rng})
-            with scopes.device(scopes.LOSS):
-                loss = loss_fn(logits, labels)
-            return loss, {}
-
-        return jax.value_and_grad(compute_loss, has_aux=True)(state.params)
+    carry = _WireCarry(tx, mesh, reduce_axes,
+                       ("rs", "ag") if use_ef else ())
 
     def local_step(state, wire_state, inputs, labels):
-        # wire_state: {"rs": [per-bucket residual], "ag": [...]} — empty
-        # (no leaves, so no effect on the compiled program) unless error
-        # feedback is on. Each residual arrives as this shard's [1, n]
-        # row of the [world, n] global buffer; squeeze for the bucket ops.
-        rs_res = [r[0] for r in wire_state.get("rs", ())]
-        ag_res = [r[0] for r in wire_state.get("ag", ())]
+        rs_res, ag_res = _WireCarry.shard_rows(wire_state)
         # per-step AND per-shard dropout stream (reference semantics:
         # each rank draws independent masks); each microbatch folds its
         # index in on top
@@ -337,23 +700,15 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
                 f"accum_steps={accum_steps} microbatches")
         micro = inputs.shape[0] // accum_steps
 
-        if sharded_tx:
-            # the optimizer-state partition IS the bucket schedule
-            schedule = state.opt_state.plan.schedule
-        elif overlap_grads:
-            schedule = _grad_schedule(state.params,
-                                      collective.mesh_size(reduce_axes))
-        else:
-            schedule = None
-
         stats = state.batch_stats
         acc_shards, acc_grads, loss_sum = None, None, 0.0
         if pipelined:
             for k in range(accum_steps):
                 xk = inputs[k * micro:(k + 1) * micro]
                 yk = labels[k * micro:(k + 1) * micro]
-                (loss_k, stats), grads_k = micro_grads(
-                    state, stats, xk, yk, jax.random.fold_in(base_rng, k))
+                (loss_k, stats), grads_k = _classification_grads(
+                    model, loss_fn, state.params, stats, xk, yk,
+                    jax.random.fold_in(base_rng, k))
                 loss_sum = loss_sum + loss_k
                 if overlap_grads:
                     # reduce-scatter every bucket of THIS microbatch now:
@@ -362,23 +717,8 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
                     # hiding scheduler overlaps them (reduce-scatter is
                     # linear — summing per-microbatch shards equals
                     # scattering the sum)
-                    leaves_k = jax.tree_util.tree_leaves(grads_k)
-                    rs_op = (state.opt_state.plan.op if sharded_tx
-                             else tx.op)
-                    shards_k = []
-                    for i in range(len(schedule.buckets)):
-                        if wire is None:
-                            s = fusion.reduce_scatter_bucket(
-                                schedule, i, leaves_k, op=rs_op)
-                        else:
-                            s, new_r = \
-                                fusion.reduce_scatter_bucket_compressed(
-                                    schedule, i, leaves_k, wire, op=rs_op,
-                                    residual=(rs_res[i] if use_ef
-                                              else None))
-                            if use_ef:
-                                rs_res[i] = new_r
-                        shards_k.append(s)
+                    schedule, shards_k = _reduce_scatter_grads(
+                        tx, reduce_axes, state, grads_k, wire, rs_res)
                     acc_shards = (shards_k if acc_shards is None else
                                   [a + s for a, s in zip(acc_shards,
                                                          shards_k)])
@@ -387,52 +727,18 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
                                  jax.tree_util.tree_map(
                                      jnp.add, acc_grads, grads_k))
         else:
-            (loss_sum, stats), grads = micro_grads(
-                state, state.batch_stats, inputs, labels, base_rng)
+            (loss_sum, stats), grads = _classification_grads(
+                model, loss_fn, state.params, stats, inputs, labels,
+                base_rng)
 
         inv_k = 1.0 / accum_steps
         gnorm = None
         if overlap_grads:
             shards = [s * jnp.asarray(inv_k, s.dtype) for s in acc_shards]
             if tele_on:
-                # shards partition the globally-averaged gradient: the
-                # psum of shard sum-squares IS its exact norm² (the pad
-                # zeros contribute nothing)
-                local_sq = sum(jnp.sum(jnp.square(s.astype(jnp.float32)))
-                               for s in shards)
-                gnorm = jnp.sqrt(collective.allreduce(
-                    local_sq, op=collective.Sum, axes=reduce_axes))
-            if sharded_tx:
-                grad_rows = {f"b{i}": s[None] for i, s in enumerate(shards)}
-                if wire is None:
-                    updates, opt_state = zero_lib.apply_shards(
-                        tx.inner, grad_rows, state.opt_state, state.params)
-                elif use_ef:
-                    updates, opt_state, ag_res = zero_lib.apply_shards(
-                        tx.inner, grad_rows, state.opt_state, state.params,
-                        wire=wire, ag_residuals=ag_res)
-                else:
-                    updates, opt_state = zero_lib.apply_shards(
-                        tx.inner, grad_rows, state.opt_state, state.params,
-                        wire=wire)
-            else:
-                leaves, treedef = jax.tree_util.tree_flatten(state.params)
-                new_leaves = [None] * len(leaves)
-                for i, s in enumerate(shards):
-                    if wire is None:
-                        flat = fusion.all_gather_bucket(schedule, i, s)
-                    else:
-                        flat, new_r = fusion.all_gather_bucket_compressed(
-                            schedule, i, s, wire,
-                            residual=ag_res[i] if use_ef else None)
-                        if use_ef:
-                            ag_res[i] = new_r
-                    for j, arr in fusion.unpack_bucket(
-                            schedule, i, flat, leaves).items():
-                        new_leaves[j] = arr
-                grads = jax.tree_util.tree_unflatten(treedef, new_leaves)
-                updates, opt_state = tx.update_preaveraged(
-                    grads, state.opt_state, state.params)
+                gnorm = _shards_norm(shards, reduce_axes)
+            updates, opt_state = _update_from_shards(
+                tx, schedule, shards, state, wire, ag_res)
         else:
             if pipelined:
                 grads = jax.tree_util.tree_map(
@@ -442,47 +748,29 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
                 # the root-mean across ranks of local norm² — an upper
                 # bound of the averaged-grad norm (Jensen), and the
                 # divergence signal observability wants
-                local_sq = sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree_util.tree_leaves(grads))
                 gnorm = jnp.sqrt(collective.allreduce(
-                    local_sq, op=collective.Average, axes=reduce_axes))
+                    _sum_squares(jax.tree_util.tree_leaves(grads)),
+                    op=collective.Average, axes=reduce_axes))
             updates, opt_state = tx.update(grads, state.opt_state,
                                            state.params)
-
-        with scopes.device(scopes.OPTIMIZER):
-            params = optax.apply_updates(state.params, updates)
-        if stats:
-            stats = jax.tree_util.tree_map(
-                lambda x: collective.allreduce(x, op=collective.Average,
-                                               axes=data_axes), stats)
         with scopes.device(scopes.LOSS):
-            loss = collective.allreduce(loss_sum * inv_k,
-                                        op=collective.Average,
-                                        axes=data_axes)
-        new_state = TrainState(params=params, opt_state=opt_state,
-                               batch_stats=stats, step=state.step + 1)
-        new_wire = {"rs": [r[None] for r in rs_res],
-                    "ag": [r[None] for r in ag_res]}
-        if tele_on:
-            return new_state, new_wire, loss, gnorm
-        return new_state, new_wire, loss
-
-    wire_spec = P(tuple(reduce_axes))
+            loss = loss_sum * inv_k
+        new_state, loss = _next_state(state, updates, opt_state, stats,
+                                      loss, mean_over=data_axes)
+        out = (new_state, _WireCarry.from_rows(rs_res, ag_res), loss)
+        return out + (gnorm,) if tele_on else out
 
     def hvd_train_step(state, wire_state, inputs, labels):
         specs = state_specs(state)
-        wspecs = jax.tree_util.tree_map(lambda _: wire_spec, wire_state)
-        out_specs = ((specs, wspecs, P(), P()) if tele_on
-                     else (specs, wspecs, P()))
+        wspecs = jax.tree_util.tree_map(lambda _: carry.spec, wire_state)
         sharded = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(specs, wspecs, P(data_axes), P(data_axes)),
-            out_specs=out_specs,
+            out_specs=(specs, wspecs) + (P(),) * (2 if tele_on else 1),
             check_vma=False)
         return sharded(state, wire_state, inputs, labels)
 
-    # wire_state is an EMPTY pytree unless error feedback is on, so the
+    # the carry is an EMPTY pytree unless error feedback is on, so the
     # extra jit argument contributes zero buffers and the compiled
     # program stays byte-identical to the uncompressed build.
     # the function's name is the compiled module's (jit_hvd_train_step)
@@ -490,188 +778,22 @@ def make_train_step(model, tx, mesh=None, loss_fn=softmax_cross_entropy,
     jitted = jax.jit(hvd_train_step,
                      donate_argnums=(0, 1) if donate else ())
     place_data = _placer(mesh, P(data_axes))
-
-    def place_state(state):
-        return _placer(mesh, state_specs(state))(state)
-
     if loader is not None:
         # stage prefetched batches straight to this step's mesh placement
         # on the PRODUCER thread — by dispatch time place_data is a no-op
         loader.attach_placement(place_data, spec=P(data_axes))
 
-    def _loader_batch():
-        if loader is None:
-            raise TypeError(
-                "step(state) with no batch needs a loader — build the "
-                "step with make_train_step(..., loader=...) or pass "
-                "(inputs, labels) explicitly")
-        batch = next(loader)
-        if not (isinstance(batch, (tuple, list)) and len(batch) == 2):
-            raise TypeError(
-                "the loader's source must yield (inputs, labels) "
-                f"batches for this step; got {type(batch).__name__} "
-                f"of {len(batch) if hasattr(batch, '__len__') else '?'}")
-        return batch[0], batch[1]
-
-    _wire_holder = [None]
-
-    def _wire_state_for(state):
-        """Zero-initialized per-bucket residual buffers ([world, n] global,
-        row r = rank r's carry), rebuilt lazily from the live state —
-        rebuildable by construction, so never checkpointed."""
-        if not use_ef:
-            return {"rs": [], "ag": []}
-        if sharded_tx:
-            schedule = state.opt_state.plan.schedule
-        else:
-            # world from the mesh THIS step was built on (the global
-            # mesh can be a different one — e.g. a sub-mesh step built
-            # while a bigger mesh is set — and a mismatched world here
-            # would shape the residual buffers against the wrong
-            # schedule)
-            mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-            schedule = _grad_schedule(
-                state.params,
-                int(np.prod([mesh_shape[a] for a in reduce_axes])))
-        w = schedule.world
-
-        def size_or_zero(i, n):
-            # non-float buckets are never quantized (the bucket ops pass
-            # their residual through untouched) — a zero-width buffer
-            # keeps the per-bucket index alignment without the HBM or
-            # donation traffic of a dead fp32 carry
-            return n if jnp.issubdtype(schedule.buckets[i].dtype,
-                                       jnp.floating) else 0
-
-        ws = {"rs": [jnp.zeros((w, size_or_zero(i, p)), jnp.float32)
-                     for i, p in enumerate(schedule.padded_sizes)],
-              "ag": [jnp.zeros((w, size_or_zero(i, s)), jnp.float32)
-                     for i, s in enumerate(schedule.shard_sizes)]}
-        return jax.tree_util.tree_map(
-            lambda x: jax.device_put(
-                x, jax.sharding.NamedSharding(mesh, wire_spec)), ws)
-
-    def _wire_state(state):
-        if _wire_holder[0] is None:
-            _wire_holder[0] = _wire_state_for(state)
-        return _wire_holder[0]
-
-    def _reset_error_feedback():
-        """Drop the carried residuals; the next step rebuilds zeros.
-        Call after restoring ``state`` to an earlier commit (elastic
-        rollback / checkpoint restore) so the compensation restarts
-        clean instead of carrying a later step's error."""
-        _wire_holder[0] = None
-
-    from horovod_tpu.diag import recorder as _flightrec
-    from horovod_tpu.telemetry import ledger as _ledger_lib
-    # the goodput ledger settles at every step boundary: the interval
-    # since the last settle, minus the stalls other subsystems charged
-    # (data_wait, ckpt_stall, compile, ...), is booked as compute.
-    # Resolved at CALL time (hvd.init opens a fresh run ledger); host-
-    # side floats only — the compiled program is byte-identical with the
-    # ledger on or off (tests/test_goodput.py).
-    _goodput = _ledger_lib.get_ledger
-
-    if not tele_on:
-        _step_no = [0]
-
-        def step(state, inputs=None, labels=None):
-            # flight-recorder step boundaries (host-side only: with no
-            # recorder installed these are a None check each, and they
-            # never touch the traced computation — the compiled program
-            # stays byte-identical either way, tests/test_diag.py)
-            n = _step_no[0]
-            with scopes.step(n):
-                if inputs is None:
-                    inputs, labels = _loader_batch()
-                _step_no[0] = n + 1
-                _check_wire_drift()
-                _flightrec.step_begin(n)
-                try:
-                    with scopes.host(scopes.PLACE):
-                        placed = (place_state(state), _wire_state(state),
-                                  place_data(inputs), place_data(labels))
-                    with scopes.host(scopes.LAUNCH):
-                        new_state, new_wire, loss = jitted(*placed)
-                    _wire_holder[0] = new_wire
-                except BaseException:
-                    # the residuals were donated into the failed dispatch
-                    # and may already be invalidated — drop them so the
-                    # retry path (elastic rollback) rebuilds zeros instead
-                    # of dying on deleted arrays forever
-                    _wire_holder[0] = None
-                    raise
-                _flightrec.step_end(n)
-                _goodput().settle_step()
-            return new_state, loss
-    else:
-        from horovod_tpu import basics as _basics
-        import time as _time
-
-        instruments = telemetry_lib.StepInstruments(accum_steps=accum_steps)
-        first_trace = [True]
-
-        def step(state, inputs=None, labels=None):
-            step_no = int(instruments.steps.value)
-            with scopes.step(step_no):
-                if inputs is None:
-                    inputs, labels = _loader_batch()
-                _check_wire_drift()
-                _flightrec.step_begin(step_no)
-                tl = _basics._state.timeline
-                flow = None
-                if tl is not None and first_trace[0]:
-                    # the first call traces: open an enclosing slice + flow
-                    # on the marker tid so the bucket markers emitted during
-                    # tracing link back to this dispatch (ops/fusion reads
-                    # _step_flow_id; flows need a B/E slice on their tid to
-                    # bind in Perfetto's legacy-JSON importer)
-                    tl.start_activity("marker", "step_trace_dispatch")
-                    flow = tl.flow_start("step_dispatch")
-                    tl._step_flow_id = flow
-                t0 = _time.perf_counter()
-                try:
-                    with scopes.host(scopes.PLACE):
-                        placed = (place_state(state), _wire_state(state),
-                                  place_data(inputs), place_data(labels))
-                    with scopes.host(scopes.LAUNCH):
-                        new_state, new_wire, loss, gnorm = jitted(*placed)
-                    _wire_holder[0] = new_wire
-                except BaseException:
-                    _wire_holder[0] = None  # donated into the failed dispatch
-                    raise
-                finally:
-                    if flow is not None:
-                        first_trace[0] = False
-                        tl._step_flow_id = None
-                        tl.flow_end("step_dispatch", flow)
-                        tl.end_activity("marker")
-                _flightrec.step_end(step_no)
-                _goodput().settle_step()
-                instruments.record_step(
-                    batch=int(inputs.shape[0]),
-                    dispatch_s=_time.perf_counter() - t0,
-                    loss=loss, grad_norm=gnorm, timeline=tl,
-                    step_no=instruments.steps.value)
-            return new_state, loss
-
-        step.instruments = instruments
-
+    step = _HostStep(
+        jitted, mesh, (place_data, place_data), carry=carry,
+        check_wire=(_wire_drift_checker(tx, wire)
+                    if is_hvd_tx and overlap_grads else None),
+        instruments=(telemetry_lib.StepInstruments(accum_steps=accum_steps)
+                     if tele_on else None),
+        loader=loader)
     step.jitted = jitted  # AOT access (lower/compile/cost_analysis)
-    step.reset_error_feedback = _reset_error_feedback
+    step.reset_error_feedback = carry.reset
     step.loader = loader
     step.place_data = place_data
-    step._settles_ledger = True  # elastic_train_loop must not re-settle
-
-    def lower(state, inputs, labels):
-        """AOT lower with the SAME placement the executed path uses, so
-        the compile cache is shared and cost_analysis describes the
-        module that actually runs."""
-        return jitted.lower(place_state(state), _wire_state(state),
-                            place_data(inputs), place_data(labels))
-
-    step.lower = lower
     return step
 
 
@@ -680,8 +802,6 @@ def _spmd_gate(tx, what):
     contract. Returns the resolved wire format (``None`` or a
     compressor — the caller compiles it in-place: the shard_map island
     for chunked quantizers, dtype-narrowed constraints for casts)."""
-    from horovod_tpu import hvd_jax
-
     if not isinstance(tx, hvd_jax.HorovodOptimizer):
         raise ValueError(
             f"{what}(spmd=True) needs the optimizer built by "
@@ -707,28 +827,23 @@ class _SpmdProgram:
     plus the once-per-build compiled-collective accounting and the AOT
     lower. One copy, so a fix to either flavor cannot miss the other.
 
-    ``arg_specs`` are the PartitionSpecs of the non-state args (batch
-    leaves; each entry may be a pytree PREFIX for its argument — a
-    single spec covers a whole subtree, which is how the wire-residual
-    dict rides as one argument); ``n_scalar_outs`` counts the
-    replicated scalar outputs after the state (loss, optional grad
-    norm). ``aux_out_specs`` are specs for outputs BETWEEN the state
-    and the scalars (the new wire-residual tree, sharded like its
-    input); ``extra_donate`` names additional donated argnums (the
-    residuals are dead after each step — donating them keeps the EF
-    carry HBM-neutral, same as the explicit path's ``donate_argnums=
-    (0, 1)``)."""
+    ``batch_specs`` are the PartitionSpecs of the batch arguments;
+    ``n_scalar_outs`` counts the replicated scalar outputs after the
+    state (loss, optional grad norm). ``carry_spec``, where there is an
+    error-feedback carry, is the ONE spec of its whole tree (a pytree
+    prefix — which is how the wire-residual dict rides as one
+    argument): the carry is then the second argument and the second
+    output, and donated with the state (the residuals are dead after
+    each step — donating them keeps the EF carry HBM-neutral, same as
+    the explicit path's ``donate_argnums=(0, 1)``)."""
 
-    def __init__(self, plan, global_step, arg_specs, n_scalar_outs,
-                 donate, aux_out_specs=(), extra_donate=()):
-        from horovod_tpu.parallel import gspmd as gspmd_lib
-
+    def __init__(self, plan, global_step, batch_specs, n_scalar_outs,
+                 donate, carry_spec=None):
         self.plan = plan
         self._fn = global_step
-        self._arg_specs = tuple(arg_specs)
+        self._carry_specs = () if carry_spec is None else (carry_spec,)
+        self._batch_specs = tuple(batch_specs)
         self._n_out = int(n_scalar_outs)
-        self._aux_out_specs = tuple(aux_out_specs)
-        self._extra_donate = tuple(extra_donate)
         self._donate = donate
         self.jitted = None
         self.state_shardings = None
@@ -737,20 +852,17 @@ class _SpmdProgram:
         self.compiled_axis_collectives = None
 
     def jitted_for(self, placed_state):
-        from horovod_tpu.parallel import gspmd as gspmd_lib
-
         if self.jitted is None:
             self.state_shardings = gspmd_lib.state_shardings(
                 self.plan, placed_state)
-            rep = self.plan.sharding(P())
+            carry = tuple(self.plan.sharding(s) for s in self._carry_specs)
             self.jitted = jax.jit(
                 self._fn,
-                in_shardings=(self.state_shardings,) + tuple(
-                    self.plan.sharding(s) for s in self._arg_specs),
-                out_shardings=(self.state_shardings,) + tuple(
-                    self.plan.sharding(s) for s in self._aux_out_specs)
-                + (rep,) * self._n_out,
-                donate_argnums=((0,) + self._extra_donate
+                in_shardings=(self.state_shardings,) + carry + tuple(
+                    self.plan.sharding(s) for s in self._batch_specs),
+                out_shardings=(self.state_shardings,) + carry
+                + (self.plan.sharding(P()),) * self._n_out,
+                donate_argnums=(tuple(range(1 + len(carry)))
                                 if self._donate else ()))
         return self.jitted
 
@@ -769,11 +881,10 @@ class _SpmdProgram:
         into every executable."""
         ex = self._cache.executable(self.jitted_for(placed[0]), placed)
         self.compiled_collectives = self._cache.last_collectives
-        self.compiled_axis_collectives = \
-            self._cache.last_axis_collectives
+        self.compiled_axis_collectives = self._cache.last_axis_collectives
         return ex
 
-    def lower(self, placed):
+    def lower(self, *placed):
         """AOT lower with the executed path's placement — for
         ``cost_analysis``-style callers; ``.compile()`` on the result
         is a fresh compile (the executing path's artifact is
@@ -781,42 +892,36 @@ class _SpmdProgram:
         return self.jitted_for(placed[0]).lower(*placed)
 
 
-def _spmd_wire_drift_checker(tx, wire):
-    """Per-step guard mirroring the explicit path's _check_wire_drift:
-    the GSPMD builders resolve the wire format ONCE at build and
-    compile it into the program (the chunked shard_map island, the
-    cast-narrowed constraints, or neither), but config.wire_dtype binds
-    late — an autotuner that installs its winner AFTER the step was
-    built would otherwise leave tx.compression claiming a format the
-    running program never applies (or vice versa). Warn once, in either
-    drift direction, instead of silently diverging."""
-    warned = [False]
-
-    def check():
-        if warned[0]:
-            return
-        now = tx.compression
-        if now is not wire:
-            warned[0] = True
-            import warnings
-            built = (f"built with {wire.name!r}" if wire is not None
-                     else "built uncompressed")
-            warnings.warn(
-                f"tx.compression now resolves to "
-                f"{getattr(now, 'name', None)!r} but this GSPMD step was "
-                f"{built} — the wire format is compiled into the program "
-                "at make_train_step time. Rebuild the step after "
-                "installing config.wire_dtype for the new format to "
-                "take effect.", stacklevel=3)
-
-    return check
+def _through_wire_dtype(grads, wire):
+    """The cast wires' plain-DP hint on the annotation-only programs.
+    Plain DP has no sharded consumer to hang a narrow constraint on:
+    round-trip the logical gradient through the wire dtype — the applied
+    update carries the wire precision, and the convert adjacent to XLA's
+    inserted all-reduce is the cue for sinking the reduction to the
+    narrow width where the backend can."""
+    return jax.tree_util.tree_map(
+        lambda g: (g.astype(wire.wire_dtype).astype(g.dtype)
+                   if jnp.issubdtype(g.dtype, jnp.floating) else g), grads)
 
 
-def _make_spmd_train_step(model, tx, mesh=None,
-                          loss_fn=softmax_cross_entropy, batch_axes=None,
-                          donate=True, dropout_seed=0, accum_steps=1,
-                          overlap_grads=False, telemetry=None,
-                          error_feedback=True, loader=None):
+def _spmd_host_step(prog, mesh, place_batch, tx, wire, **kwargs):
+    """The :class:`_HostStep` of a GSPMD build: the drift check always
+    on, the AOT executable (one compile per argument-shape signature)
+    fetched between placement and launch, the ledger told that the
+    compiled path ran, and the attributes the GSPMD steps carry."""
+    step = _HostStep(prog, mesh, place_batch,
+                     check_wire=_wire_drift_checker(tx, wire),
+                     prepare=prog.executable, compiled_path=True,
+                     **kwargs)
+    step.plan = prog.plan
+    step.spmd = True
+    step.xray = functools.partial(_xray, step)
+    return step
+
+
+def _make_spmd_train_step(model, tx, mesh, loss_fn, batch_axes, donate,
+                          dropout_seed, accum_steps, overlap_grads, tele_on,
+                          error_feedback, loader):
     """The GSPMD hot path behind ``make_train_step(spmd=True)`` — see
     that docstring and ``parallel/gspmd.py`` for the contract.
 
@@ -841,13 +946,6 @@ def _make_spmd_train_step(model, tx, mesh=None,
     * ``wire is None`` compiles the byte-identical uncompressed program
       (the wire-residual argument is an empty pytree — zero buffers).
     """
-    import time as _time
-
-    from horovod_tpu import telemetry as telemetry_lib
-    from horovod_tpu.ops import fusion
-    from horovod_tpu.parallel import gspmd as gspmd_lib
-    from horovod_tpu.parallel import zero as zero_lib
-
     wire = _spmd_gate(tx, "make_train_step")
     if accum_steps != 1 or overlap_grads:
         raise ValueError(
@@ -856,9 +954,6 @@ def _make_spmd_train_step(model, tx, mesh=None,
             "and XLA's latency-hiding scheduler owns the compute/comms "
             "overlap")
 
-    tele_on = (telemetry_lib.enabled() if telemetry is None
-               else bool(telemetry))
-    mesh = mesh if mesh is not None else mesh_lib.get_mesh()
     plan = gspmd_lib.derive_plan(mesh)
     data_axes = tuple(batch_axes) if batch_axes else plan.data_axes
     batch_spec = P(data_axes)
@@ -873,13 +968,9 @@ def _make_spmd_train_step(model, tx, mesh=None,
     # still-unreduced logical gradient — see apply_shards_spmd).
     use_ef = (wire is not None and error_feedback
               and (chunked or sharded_tx))
-    wire_spec = P(tuple(reduce_axes))
-
-    def _grad_schedule(params, world):
-        return fusion.bucket_schedule(
-            jax.tree_util.tree_leaves(params), world=world,
-            threshold_bytes=tx.threshold_bytes, axes=reduce_axes,
-            hierarchical=tx._hierarchical_resolved())
+    carry = _WireCarry(
+        tx, mesh, reduce_axes,
+        () if not use_ef else ("rs", "ag") if chunked else ("ag",))
 
     if chunked:
         def local_step(state, wire_state, inputs, labels):
@@ -887,118 +978,35 @@ def _make_spmd_train_step(model, tx, mesh=None,
             # the chunked quantize->alltoall->dequantize bucket exchange
             # — the same data plane as the explicit overlap pipeline,
             # but compiled INSIDE the GSPMD jit step so the surrounding
-            # program (and its scheduler) stays XLA's. Residual rows
-            # arrive as this shard's [1, n] slice of the [world, n]
-            # global carry; squeeze for the bucket ops.
-            rs_res = [r[0] for r in wire_state.get("rs", ())]
-            ag_res = [r[0] for r in wire_state.get("ag", ())]
+            # program (and its scheduler) stays XLA's.
+            rs_res, ag_res = _WireCarry.shard_rows(wire_state)
             # per-step AND per-shard dropout stream — explicit-path
             # semantics (each rank draws independent masks)
             rng = jax.random.fold_in(
                 jax.random.fold_in(jax.random.PRNGKey(dropout_seed),
                                    state.step),
                 collective.mesh_rank(data_axes))
-
-            def compute_loss(params):
-                variables = {"params": params}
-                if state.batch_stats:
-                    variables["batch_stats"] = state.batch_stats
-                    logits, mutated = model.apply(
-                        variables, inputs, train=True,
-                        mutable=["batch_stats"], rngs={"dropout": rng})
-                    with scopes.device(scopes.LOSS):
-                        loss = loss_fn(logits, labels)
-                    return loss, mutated["batch_stats"]
-                logits = model.apply(variables, inputs, train=True,
-                                     rngs={"dropout": rng})
-                with scopes.device(scopes.LOSS):
-                    loss = loss_fn(logits, labels)
-                return loss, {}
-
-            (loss, stats), grads = jax.value_and_grad(
-                compute_loss, has_aux=True)(state.params)
-
-            if sharded_tx:
-                # the optimizer-state partition IS the bucket schedule
-                schedule = state.opt_state.plan.schedule
-                rs_op = state.opt_state.plan.op
-            else:
-                schedule = _grad_schedule(
-                    state.params, collective.mesh_size(reduce_axes))
-                rs_op = tx.op
-            leaves_g = jax.tree_util.tree_leaves(grads)
-            shards = []
-            for i in range(len(schedule.buckets)):
-                s, new_r = fusion.reduce_scatter_bucket_compressed(
-                    schedule, i, leaves_g, wire, op=rs_op,
-                    residual=(rs_res[i] if use_ef else None))
-                if use_ef:
-                    rs_res[i] = new_r
-                shards.append(s)
-            gnorm = None
-            if tele_on:
-                # shards partition the globally-averaged gradient: the
-                # psum of shard sum-squares IS its exact norm²
-                local_sq = sum(jnp.sum(jnp.square(s.astype(jnp.float32)))
-                               for s in shards)
-                gnorm = jnp.sqrt(collective.allreduce(
-                    local_sq, op=collective.Sum, axes=reduce_axes))
-            if sharded_tx:
-                grad_rows = {f"b{i}": s[None]
-                             for i, s in enumerate(shards)}
-                if use_ef:
-                    updates, opt_state, ag_res = zero_lib.apply_shards(
-                        tx.inner, grad_rows, state.opt_state,
-                        state.params, wire=wire, ag_residuals=ag_res)
-                else:
-                    updates, opt_state = zero_lib.apply_shards(
-                        tx.inner, grad_rows, state.opt_state,
-                        state.params, wire=wire)
-            else:
-                leaves_p, treedef = jax.tree_util.tree_flatten(
-                    state.params)
-                new_leaves = [None] * len(leaves_p)
-                for i, s in enumerate(shards):
-                    flat, new_r = fusion.all_gather_bucket_compressed(
-                        schedule, i, s, wire,
-                        residual=ag_res[i] if use_ef else None)
-                    if use_ef:
-                        ag_res[i] = new_r
-                    for j, arr in fusion.unpack_bucket(
-                            schedule, i, flat, leaves_p).items():
-                        new_leaves[j] = arr
-                grads_full = jax.tree_util.tree_unflatten(treedef,
-                                                          new_leaves)
-                updates, opt_state = tx.update_preaveraged(
-                    grads_full, state.opt_state, state.params)
-            with scopes.device(scopes.OPTIMIZER):
-                params = optax.apply_updates(state.params, updates)
-            if stats:
-                stats = jax.tree_util.tree_map(
-                    lambda x: collective.allreduce(
-                        x, op=collective.Average, axes=data_axes), stats)
-            with scopes.device(scopes.LOSS):
-                loss = collective.allreduce(loss, op=collective.Average,
-                                            axes=data_axes)
-            new_state = TrainState(params=params, opt_state=opt_state,
-                                   batch_stats=stats,
-                                   step=state.step + 1)
-            new_wire = {"rs": [r[None] for r in rs_res],
-                        "ag": [r[None] for r in ag_res]}
-            if tele_on:
-                return new_state, new_wire, loss, gnorm
-            return new_state, new_wire, loss
+            (loss, stats), grads = _classification_grads(
+                model, loss_fn, state.params, state.batch_stats, inputs,
+                labels, rng)
+            schedule, shards = _reduce_scatter_grads(
+                tx, reduce_axes, state, grads, wire, rs_res)
+            gnorm = _shards_norm(shards, reduce_axes) if tele_on else None
+            updates, opt_state = _update_from_shards(
+                tx, schedule, shards, state, wire, ag_res)
+            new_state, loss = _next_state(state, updates, opt_state,
+                                          stats, loss, mean_over=data_axes)
+            out = (new_state, _WireCarry.from_rows(rs_res, ag_res), loss)
+            return out + (gnorm,) if tele_on else out
 
         def global_step(state, wire_state, inputs, labels):
             specs = state_specs(state)
-            wspecs = jax.tree_util.tree_map(lambda _: wire_spec,
+            wspecs = jax.tree_util.tree_map(lambda _: carry.spec,
                                             wire_state)
-            out_specs = ((specs, wspecs, P(), P()) if tele_on
-                         else (specs, wspecs, P()))
             island = gspmd_lib.shard_map_island(
                 local_step, plan,
                 in_specs=(specs, wspecs, batch_spec, batch_spec),
-                out_specs=out_specs)
+                out_specs=(specs, wspecs) + (P(),) * (2 if tele_on else 1))
             return island(state, wire_state, inputs, labels)
     else:
         def global_step(state, wire_state, inputs, labels):
@@ -1008,99 +1016,31 @@ def _make_spmd_train_step(model, tx, mesh=None,
             # docs/PERFORMANCE.md)
             rng = jax.random.fold_in(jax.random.PRNGKey(dropout_seed),
                                      state.step)
-
-            def compute_loss(params):
-                variables = {"params": params}
-                if state.batch_stats:
-                    variables["batch_stats"] = state.batch_stats
-                    logits, mutated = model.apply(
-                        variables, inputs, train=True,
-                        mutable=["batch_stats"], rngs={"dropout": rng})
-                    with scopes.device(scopes.LOSS):
-                        loss = loss_fn(logits, labels)
-                    return loss, mutated["batch_stats"]
-                logits = model.apply(variables, inputs, train=True,
-                                     rngs={"dropout": rng})
-                with scopes.device(scopes.LOSS):
-                    loss = loss_fn(logits, labels)
-                return loss, {}
-
-            (loss, stats), grads = jax.value_and_grad(
-                compute_loss, has_aux=True)(state.params)
+            (loss, stats), grads = _classification_grads(
+                model, loss_fn, state.params, state.batch_stats, inputs,
+                labels, rng)
             if wire is not None and not sharded_tx:
-                # plain DP has no sharded consumer to hang a narrow
-                # constraint on: round-trip the logical gradient through
-                # the wire dtype — the applied update carries the wire
-                # precision, and the convert adjacent to XLA's inserted
-                # all-reduce is the cue for sinking the reduction to the
-                # narrow width where the backend can
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(wire.wire_dtype).astype(g.dtype)
-                               if jnp.issubdtype(g.dtype, jnp.floating)
-                               else g), grads)
+                grads = _through_wire_dtype(grads, wire)
             gnorm = None
             if tele_on:
                 # grads are the logical global-mean gradient — this is
                 # its exact L2 norm (same definition as the overlapped
                 # path)
-                gnorm = jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in jax.tree_util.tree_leaves(grads)))
-            if wire is not None and sharded_tx:
-                ag_res = list(wire_state.get("ag", ()))
-                if use_ef:
-                    updates, opt_state, ag_res = tx.update_spmd(
-                        grads, state.opt_state, state.params, plan,
-                        wire=wire, ag_residuals=ag_res)
-                else:
-                    updates, opt_state = tx.update_spmd(
-                        grads, state.opt_state, state.params, plan,
-                        wire=wire)
-                new_wire = {"rs": [], "ag": ag_res if use_ef else []}
+                gnorm = jnp.sqrt(_sum_squares(
+                    jax.tree_util.tree_leaves(grads)))
+            new_wire = {"rs": [], "ag": []}
+            if use_ef:  # the cast + ZeRO-1 delta carry
+                updates, opt_state, new_wire["ag"] = tx.update_spmd(
+                    grads, state.opt_state, state.params, plan,
+                    wire=wire, ag_residuals=list(wire_state["ag"]))
             else:
                 updates, opt_state = tx.update_spmd(
-                    grads, state.opt_state, state.params, plan)
-                new_wire = {"rs": [], "ag": []}
-            with scopes.device(scopes.OPTIMIZER):
-                params = optax.apply_updates(state.params, updates)
-            new_state = TrainState(params=params, opt_state=opt_state,
-                                   batch_stats=stats,
-                                   step=state.step + 1)
-            if tele_on:
-                return new_state, new_wire, loss, gnorm
-            return new_state, new_wire, loss
-
-    place_data = _placer(mesh, batch_spec)
-
-    def place_state(state):
-        # ONE placement implementation (parallel/gspmd.place_state);
-        # once the program is built, its cached shardings tree is
-        # reused instead of re-deriving specs on every step
-        if prog.state_shardings is not None:
-            return jax.tree_util.tree_map(_put, state,
-                                          prog.state_shardings)
-        return gspmd_lib.place_state(plan, state)
-
-    if loader is not None:
-        # prefetched batches are staged by the PRODUCER thread directly
-        # onto the plan's batch NamedSharding — they arrive matching the
-        # compiled step's in_shardings, so dispatch-time placement is a
-        # no-op
-        loader.attach_placement(place_data,
-                                spec=plan.sharding(batch_spec))
-
-    def _loader_batch():
-        if loader is None:
-            raise TypeError(
-                "step(state) with no batch needs a loader — build the "
-                "step with make_train_step(..., loader=...) or pass "
-                "(inputs, labels) explicitly")
-        batch = next(loader)
-        if not (isinstance(batch, (tuple, list)) and len(batch) == 2):
-            raise TypeError(
-                "the loader's source must yield (inputs, labels) "
-                f"batches for this step; got {type(batch).__name__}")
-        return batch[0], batch[1]
+                    grads, state.opt_state, state.params, plan,
+                    wire=wire if sharded_tx else None)
+            new_state, loss = _next_state(state, updates, opt_state,
+                                          stats, loss)
+            out = (new_state, new_wire, loss)
+            return out + (gnorm,) if tele_on else out
 
     # the wire-residual carry (error feedback on) rides as ONE extra
     # jit argument — a dict of per-bucket [world, n] fp32 arrays,
@@ -1110,14 +1050,9 @@ def _make_spmd_train_step(model, tx, mesh=None,
     # result metadata — byte-identical to a build with no wire
     # plumbing at all.
     if use_ef:
-        prog = _SpmdProgram(plan, global_step,
-                            arg_specs=(wire_spec, batch_spec, batch_spec),
-                            n_scalar_outs=2 if tele_on else 1,
-                            donate=donate,
-                            aux_out_specs=(wire_spec,),
-                            extra_donate=(1,))
+        program_fn = global_step
     else:
-        def _global_step_stateless(state, inputs, labels):
+        def program_fn(state, inputs, labels):
             out = global_step(state, {"rs": [], "ag": []}, inputs,
                               labels)
             return (out[0],) + out[2:]  # drop the empty wire slot
@@ -1125,153 +1060,33 @@ def _make_spmd_train_step(model, tx, mesh=None,
         # keep the jitted module's name (jit_global_step) — the
         # compression-off program must be byte-identical, debug
         # metadata included
-        _global_step_stateless.__name__ = "global_step"
-        _global_step_stateless.__qualname__ = global_step.__qualname__
-        prog = _SpmdProgram(plan, _global_step_stateless,
-                            arg_specs=(batch_spec, batch_spec),
-                            n_scalar_outs=2 if tele_on else 1,
-                            donate=donate)
-    _check_wire_drift = _spmd_wire_drift_checker(tx, wire)
+        program_fn.__name__ = "global_step"
+        program_fn.__qualname__ = global_step.__qualname__
+    prog = _SpmdProgram(plan, program_fn, (batch_spec, batch_spec),
+                        n_scalar_outs=2 if tele_on else 1, donate=donate,
+                        carry_spec=carry.spec if use_ef else None)
 
-    _wire_holder = [None]
+    place_data = _placer(mesh, batch_spec)
+    if loader is not None:
+        # prefetched batches are staged by the PRODUCER thread directly
+        # onto the plan's batch NamedSharding — they arrive matching the
+        # compiled step's in_shardings, so dispatch-time placement is a
+        # no-op
+        loader.attach_placement(place_data,
+                                spec=plan.sharding(batch_spec))
 
-    def _wire_state_for(state):
-        """Zero-initialized residual buffers ([world, n] global, row r =
-        rank r's carry), rebuilt lazily from the live state —
-        rebuildable by construction, so never checkpointed. The chunked
-        island carries both exchange halves; the cast+ZeRO-1 annotation
-        path carries the delta all-gather half only."""
-        if sharded_tx:
-            schedule = state.opt_state.plan.schedule
-        else:
-            mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-            schedule = _grad_schedule(
-                state.params,
-                int(np.prod([mesh_shape[a] for a in reduce_axes])))
-        w = schedule.world
-
-        def size_or_zero(i, n):
-            # non-float buckets are never quantized — zero-width buffer
-            # keeps per-bucket index alignment without dead HBM traffic
-            return n if jnp.issubdtype(schedule.buckets[i].dtype,
-                                       jnp.floating) else 0
-
-        rs = ([jnp.zeros((w, size_or_zero(i, p)), jnp.float32)
-               for i, p in enumerate(schedule.padded_sizes)]
-              if chunked else [])
-        ag = [jnp.zeros((w, size_or_zero(i, s)), jnp.float32)
-              for i, s in enumerate(schedule.shard_sizes)]
-        return jax.tree_util.tree_map(
-            lambda x: _put(x, plan.sharding(wire_spec)),
-            {"rs": rs, "ag": ag})
-
-    def _wire_state(state):
-        if _wire_holder[0] is None:
-            _wire_holder[0] = _wire_state_for(state)
-        return _wire_holder[0]
-
-    def _reset_error_feedback():
-        """Drop the carried residuals; the next step rebuilds zeros
-        (call after rolling ``state`` back to an earlier commit)."""
-        _wire_holder[0] = None
-
-    from horovod_tpu.diag import recorder as _flightrec
-    from horovod_tpu.telemetry import ledger as _ledger_lib
-
-    instruments = (telemetry_lib.StepInstruments() if tele_on else None)
-    _step_no = [0]
-
-    def step(state, inputs=None, labels=None):
-        n = _step_no[0]
-        with scopes.step(n):
-            if inputs is None:
-                inputs, labels = _loader_batch()
-            _step_no[0] = n + 1
-            _flightrec.step_begin(n)
-            with scopes.host(scopes.PLACE):
-                if use_ef:
-                    placed = (place_state(state), _wire_state(state),
-                              place_data(inputs), place_data(labels))
-                else:
-                    placed = (place_state(state), place_data(inputs),
-                              place_data(labels))
-            _check_wire_drift()
-            ex = prog.executable(placed)  # one compile per shape signature
-            step.jitted = prog.jitted
-            step.compiled_collectives = prog.compiled_collectives
-            step.compiled_axis_collectives = prog.compiled_axis_collectives
-            t0 = _time.perf_counter()
-            try:
-                with scopes.host(scopes.LAUNCH):
-                    outs = ex(*placed)
-            except BaseException:
-                # the residuals were donated into the failed dispatch —
-                # drop them so a retry rebuilds zeros instead of dying on
-                # deleted arrays
-                _wire_holder[0] = None
-                raise
-            if use_ef:
-                new_state, rest = outs[0], outs[2:]
-                _wire_holder[0] = outs[1]
-            else:
-                new_state, rest = outs[0], outs[1:]
-            loss = rest[0]
-            gnorm = rest[1] if tele_on else None
-            _flightrec.step_end(n)
-            ledger = _ledger_lib.get_ledger()
-            ledger.note_compiled_path()
-            ledger.settle_step()
-            if instruments is not None:
-                instruments.record_step(
-                    batch=int(inputs.shape[0]),
-                    dispatch_s=_time.perf_counter() - t0,
-                    loss=loss, grad_norm=gnorm,
-                    step_no=instruments.steps.value)
-        return new_state, loss
-
-    def xray(state, inputs=None, labels=None, k=3, profile_dir=None):
-        """Opt-in compiled-step X-ray: run K steps of the ALREADY
-        compiled executable under a device trace and attribute where
-        the device time went (telemetry/xprof.py). Capture wraps
-        around the dispatch — the compiled program is byte-identical
-        with X-ray off. State threads through the captured steps
-        (donation as usual): returns ``(new_state, summary)``."""
-        from horovod_tpu.telemetry import xprof as _xprof
-        if inputs is None:
-            inputs, labels = _loader_batch()
-        return _xprof.xray_run(
-            step, state, (inputs, labels), k=k, profile_dir=profile_dir,
-            compiled_collectives=lambda: step.compiled_collectives)
-
-    def lower(state, inputs, labels):
-        if use_ef:
-            placed = (place_state(state), _wire_state(state),
-                      place_data(inputs), place_data(labels))
-        else:
-            placed = (place_state(state), place_data(inputs),
-                      place_data(labels))
-        lowered = prog.lower(placed)
-        step.jitted = prog.jitted
-        return lowered
-
-    if instruments is not None:
-        step.instruments = instruments
-    step.jitted = None  # set at first build
-    step.lower = lower
-    step.reset_error_feedback = _reset_error_feedback
+    step = _spmd_host_step(
+        prog, mesh, (place_data, place_data), tx, wire,
+        carry=carry if use_ef else None,
+        instruments=telemetry_lib.StepInstruments() if tele_on else None,
+        loader=loader)
+    step.reset_error_feedback = carry.reset
     step.loader = loader
     step.place_data = place_data
-    step.plan = plan
-    step.spmd = True
-    step.compiled_collectives = None  # set at first call
-    step.compiled_axis_collectives = None
-    step._settles_ledger = True
-    step.xray = xray
     return step
 
 
-def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
-                             donate=True):
+def _make_spmd_lm_train_step(model, tx, mesh, batch_axis, donate):
     """The GSPMD LM step behind ``make_lm_train_step(spmd=True)``:
     next-token mean loss over the batch-sharded tokens.
 
@@ -1285,12 +1100,7 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
     step's ``fused_allreduce`` route, so ``step(state, tokens)`` keeps
     its two-argument signature and the two builds stay head-to-head
     comparable in ``bench.py``."""
-    from horovod_tpu.ops import fusion
-    from horovod_tpu.parallel import gspmd as gspmd_lib
-    from horovod_tpu.parallel import zero as zero_lib
-
     wire = _spmd_gate(tx, "make_lm_train_step")
-    mesh = mesh if mesh is not None else mesh_lib.get_mesh()
     plan = gspmd_lib.derive_plan(mesh)
     token_spec = P(batch_axis)
     sharded_tx = tx.sharded_update
@@ -1299,16 +1109,9 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
     chunked = wire is not None and getattr(wire, "chunked", False)
 
     def _local_loss(params, tokens):
-        logits = model.apply({"params": params}, tokens)
-        targets = tokens[:, 1:]
-        logits_t = (logits[:, :-1]
-                    if targets.shape[1] == logits.shape[1] - 1
-                    else logits)
+        ll = _next_token_ll(model.apply({"params": params}, tokens),
+                            tokens[:, 1:])
         with scopes.device(scopes.LOSS):
-            logp = jax.nn.log_softmax(logits_t.astype(jnp.float32),
-                                      axis=-1)
-            ll = jnp.take_along_axis(logp, targets[..., None],
-                                     axis=-1)[..., 0]
             return -jnp.mean(ll)
 
     if chunked:
@@ -1318,52 +1121,13 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
             # shards, IS the exact global mean
             loss, grads = jax.value_and_grad(_local_loss)(state.params,
                                                           tokens)
-            if sharded_tx:
-                schedule = state.opt_state.plan.schedule
-                rs_op = state.opt_state.plan.op
-            else:
-                schedule = fusion.bucket_schedule(
-                    jax.tree_util.tree_leaves(state.params),
-                    world=collective.mesh_size(reduce_axes),
-                    threshold_bytes=tx.threshold_bytes,
-                    axes=reduce_axes,
-                    hierarchical=tx._hierarchical_resolved())
-                rs_op = tx.op
-            leaves_g = jax.tree_util.tree_leaves(grads)
-            shards = []
-            for i in range(len(schedule.buckets)):
-                s, _ = fusion.reduce_scatter_bucket_compressed(
-                    schedule, i, leaves_g, wire, op=rs_op)
-                shards.append(s)
-            if sharded_tx:
-                grad_rows = {f"b{i}": s[None]
-                             for i, s in enumerate(shards)}
-                updates, opt_state = zero_lib.apply_shards(
-                    tx.inner, grad_rows, state.opt_state, state.params,
-                    wire=wire)
-            else:
-                leaves_p, treedef = jax.tree_util.tree_flatten(
-                    state.params)
-                new_leaves = [None] * len(leaves_p)
-                for i, s in enumerate(shards):
-                    flat, _ = fusion.all_gather_bucket_compressed(
-                        schedule, i, s, wire)
-                    for j, arr in fusion.unpack_bucket(
-                            schedule, i, flat, leaves_p).items():
-                        new_leaves[j] = arr
-                grads_full = jax.tree_util.tree_unflatten(treedef,
-                                                          new_leaves)
-                updates, opt_state = tx.update_preaveraged(
-                    grads_full, state.opt_state, state.params)
-            with scopes.device(scopes.OPTIMIZER):
-                params = optax.apply_updates(state.params, updates)
-            with scopes.device(scopes.LOSS):
-                loss = collective.allreduce(loss, op=collective.Average,
-                                            axes=(batch_axis,))
-            new_state = TrainState(params=params, opt_state=opt_state,
-                                   batch_stats=state.batch_stats,
-                                   step=state.step + 1)
-            return new_state, loss
+            schedule, shards = _reduce_scatter_grads(
+                tx, reduce_axes, state, grads, wire, None)
+            updates, opt_state = _update_from_shards(
+                tx, schedule, shards, state, wire, None)
+            return _next_state(state, updates, opt_state,
+                               state.batch_stats, loss,
+                               mean_over=(batch_axis,))
 
         def global_step(state, tokens):
             specs = state_specs(state)
@@ -1379,87 +1143,17 @@ def _make_spmd_lm_train_step(model, tx, mesh=None, batch_axis="data",
             loss, grads = jax.value_and_grad(_local_loss)(state.params,
                                                           tokens)
             if wire is not None and not sharded_tx:
-                # plain DP: round-trip through the wire dtype as the
-                # convert-sinking hint (see _make_spmd_train_step)
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(wire.wire_dtype).astype(g.dtype)
-                               if jnp.issubdtype(g.dtype, jnp.floating)
-                               else g), grads)
-            if wire is not None and sharded_tx:
-                updates, opt_state = tx.update_spmd(
-                    grads, state.opt_state, state.params, plan,
-                    wire=wire)
-            else:
-                updates, opt_state = tx.update_spmd(
-                    grads, state.opt_state, state.params, plan)
-            with scopes.device(scopes.OPTIMIZER):
-                params = optax.apply_updates(state.params, updates)
-            new_state = TrainState(params=params, opt_state=opt_state,
-                                   batch_stats=state.batch_stats,
-                                   step=state.step + 1)
-            return new_state, loss
+                grads = _through_wire_dtype(grads, wire)
+            updates, opt_state = tx.update_spmd(
+                grads, state.opt_state, state.params, plan,
+                wire=wire if sharded_tx else None)
+            return _next_state(state, updates, opt_state,
+                               state.batch_stats, loss)
 
-    place_tokens = _placer(mesh, token_spec)
-
-    def place_state(state):
-        if prog.state_shardings is not None:
-            return jax.tree_util.tree_map(_put, state,
-                                          prog.state_shardings)
-        return gspmd_lib.place_state(plan, state)
-
-    prog = _SpmdProgram(plan, global_step, arg_specs=(token_spec,),
-                        n_scalar_outs=1, donate=donate)
-    _check_wire_drift = _spmd_wire_drift_checker(tx, wire)
-
-    from horovod_tpu.diag import recorder as _flightrec
-    from horovod_tpu.telemetry import ledger as _ledger_lib
-    _step_no = [0]
-
-    def step(state, tokens):
-        n = _step_no[0]
-        _step_no[0] = n + 1
-        with scopes.step(n):
-            _flightrec.step_begin(n)
-            with scopes.host(scopes.PLACE):
-                placed = (place_state(state), place_tokens(tokens))
-            _check_wire_drift()
-            ex = prog.executable(placed)  # one compile per shape signature
-            step.jitted = prog.jitted
-            step.compiled_collectives = prog.compiled_collectives
-            step.compiled_axis_collectives = prog.compiled_axis_collectives
-            with scopes.host(scopes.LAUNCH):
-                out = ex(*placed)
-            _flightrec.step_end(n)
-            ledger = _ledger_lib.get_ledger()
-            ledger.note_compiled_path()
-            ledger.settle_step()
-        return out
-
-    def lower(state, tokens):
-        placed = (place_state(state), place_tokens(tokens))
-        lowered = prog.lower(placed)
-        step.jitted = prog.jitted
-        return lowered
-
-    def xray(state, tokens, k=3, profile_dir=None):
-        """Compiled-step X-ray for the LM step — see the ResNet twin:
-        K traced executions of the already-compiled program, device
-        time attributed by telemetry/xprof.py. Returns
-        ``(new_state, summary)``."""
-        from horovod_tpu.telemetry import xprof as _xprof
-        return _xprof.xray_run(
-            step, state, (tokens,), k=k, profile_dir=profile_dir,
-            compiled_collectives=lambda: step.compiled_collectives)
-
-    step.jitted = None
-    step.lower = lower
-    step.plan = plan
-    step.spmd = True
-    step.compiled_collectives = None
-    step.compiled_axis_collectives = None
-    step._settles_ledger = True
-    step.xray = xray
-    return step
+    prog = _SpmdProgram(plan, global_step, (token_spec,), n_scalar_outs=1,
+                        donate=donate)
+    return _spmd_host_step(prog, mesh, (_placer(mesh, token_spec),), tx,
+                           wire)
 
 
 def elastic_train_loop(elastic_state, train_step, batch_fn, num_steps,
@@ -1496,10 +1190,7 @@ def elastic_train_loop(elastic_state, train_step, batch_fn, num_steps,
     latency / examples-per-sec itself, so a hand-written step function
     still shows up on the metrics plane.
     """
-    import time as _time
-
     from horovod_tpu import elastic as _elastic
-    from horovod_tpu import telemetry as telemetry_lib
 
     if checkpoint_every is not None:
         if not getattr(elastic_state, "_directory", None):
@@ -1518,7 +1209,6 @@ def elastic_train_loop(elastic_state, train_step, batch_fn, num_steps,
     if telemetry_lib.enabled() and not hasattr(train_step, "instruments"):
         own_instruments = telemetry_lib.StepInstruments()
 
-    from horovod_tpu.telemetry import ledger as _ledger_lib
     # a hand-written train_step doesn't settle the goodput ledger itself
     # — the loop does it, so its steps still get time attribution
     _goodput = (None if getattr(train_step, "_settles_ledger", False)
@@ -1543,16 +1233,15 @@ def elastic_train_loop(elastic_state, train_step, batch_fn, num_steps,
                 inputs, labels = next(loader)
             else:
                 inputs, labels = batch_fn(_step_of(state.train_state))
-            t0 = _time.perf_counter()
+            t0 = time.perf_counter()
             new_ts, loss = train_step(state.train_state, inputs, labels)
             if _goodput is not None:
                 _goodput().settle_step()
             if own_instruments is not None:
-                from horovod_tpu import basics as _basics
                 own_instruments.record_step(
                     batch=_batch_of(inputs),
-                    dispatch_s=_time.perf_counter() - t0, loss=loss,
-                    timeline=_basics._state.timeline)
+                    dispatch_s=time.perf_counter() - t0, loss=loss,
+                    timeline=basics._state.timeline)
             state.train_state = new_ts
             done = _step_of(new_ts)
             if on_step is not None:
@@ -1596,16 +1285,14 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
     seq-parallel loss and gradient match the single-device full-sequence
     computation.
     """
+    mesh = mesh if mesh is not None else mesh_lib.get_mesh()
     if spmd:
         if seq_axis is not None:
             raise ValueError(
                 "make_lm_train_step(spmd=True) shards the batch axis "
                 "only; ring attention (seq_axis) is the explicit path's "
                 "shard_map schedule — drop seq_axis or spmd")
-        return _make_spmd_lm_train_step(model, tx, mesh=mesh,
-                                        batch_axis=batch_axis,
-                                        donate=donate)
-    mesh = mesh if mesh is not None else mesh_lib.get_mesh()
+        return _make_spmd_lm_train_step(model, tx, mesh, batch_axis, donate)
     grad_axes = (batch_axis,) if seq_axis is None else (batch_axis, seq_axis)
     n_shards = int(np.prod([mesh.shape[a] for a in grad_axes]))
     n_seq = mesh.shape[seq_axis] if seq_axis else 1
@@ -1626,16 +1313,9 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
             mask = jnp.ones(targets.shape, jnp.float32)
 
         def compute_loss(params):
-            logits = model.apply({"params": params}, tokens)
-            if targets.shape[1] == logits.shape[1] - 1:
-                logits_t = logits[:, :-1]
-            else:
-                logits_t = logits
+            ll = _next_token_ll(model.apply({"params": params}, tokens),
+                                targets)
             with scopes.device(scopes.LOSS):
-                logp = jax.nn.log_softmax(logits_t.astype(jnp.float32),
-                                          axis=-1)
-                ll = jnp.take_along_axis(logp, targets[..., None],
-                                         axis=-1)[..., 0]
                 local_sum = -jnp.sum(ll * mask)
                 global_count = collective.allreduce(
                     jnp.asarray(jnp.sum(mask), jnp.float32),
@@ -1647,15 +1327,8 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
 
         loss, grads = jax.value_and_grad(compute_loss)(state.params)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        with scopes.device(scopes.OPTIMIZER):
-            params = optax.apply_updates(state.params, updates)
-        with scopes.device(scopes.LOSS):
-            loss = collective.allreduce(loss, op=collective.Average,
-                                        axes=grad_axes)
-        new_state = TrainState(params=params, opt_state=opt_state,
-                               batch_stats=state.batch_stats,
-                               step=state.step + 1)
-        return new_state, loss
+        return _next_state(state, updates, opt_state, state.batch_stats,
+                           loss, mean_over=grad_axes)
 
     token_spec = P(batch_axis, seq_axis) if seq_axis else P(batch_axis)
 
@@ -1671,36 +1344,6 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
     # named for what it is: jit_hvd_lm_train_step (see make_train_step)
     jitted = jax.jit(hvd_lm_train_step,
                      donate_argnums=(0,) if donate else ())
-    place_tokens = _placer(mesh, token_spec)
-
-    def place_state(state):
-        return _placer(mesh, state_specs(state))(state)
-
-    from horovod_tpu.diag import recorder as _flightrec
-    from horovod_tpu.telemetry import ledger as _ledger_lib
-    _step_no = [0]
-
-    def step(state, tokens):
-        n = _step_no[0]
-        _step_no[0] = n + 1
-        with scopes.step(n):
-            _flightrec.step_begin(n)
-            with scopes.host(scopes.PLACE):
-                placed = (place_state(state), place_tokens(tokens))
-            with scopes.host(scopes.LAUNCH):
-                out = jitted(*placed)
-            _flightrec.step_end(n)
-            _ledger_lib.get_ledger().settle_step()
-        return out
-
+    step = _HostStep(jitted, mesh, (_placer(mesh, token_spec),))
     step.jitted = jitted  # AOT access (lower/compile/cost_analysis)
-    step._settles_ledger = True
-
-    def lower(state, tokens):
-        """AOT lower with the SAME placement the executed path uses (one
-        shared compile-cache entry; cost_analysis describes the module
-        that actually runs)."""
-        return jitted.lower(place_state(state), place_tokens(tokens))
-
-    step.lower = lower
     return step

@@ -727,11 +727,15 @@ def test_config_wire_dtype_binds_late(hvd):
         cfg.wire_dtype = old
 
 
-def test_step_failure_does_not_brick_error_feedback(hvd):
+@pytest.mark.parametrize("build", [
+    dict(accum_steps=2, overlap_grads=True), dict(spmd=True)],
+    ids=["overlap-pipeline", "spmd-island"])
+def test_step_failure_does_not_brick_error_feedback(hvd, build):
     """The EF residuals are donated into each dispatch; a step call that
     raises must drop the carried buffers so the NEXT call (the elastic
     retry path) rebuilds zeros instead of dying on deleted arrays, and
-    reset_error_feedback() gives rollbacks an explicit restart."""
+    reset_error_feedback() gives rollbacks an explicit restart. One host
+    scaffold holds the carry for both builds that have one."""
     model = MLP(features=(10, 3))
     X = jnp.asarray(np.random.default_rng(0).standard_normal((16, 5)),
                     jnp.float32)
@@ -740,11 +744,15 @@ def test_step_failure_does_not_brick_error_feedback(hvd):
                                       compression="int8")
     state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
                                         X[:1])
-    step = training.make_train_step(model, tx, accum_steps=2,
-                                    overlap_grads=True)  # donate=True
+    step = training.make_train_step(model, tx, **build)  # donate=True
     state, _ = step(state, X, y)  # populates + donates the residuals
+    carried = jax.tree_util.tree_leaves(step._carry.get(state))
+    assert carried and any(float(jnp.abs(r).max()) > 0 for r in carried)
     with pytest.raises(Exception):
         step(state, X[:, :3], y)  # wrong feature width — dispatch fails
+    rebuilt = jax.tree_util.tree_leaves(step._carry.get(state))
+    assert [r.shape for r in rebuilt] == [r.shape for r in carried]
+    assert all(float(jnp.abs(r).max()) == 0 for r in rebuilt)
     state, loss = step(state, X, y)  # must NOT raise "Array has been deleted"
     assert np.isfinite(float(loss))
     step.reset_error_feedback()

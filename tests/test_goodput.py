@@ -391,12 +391,20 @@ def test_healthz_503_through_elastic_reset(monkeypatch, tmp_path):
 # ---------------------------------------------------------------------------
 
 DATA_DELAY_S = 0.10
-N_STEPS = 6
+# enough steps that the data stall outweighs the compile phase even when
+# the other xdist workers have the cores (0.1 s alone, 0.4 s under load)
+N_STEPS = 10
 CKPT_SLEEP_S = 0.12
 N_SAVES = 2
 
 
 def _attribution_run(monkeypatch, tmp_path, rank, size, dump_dir):
+    """One rank's run. Returns the stalls the training thread saw on the
+    test's own clock: ``{"data_wait", "ckpt_stall"}`` in seconds. They
+    are NOT the nominal injections: the prefetch thread produces batches
+    while the first step traces and compiles, so part of the data delay
+    is hidden (one batch on an idle machine, three under load), and a
+    blocking save takes its sleeps plus the scheduler's wake-ups."""
     import jax
     import optax
 
@@ -424,7 +432,17 @@ def _attribution_run(monkeypatch, tmp_path, rank, size, dump_dir):
         state = training.create_train_state(
             model, tx, jax.random.PRNGKey(0), xs[:1])
 
-        loader = PrefetchLoader(
+        seen = {"data_wait": 0.0, "ckpt_stall": 0.0}
+
+        class TimedLoader(PrefetchLoader):
+            def __next__(self):
+                t0 = time.perf_counter()
+                try:
+                    return super().__next__()
+                finally:
+                    seen["data_wait"] += time.perf_counter() - t0
+
+        loader = TimedLoader(
             ArraySource([xs, ys], delay_s=DATA_DELAY_S), batch,
             rank=rank, world=size, seed=0, shuffle=False, epochs=None)
         step = training.make_train_step(model, tx, loader=loader,
@@ -446,11 +464,14 @@ def _attribution_run(monkeypatch, tmp_path, rank, size, dump_dir):
                                world=1)
         tree = {"w": np.arange(64, dtype=np.float32)}
         for s in range(1, N_SAVES + 1):
+            t0 = time.perf_counter()
             ck.save(s, tree, block=True)
+            seen["ckpt_stall"] += time.perf_counter() - t0
         ck.close()
         monkeypatch.setattr(sharded_lib, "write_shard", real_write)
     finally:
         hvd_mod.shutdown()  # writes goodput.rank<rank>.json to dump_dir
+    return seen
 
 
 def test_two_rank_injected_stall_attribution(monkeypatch, tmp_path,
@@ -467,24 +488,26 @@ def test_two_rank_injected_stall_attribution(monkeypatch, tmp_path,
     warm_dir.mkdir()
     _attribution_run(monkeypatch, tmp_path, 0, 2, str(warm_dir))
 
-    injected_data = N_STEPS * DATA_DELAY_S
-    injected_ckpt = N_SAVES * CKPT_SLEEP_S
-
-    # The timing bounds (±20% on the injected stalls, <2% unattributed)
-    # flake under CPU contention on the single-core CI box; retry the
-    # measured run up to 3× with fresh dirs — the structural asserts
-    # (both dumps present, self-describing build_info, doctor exits 0)
-    # hold unconditionally on every attempt, only the timing bounds may
-    # send us around again (same pattern as test_ckpt.py's async-save
-    # stall bound).
+    # The timing bounds (the ledger within ±20% of the stall each rank
+    # saw on the test's own clock, <2% unattributed) can still flake
+    # under CPU contention; retry the measured run up to 3× with fresh
+    # dirs — the structural asserts (both dumps present, self-describing
+    # build_info, doctor exits 0) hold unconditionally on every attempt,
+    # only the timing bounds may send us around again (same pattern as
+    # test_ckpt.py's async-save stall bound).
     timing_failures = []
     for attempt in range(3):
         base = tmp_path / f"try{attempt}"
         base.mkdir()
         dump_dir = base / "dumps"
         dump_dir.mkdir()
+        seen = {rank: _attribution_run(monkeypatch, base, rank, 2,
+                                       str(dump_dir))
+                for rank in (0, 1)}
         for rank in (0, 1):
-            _attribution_run(monkeypatch, base, rank, 2, str(dump_dir))
+            # the injections are real stalls, whatever the prefetch hid
+            assert seen[rank]["data_wait"] > 0.4 * N_STEPS * DATA_DELAY_S
+            assert seen[rank]["ckpt_stall"] >= N_SAVES * CKPT_SLEEP_S
 
         dumps, skipped = report_mod.load_dumps(str(dump_dir))
         assert sorted(dumps) == [0, 1], \
@@ -500,14 +523,11 @@ def test_two_rank_injected_stall_attribution(monkeypatch, tmp_path,
         try:
             for rank in (0, 1):
                 phases = report["ranks"][rank]["phases"]
-                assert phases["data_wait"] == pytest.approx(
-                    injected_data, rel=0.20), \
-                    f"rank {rank} data_wait {phases['data_wait']:.3f}s " \
-                    f"vs injected {injected_data:.3f}s"
-                assert phases["ckpt_stall"] == pytest.approx(
-                    injected_ckpt, rel=0.20), \
-                    f"rank {rank} ckpt_stall {phases['ckpt_stall']:.3f}s " \
-                    f"vs injected {injected_ckpt:.3f}s"
+                for phase in ("data_wait", "ckpt_stall"):
+                    assert phases[phase] == pytest.approx(
+                        seen[rank][phase], rel=0.20), \
+                        f"rank {rank} {phase} {phases[phase]:.3f}s vs " \
+                        f"{seen[rank][phase]:.3f}s on the test's clock"
                 # every second explained: the dump was written after a
                 # final settle, so the unattributed tail is ~nothing
                 assert report["ranks"][rank]["unattributed_seconds"] < \

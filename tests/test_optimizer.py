@@ -82,6 +82,11 @@ def test_distributed_optimizer_training_converges(hvd, n_devices):
     w, opt_state = w0, opt_state0
     for _ in range(200):
         w, opt_state = sharded_step(w, opt_state, X, y)
+        # one step in flight at a time: 200 dispatches queued ahead, each
+        # an eight-participant rendezvous on the CPU backend's thread
+        # pool, abort the process (SIGABRT, no Python error) when other
+        # xdist workers hold the cores
+        w.block_until_ready()
     np.testing.assert_allclose(np.asarray(w), w_true, atol=1e-2)
 
 

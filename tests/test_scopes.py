@@ -41,7 +41,7 @@ def _mesh():
     return jax.sharding.Mesh(np.asarray(jax.devices()[:WORLD]), ("data",))
 
 
-def _lm(threshold_bytes=None):
+def _lm(threshold_bytes=None, **step_kwargs):
     """``(step, state, (tokens,))`` of a two-layer LM on the data mesh."""
     cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=2,
                             d_model=16, d_ff=32, dtype=jnp.float32,
@@ -54,11 +54,12 @@ def _lm(threshold_bytes=None):
     state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
                                         tokens[:1])
     step = training.make_lm_train_step(model, tx, mesh=_mesh(),
-                                       batch_axis="data", donate=False)
+                                       batch_axis="data", donate=False,
+                                       **step_kwargs)
     return step, state, (tokens,)
 
 
-def _classifier():
+def _classifier(**step_kwargs):
     """``(step, state, (inputs, labels))`` of a small MLP through
     ``make_train_step`` with SGD momentum."""
     import flax.linen as nn
@@ -76,11 +77,18 @@ def _classifier():
     labels = jnp.asarray(rng.integers(0, 10, size=(WORLD * 2,)), jnp.int32)
     state = training.create_train_state(model, tx, jax.random.PRNGKey(0),
                                         inputs[:1])
-    step = training.make_train_step(model, tx, mesh=_mesh(), donate=False)
+    step = training.make_train_step(model, tx, mesh=_mesh(), donate=False,
+                                    **step_kwargs)
     return step, state, (inputs, labels)
 
 
 BUILDERS = {"lm": _lm, "classifier": _classifier}
+# The annotation-only GSPMD programs hold no explicit collective under
+# ``hvd_exchange``, so the device-scope tests stay on BUILDERS; what
+# surrounds the dispatch on the host is one scaffold for all four.
+HOST_BUILDERS = {**BUILDERS,
+                 "lm-spmd": lambda: _lm(spmd=True),
+                 "classifier-spmd": lambda: _classifier(spmd=True)}
 
 _INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = .*?\s([a-z][a-z\-]*)\(")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
@@ -109,7 +117,7 @@ def texts():
     hvd_api.init()
     try:
         yield {name: _compiled_text(build)
-               for name, build in BUILDERS.items()}
+               for name, build in HOST_BUILDERS.items()}
     finally:
         hvd_api.shutdown()
 
@@ -157,13 +165,30 @@ def test_loss_scope_forward_and_backward(texts, builder):
 
 @pytest.mark.parametrize("builder", sorted(BUILDERS))
 def test_scopes_change_no_program(monkeypatch, builder):
-    """Without its ``metadata={...}`` the optimised HLO is the same, byte
-    for byte, with every device scope replaced by a null context."""
+    """Without its ``metadata={...}`` the optimised HLO is the same,
+    instruction for instruction, with every device scope replaced by a
+    null context. (Until PR 29 ``strip`` cut the text at the tables,
+    which follow the header line: it compared two header lines.)"""
     def strip(text):
         # every instruction's metadata, and the tables of files, functions
-        # and stack frames it points into (the module's tail)
-        text = text.split("\nFileNames\n")[0]
-        return re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+        # and stack frames it points into (between the module's header
+        # line and its first computation)
+        out, tables = [], False
+        for line in text.split("\n"):
+            if line == "FileNames":
+                tables = True
+            elif tables and line.startswith(("%", "ENTRY")):
+                tables = False
+            if not tables:
+                out.append(line)
+        assert len(out) > 10, "the module's computations were cut away"
+        bare = re.sub(r",? ?metadata=\{[^}]*\}", "", "\n".join(out))
+        # the compiler names some instructions after their op_name
+        # (%jvp_jit_take_along_axis__.22): number the names by first use
+        seen = {}
+        return re.sub(r"%[\w.\-]+",
+                      lambda m: seen.setdefault(m.group(0), f"%n{len(seen)}"),
+                      bare)
 
     scoped = _compiled_text(BUILDERS[builder])
     monkeypatch.setattr(scopes, "device",
@@ -218,10 +243,11 @@ def _host_events(trace_dir):
     return out
 
 
-def test_host_spans_in_the_profilers_trace(hvd, tmp_path):
+@pytest.mark.parametrize("builder", sorted(HOST_BUILDERS))
+def test_host_spans_in_the_profilers_trace(hvd, tmp_path, builder):
     """Two steps under ``jax.profiler``: two ``hvd_step`` spans numbered 0
     and 1, each holding one ``hvd_place`` and one ``hvd_launch``."""
-    step, state, batch = _lm()
+    step, state, batch = HOST_BUILDERS[builder]()
     step.lower(state, *batch).compile()  # tracing is not what is timed
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
@@ -242,8 +268,71 @@ def test_host_spans_in_the_profilers_trace(hvd, tmp_path):
         assert inside == [scopes.LAUNCH, scopes.PLACE]
 
 
+# what each kind of step exposes beside ``__call__``; nothing of another
+# kind's list may appear on it
+_ALL_STEPS = {"jitted", "lower", "_settles_ledger"}
+_CLASSIFICATION = {"loader", "place_data", "reset_error_feedback"}
+_GSPMD = {"plan", "spmd", "compiled_collectives",
+          "compiled_axis_collectives", "xray"}
+STEP_ATTRIBUTES = {
+    "lm": _ALL_STEPS,
+    "classifier": _ALL_STEPS | _CLASSIFICATION,
+    "lm-spmd": _ALL_STEPS | _GSPMD,
+    "classifier-spmd": _ALL_STEPS | _CLASSIFICATION | _GSPMD,
+}
+
+
+@pytest.mark.parametrize("builder", sorted(HOST_BUILDERS))
+def test_host_contract_of_a_step_call(hvd, monkeypatch, builder):
+    """Each call gives the flight recorder one ``step_begin(n)`` /
+    ``step_end(n)`` pair, ``n`` counting from 0, and settles the goodput
+    ledger exactly once (the GSPMD builds first note the compiled path);
+    the step carries the attributes of its kind and no other's."""
+    from horovod_tpu.diag import recorder
+    from horovod_tpu.telemetry import ledger
+
+    events = []
+
+    class Ledger:
+        def note_compiled_path(self):
+            events.append(("compiled_path",))
+
+        def settle_step(self):
+            events.append(("settle",))
+
+    monkeypatch.setattr(recorder, "step_begin",
+                        lambda n: events.append(("begin", n)))
+    monkeypatch.setattr(recorder, "step_end",
+                        lambda n: events.append(("end", n)))
+    monkeypatch.setattr(ledger, "get_ledger", Ledger)
+
+    step, state, batch = HOST_BUILDERS[builder]()
+    spmd = builder.endswith("-spmd")
+    optional = _CLASSIFICATION | _GSPMD | {"instruments"}
+    assert {a for a in optional | _ALL_STEPS if hasattr(step, a)} \
+        == STEP_ATTRIBUTES[builder]
+    assert step._settles_ledger is True
+    if spmd:
+        assert step.spmd is True and step.jitted is None
+        assert step.compiled_collectives is None
+    for _ in range(2):
+        state, loss = step(state, *batch)
+    assert np.isfinite(float(loss))
+    per_call = ([("compiled_path",)] if spmd else []) + [("settle",)]
+    assert events == [("begin", 0), ("end", 0)] + per_call \
+        + [("begin", 1), ("end", 1)] + per_call
+    # the GSPMD steps read these through to the program, built by now
+    assert step.jitted is not None
+    if spmd:
+        assert step.compiled_collectives is not None
+        assert step.compiled_axis_collectives is not None
+    lowered = step.lower(state, *batch)
+    assert lowered.compile().as_text().startswith("HloModule jit_")
+
+
 @pytest.mark.parametrize("builder,module", [
-    ("lm", "jit_hvd_lm_train_step"), ("classifier", "jit_hvd_train_step")])
+    ("lm", "jit_hvd_lm_train_step"), ("classifier", "jit_hvd_train_step"),
+    ("lm-spmd", "jit_global_step"), ("classifier-spmd", "jit_global_step")])
 def test_jitted_step_is_named_for_what_it_is(texts, builder, module):
     # The module name is part of the persistent compile cache's key; the
     # scopes are not (they are debug metadata, which the key leaves out).
