@@ -19,7 +19,9 @@ layouts alone:
 * MLP ``Dense_0 (d_model, d_ff)`` column-parallel, ``Dense_1
   (d_ff, d_model)`` row-parallel — one more all-reduce.
 * ``lm_head (d_model, vocab)`` column-parallel: logits arrive
-  vocab-sharded and the loss's log-softmax gathers them.
+  vocab-sharded and stay so; the loss (``training._next_token_ll``)
+  reduces over them, and GSPMD all-reduces a row's maximum, its sum of
+  exponentials and its target's logit, ``[B, S]`` each.
 * norms/embedding replicated.
 
 Because the step is a single jitted program (no ``shard_map``), the data

@@ -14,6 +14,7 @@ Nothing executes; a pass is not a chip run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -195,3 +196,31 @@ def test_chip_smoke_refuses_to_run_without_a_chip():
     assert rv.stdout == ""
     assert "found platform 'cpu'" in rv.stderr
     assert "no phase was run" in rv.stderr
+
+
+def test_lm_head_and_loss_keep_no_float32_logits(v5e):
+    """The head and loss of the ``lm-d768-*`` cells, forward and
+    backward, as the model writes them (a bfloat16 product read as
+    float32): the only array over the vocabulary that the compiled
+    program holds in memory is the bfloat16 one the head wrote. No
+    float32 copy for the loss to gather from, none kept for the
+    backward pass, and no cut or padded copy for the dropped last
+    position (until PR 30: 3.3 GB written and read back every step)."""
+    from horovod_tpu.training import _next_token_ll
+
+    b, s, d, v = 8, 2048, 768, 50304
+
+    def loss(w, h, tokens):
+        logits = jnp.dot(h, w.astype(jnp.bfloat16)).astype(jnp.float32)
+        return -jnp.mean(_next_token_ll(logits, tokens[:, 1:]))
+
+    w = jax.ShapeDtypeStruct((d, v), jnp.float32, sharding=v5e)
+    h = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=v5e)
+    tokens = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=v5e)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        w, h, tokens).compile().as_text()
+    # the entry computation's instructions are the program's buffers;
+    # what a fusion computes inside itself never reaches memory
+    entry = text[text.index("\nENTRY"):]
+    over_vocabulary = set(re.findall(rf"\w+\[{b},\d+,{v}\]", entry))
+    assert over_vocabulary == {f"bf16[{b},{s},{v}]"}, over_vocabulary
