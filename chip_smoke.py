@@ -17,7 +17,12 @@ points users call, at the full width of two models the repo supports:
                     ``block_schedule`` (interior / diagonal / skipped pairs).
 * ``grouped_kernel`` the expert share's grouped products (megablox under
                     ``models/experts._gmm``'s VJP) against a loop over the
-                    groups: result, input and weight gradient.
+                    groups: result, input and weight gradient, at the
+                    SwiGLU cell's sizes and at the relu^2 cell's (a width
+                    off the 128 lanes).
+* ``state_space_scan`` the state-space mixer's chunked scan against the
+                    recurrence one step at a time, result and gradients,
+                    at its benchmark cell's sizes; then timed.
 * ``lm``            the decoder LM, 8 layers d2048 16 heads, vocab 32000,
                     sequence 2048, batch 8, flash on (``make_lm_bench`` ->
                     ``make_lm_train_step``): loss finite and falling on a
@@ -72,11 +77,22 @@ KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64),
 # tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
 KERNEL_REL_L2 = 2e-2
 KERNEL_ATOL = dict(out=5e-2, dq=8e-2, dk=8e-2, dv=8e-2)
-# the expert share's grouped products at its benchmark cell's sizes: all
-# T * k = 16,384 * 6 token-slots as rows, an eighth of them live, in 16
-# ragged groups; gate and up as one product [d, 2f], then down [f, d]
-GROUPED = dict(rows=98304, live=12288, groups=16,
-               products=((2048, 1536), (768, 2048)))
+# the expert share's grouped products at its benchmark cells' sizes: all
+# T * k token-slots as rows, the held experts' share of them live, in
+# ragged groups. SwiGLU experts (16,384 * 6 slots, an eighth live, 16
+# groups): gate and up as one product [d, 2f], then down [f, d]. relu^2
+# experts (8,192 * 6 slots, a sixteenth live, 8 groups): up [d, f], down
+# [f, d], at a width off the 128 lanes (1856 = 14.5 * 128)
+GROUPED = (dict(rows=98304, live=12288, groups=16,
+                products=((2048, 1536), (768, 2048))),
+           dict(rows=49152, live=3072, groups=8,
+                products=((2688, 1856), (1856, 2688))))
+# the state-space mixer's chunked scan at its benchmark cell's sizes:
+# [B, S] positions, H heads of P channels, G groups of N states, chunks of
+# 128; against the recurrence one step at a time in float32
+SCAN = dict(batch=2, seq_len=4096, heads=64, head_dim=64, groups=8,
+            states=128, chunk=128)
+SCAN_REL_L2 = 2e-2
 LM = dict(layers=8, d_model=2048, heads=16, vocab=32000, seq_len=2048,
           batch=8, steps=4)
 RESNET = dict(model="resnet101", batch=256, image=224, steps=3)
@@ -364,14 +380,25 @@ def phase_grouped_kernel():
     input gradient and the weight gradient, over the live rows. The rows
     past the groups' end carry numbers like any other, in the operand and
     in the cotangent: the weight gradient must not see them."""
+    import numpy as np
+
+    rng = np.random.default_rng(0)
+    results = []
+    with CompileWatch() as watch:
+        for a in GROUPED:
+            results += _grouped_case(a, rng)
+    _emit("grouped_kernel", products=results,
+          tolerance={"rel_l2": KERNEL_REL_L2}, **watch.fields())
+
+
+def _grouped_case(a, rng):
+    """The readings of one entry of ``GROUPED``, a product each."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from horovod_tpu.models import experts
 
-    a = GROUPED
-    rng = np.random.default_rng(0)
     sizes = rng.multinomial(a["live"],
                             rng.dirichlet(np.full(a["groups"], 0.5)))
     sizes[0] += sizes[3]
@@ -400,35 +427,100 @@ def phase_grouped_kernel():
             return (out,) + vjp(live(g))
 
     results = []
+    for k, n in a["products"]:
+        xs = jnp.asarray(rng.standard_normal((a["rows"], k)), jnp.bfloat16)
+        w = jnp.asarray(rng.standard_normal((a["groups"], k, n))
+                        / k ** 0.5, jnp.bfloat16)
+        g = jnp.asarray(rng.standard_normal((a["rows"], n)), jnp.bfloat16)
+        compiled = jax.jit(kernel).lower(xs, w, g).compile()
+        kernels = assert_kernel_compiled(
+            compiled.as_text(), f"grouped product {k} x {n}")
+        got, want = compiled(xs, w, g), jax.jit(loop)(xs, w, g)
+        errors = {}
+        for name, x, y in zip(("out", "d_xs", "d_w"), got, want):
+            x = np.asarray(x, np.float32)[:len(y)]
+            errors[name] = round(float(
+                np.linalg.norm(x - np.asarray(y))
+                / np.linalg.norm(y)), 5)
+            if not errors[name] <= KERNEL_REL_L2:
+                raise RuntimeError(
+                    f"grouped product {name} at {a['rows']} x {k} x "
+                    f"{n} disagrees with the loop over groups: rel_l2 "
+                    f"{errors[name]} (allowed {KERNEL_REL_L2})")
+        results.append({"shape": [a["rows"], k, n],
+                        "live_rows": a["live"], "groups": a["groups"],
+                        "dtype": "bfloat16",
+                        "forward_backward_ms": _ms_per_call(compiled,
+                                                            (xs, w, g)),
+                        "tpu_custom_calls": kernels, "rel_l2": errors})
+    return results
+
+
+def phase_state_space_scan():
+    """``models/ssm.chunked_scan`` in bfloat16 at the sizes of its
+    benchmark cell against the benchmark reference's recurrence, one step
+    at a time in float32: the result and the gradients of ``u``, ``B``,
+    ``C`` and the step. The steps and decays are drawn as the family
+    initialises them, so the slow heads carry a state across every one of
+    the 32 chunks."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import ssm_moe_lm as reference
+    from horovod_tpu.models import ssm
+
+    a = SCAN
+    rng = np.random.default_rng(0)
+    shape = (a["batch"], a["seq_len"])
+    normal = lambda *tail: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape + tail), jnp.bfloat16)
+    u = normal(a["heads"], a["head_dim"])
+    b, c = normal(a["groups"], a["states"]), normal(a["groups"], a["states"])
+    weight = normal(a["heads"], a["head_dim"]).astype(jnp.float32)
+    dt = jnp.asarray(np.exp(rng.uniform(
+        np.log(0.001), np.log(0.1), shape + (a["heads"],))), jnp.float32)
+    decay = -jnp.asarray(rng.uniform(1.0, 16.0, a["heads"]), jnp.float32)
+    skip = jnp.ones((a["heads"],), jnp.float32)
+
+    def both(scan):
+        # the weight is an argument: closed over, its 134 MB would be a
+        # constant of the executable and evict the compile cache
+        def value(u, b, c, dt, weight):
+            o = scan(u, b, c, dt)
+            return jnp.sum(o.astype(jnp.float32) * weight), o
+
+        def run(*x):
+            (_, o), grads = jax.value_and_grad(
+                value, argnums=(0, 1, 2, 3), has_aux=True)(*x)
+            return (o,) + grads
+        return jax.jit(run)
+
+    def recurrence(u, b, c, dt):
+        share = a["heads"] // a["groups"]
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        with jax.default_matmul_precision("highest"):
+            return reference._recurrence(
+                f32(u), jnp.repeat(f32(b), share, 2),
+                jnp.repeat(f32(c), share, 2), dt, dt * decay, skip)
+
+    chunked = both(lambda *x: ssm.chunked_scan(*x, decay, skip, a["chunk"]))
     with CompileWatch() as watch:
-        for k, n in a["products"]:
-            xs = jnp.asarray(rng.standard_normal((a["rows"], k)),
-                             jnp.bfloat16)
-            w = jnp.asarray(rng.standard_normal((a["groups"], k, n))
-                            / k ** 0.5, jnp.bfloat16)
-            g = jnp.asarray(rng.standard_normal((a["rows"], n)),
-                            jnp.bfloat16)
-            compiled = jax.jit(kernel).lower(xs, w, g).compile()
-            kernels = assert_kernel_compiled(
-                compiled.as_text(), f"grouped product {k} x {n}")
-            got, want = compiled(xs, w, g), jax.jit(loop)(xs, w, g)
-            errors = {}
-            for name, x, y in zip(("out", "d_xs", "d_w"), got, want):
-                x = np.asarray(x, np.float32)[:len(y)]
-                errors[name] = round(float(
-                    np.linalg.norm(x - np.asarray(y))
-                    / np.linalg.norm(y)), 5)
-                if not errors[name] <= KERNEL_REL_L2:
-                    raise RuntimeError(
-                        f"grouped product {name} at {a['rows']} x {k} x "
-                        f"{n} disagrees with the loop over groups: rel_l2 "
-                        f"{errors[name]} (allowed {KERNEL_REL_L2})")
-            results.append({"shape": [a["rows"], k, n],
-                            "live_rows": a["live"], "groups": a["groups"],
-                            "dtype": "bfloat16",
-                            "tpu_custom_calls": kernels, "rel_l2": errors})
-    _emit("grouped_kernel", products=results,
-          tolerance={"rel_l2": KERNEL_REL_L2}, **watch.fields())
+        args = (u, b, c, dt, weight)
+        got, want = chunked(*args), both(recurrence)(*args)
+        errors = {}
+        for name, x, y in zip(("o", "d_u", "d_B", "d_C", "d_step"), got,
+                              want):
+            x, y = (np.asarray(z, np.float32) for z in (x, y))
+            errors[name] = round(float(np.linalg.norm(x - y)
+                                       / np.linalg.norm(y)), 5)
+            if not errors[name] <= SCAN_REL_L2:
+                raise RuntimeError(
+                    f"chunked scan: {name} disagrees with the recurrence: "
+                    f"rel_l2 {errors[name]} (allowed {SCAN_REL_L2})")
+        _emit("state_space_scan", sizes=a, dtype="bfloat16", rel_l2=errors,
+              forward_backward_ms=_ms_per_call(chunked, args),
+              tolerance={"rel_l2": SCAN_REL_L2}, **watch.fields())
 
 
 def _lm_run(mesh, a):
@@ -673,7 +765,7 @@ def main():
                  f"{found}")
     phases = ([phase_init, phase_data_parallel] if args.four_chips else
               [phase_init, phase_flash_kernel, phase_grouped_kernel,
-               phase_lm, phase_resnet, phase_serve])
+               phase_state_space_scan, phase_lm, phase_resnet, phase_serve])
     for phase in phases:
         phase()
         gc.collect()  # the next phase needs the device memory back

@@ -17,10 +17,13 @@ the framework is benchmarkable and usable standalone:
   layer is a (mixer, feed-forward) pair read from a layer pattern.
 * ``mla``         — multi-head latent attention (the DeepSeek-V3 family's
   mixer), through the one flash kernel at two head sizes.
+* ``ssm``         — a Mamba-2 state-space mixer with a chunked scan (the
+  ``nemotron_h`` family's), the third mixer kind.
 * ``moe``         — capacity-dispatch mixture of experts over an expert
   mesh axis.
 * ``experts``     — a no-drop expert layer that holds one chip's share of
-  the experts, and the SwiGLU of dense and shared feed-forwards.
+  the experts (SwiGLU or relu² bodies), and the dense and shared
+  feed-forwards of the same bodies.
 
 All models are NHWC, bf16-compute/fp32-param by default — the layout the
 MXU wants.
@@ -39,11 +42,14 @@ from horovod_tpu.models.vgg import VGG16
 from horovod_tpu.models.transformer import Transformer, TransformerConfig
 from horovod_tpu.models.moe import MoE
 from horovod_tpu.models.mla import LatentAttention, LatentAttentionConfig
-from horovod_tpu.models.experts import ExpertShare, ExpertShareConfig, SwiGLU
+from horovod_tpu.models.experts import (ExpertShare, ExpertShareConfig,
+                                        Relu2, SwiGLU)
+from horovod_tpu.models.ssm import StateSpaceConfig, StateSpaceMixer
 
 __all__ = [
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "MNISTConvNet", "MLP", "VGG16", "Transformer", "TransformerConfig",
     "MoE", "LatentAttention", "LatentAttentionConfig", "ExpertShare",
-    "ExpertShareConfig", "SwiGLU",
+    "ExpertShareConfig", "SwiGLU", "Relu2", "StateSpaceConfig",
+    "StateSpaceMixer",
 ]

@@ -16,8 +16,14 @@ its own experts give:
 The weights are normalised over all k chosen, held or not; a token-slot
 whose expert is not held adds nothing, and what the absent experts would
 have added is left out, not stood in for: there is no exchange on one
-chip. The shared experts (``SwiGLU`` of width ``n_shared_experts *
-moe_d_ff``) are the caller's to add; every share computes them alike.
+chip. The shared experts (``shared_expert``: one module of width
+``n_shared_experts * moe_d_ff``, or ``shared_d_ff``) are the caller's to
+add; every share computes them alike.
+
+An expert's body is ``expert_body``: ``"swiglu"``, ``(silu(y Wg) * (y
+Wu)) Wd``, three matrices (the DeepSeek-V3 family's), or ``"relu2"``,
+``relu(y Wu)^2 Wd``, two (the ``nemotron_h`` family's). Routed and shared
+experts have the same body; the routing and the dispatch do not know it.
 
 Routing is sort-and-gather: the ``T * k`` token-slots are sorted by
 expert (held experts first, every slot of an absent expert after them),
@@ -36,6 +42,7 @@ the pass, would be lost on the device trace. PERF.md, PR 27.)
 
 import dataclasses
 import warnings
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -65,6 +72,14 @@ class ExpertShareConfig:
     # standard deviation the selection bias is drawn with at
     # initialisation (0: zeros, as a model trained from scratch starts)
     selection_bias_std: float = 0.0
+    expert_body: str = "swiglu"  # | "relu2"
+    # width of the shared experts' one module (None: n_shared_experts *
+    # moe_d_ff)
+    shared_d_ff: Optional[int] = None
+
+
+def _relu2(x):
+    return jnp.square(nn.relu(x))
 
 
 class SwiGLU(nn.Module):
@@ -80,6 +95,30 @@ class SwiGLU(nn.Module):
         hidden = nn.silu(dense(self.d_ff, "gate_proj")(y)) * dense(
             self.d_ff, "up_proj")(y)
         return dense(y.shape[-1], "down_proj")(hidden)
+
+
+class Relu2(nn.Module):
+    """``relu(y Wu)^2 Wd``: the two-matrix feed-forward."""
+    d_ff: int
+    dtype: object = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, y):
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, dtype=self.dtype, use_bias=False, name=name)
+        return dense(y.shape[-1], "down_proj")(
+            _relu2(dense(self.d_ff, "up_proj")(y)))
+
+
+BODIES = {"swiglu": SwiGLU, "relu2": Relu2}
+
+
+def shared_expert(cfg, dtype, name):
+    """The shared experts of a layer sized by ``cfg``, as one module of
+    the routed experts' body."""
+    return BODIES[cfg.expert_body](
+        cfg.shared_d_ff or cfg.n_shared_experts * cfg.moe_d_ff,
+        dtype=dtype, name=name)
 
 
 def _live(x, index, total):
@@ -164,6 +203,13 @@ def _tile(size, widths=(1024, 768, 512, 384, 256, 128)):
     return next((t for t in widths if size % t == 0), None)
 
 
+def _width(size):
+    """The tile of a product's width: ``_tile``, or, where none divides it
+    (an expert 1856 wide: 14.5 x 128 lanes), the whole width up to 2048 (a
+    block as wide as the array is a legal block whatever the lanes)."""
+    return _tile(size) or (size if size <= 2048 else None)
+
+
 @jax.custom_vjp
 def _gmm(xs, w, group_sizes):
     """The megablox grouped product ``[m, k] x [g, k, n] -> [m, n]`` with a
@@ -176,7 +222,7 @@ def _gmm(xs, w, group_sizes):
 
     (m, k), n = xs.shape, w.shape[2]
     return gmm(xs, w, group_sizes, xs.dtype,
-               (_tile(m, GMM_ROWS), _tile(k), _tile(n)))
+               (_tile(m, GMM_ROWS), _width(k), _width(n)))
 
 
 def _gmm_fwd(xs, w, group_sizes):
@@ -189,11 +235,11 @@ def _gmm_bwd(res, g):
     xs, w, group_sizes = res
     (m, k), n = xs.shape, w.shape[2]
     rows = _tile(m, GMM_ROWS)
-    d_xs = gmm(g, w, group_sizes, xs.dtype, (rows, _tile(n), _tile(k)),
+    d_xs = gmm(g, w, group_sizes, xs.dtype, (rows, _width(n), _width(k)),
                transpose_rhs=True)
     d_w = tgmm(
         xs.swapaxes(0, 1), g, group_sizes, w.dtype,
-        (rows, _tile(k), _tile(n)), num_actual_groups=w.shape[0])
+        (rows, _width(k), _width(n)), num_actual_groups=w.shape[0])
     return d_xs, d_w, None
 
 
@@ -206,17 +252,18 @@ def grouped_matmul(xs, w, group_sizes):
     caller reads them through ``_live``). On the TPU the megablox kernel,
     which walks only the tiles of the groups; off the TPU, and with a
     ``GroupedFallbackWarning`` where the kernel's tiles do not divide the
-    three sizes (a handful of tokens, a width off the 128 lanes),
+    three sizes (a handful of tokens, a wide width off the 128 lanes),
     ``jax.lax.ragged_dot``."""
     if jax.devices()[0].platform == "tpu":
-        if all((_tile(xs.shape[0], GMM_ROWS), _tile(xs.shape[1]),
-                _tile(w.shape[2]))):
+        if all((_tile(xs.shape[0], GMM_ROWS), _width(xs.shape[1]),
+                _width(w.shape[2]))):
             return _gmm(xs, w, group_sizes)
         warnings.warn(
             f"models.experts.grouped_matmul: jax.lax.ragged_dot ran in "
             f"place of the megablox kernel for {tuple(xs.shape)} x "
             f"{tuple(w.shape)}: rows must divide by one of {GMM_ROWS} and "
-            f"both widths by 128", GroupedFallbackWarning, stacklevel=2)
+            f"both widths by 128 (or be at most 2048)",
+            GroupedFallbackWarning, stacklevel=2)
     return jax.lax.ragged_dot(xs, w, group_sizes)
 
 
@@ -235,9 +282,13 @@ class ExpertShare(nn.Module):
         if not 0 <= c.expert_offset <= n - held:
             raise ValueError(f"experts {c.expert_offset}.."
                              f"{c.expert_offset + held} of {n}")
+        if c.expert_body not in BODIES:
+            raise ValueError(f"unknown expert body {c.expert_body!r}")
+        gated = c.expert_body == "swiglu"
         expert = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                               batch_axis=(0,))
-        w_gate = self.param("gate_proj", expert, (held, d, f))
+        if gated:
+            w_gate = self.param("gate_proj", expert, (held, d, f))
         w_up = self.param("up_proj", expert, (held, d, f))
         w_down = self.param("down_proj", expert, (held, f, d))
         router = self.param("router", nn.initializers.lecun_normal(), (d, n))
@@ -266,15 +317,18 @@ class ExpertShare(nn.Module):
             xs = _dispatch(y.astype(self.dtype), order, inverse, total)
 
         with scopes.device(scopes.MOE_EXPERTS):
-            # gate and up as one product: xs is read once, and one
-            # gradient comes back to it
             cast = lambda a: a.astype(self.dtype)  # noqa: E731
-            gate_up = grouped_matmul(
-                xs, jnp.concatenate([cast(w_gate), cast(w_up)], 2),
-                group_sizes)
-            gate, up = jnp.split(gate_up, 2, axis=1)
-            out = grouped_matmul(nn.silu(gate) * up, cast(w_down),
-                                 group_sizes)
+            if gated:
+                # gate and up as one product: xs is read once, and one
+                # gradient comes back to it
+                gate_up = grouped_matmul(
+                    xs, jnp.concatenate([cast(w_gate), cast(w_up)], 2),
+                    group_sizes)
+                gate, up = jnp.split(gate_up, 2, axis=1)
+                hidden = nn.silu(gate) * up
+            else:
+                hidden = _relu2(grouped_matmul(xs, cast(w_up), group_sizes))
+            out = grouped_matmul(hidden, cast(w_down), group_sizes)
 
         with scopes.device(scopes.MOE_ROUTE):
             return _combine(out, w.T, order, inverse, total)

@@ -7,10 +7,14 @@ of the TPU framework. Design:
 * Pre-RMSNorm, rotary position embeddings, GELU MLP — the standard modern
   decoder block, all shapes static and MXU-friendly (bf16 compute).
 * A layer is a pair (mixer, feed-forward) read from
-  ``TransformerConfig.layer_pattern``: multi-head attention or latent
-  attention (``models/mla.py``); GELU, SwiGLU, the capacity-dispatch MoE
+  ``TransformerConfig.layer_pattern``: multi-head attention (grouped-query
+  where ``num_kv_heads`` says so, without rotary where ``rotary`` is
+  off), latent attention (``models/mla.py``) or a state-space mixer
+  (``models/ssm.py``); GELU, SwiGLU, the capacity-dispatch MoE
   (``models/moe.py``) or the no-drop expert share with its shared experts
-  (``models/experts.py``). The default pattern is the block above.
+  (``models/experts.py``). Either half may be ``None``: a layer of one
+  sublayer, behind one norm and one residual. The default pattern is the
+  block above.
 * ``sequence_axis``: when set (inside shard_map over that mesh axis), the
   sequence dimension is sharded across the axis and attention runs as
   **ring attention** (``horovod_tpu.parallel.ring``): K/V blocks rotate
@@ -76,17 +80,29 @@ class TransformerConfig:
     moe_group_axis: Optional[str] = None
     # A layer is a pair (mixer, feed-forward), one pair a layer:
     #   mixer         "mha" (Attention below) | "mla" (models/mla.py,
-    #                 sized by ``mla``)
+    #                 sized by ``mla``) | "ssm" (models/ssm.py, sized by
+    #                 ``ssm``) | None
     #   feed-forward  "gelu" (two matrices, width d_ff) | "moe" (the
     #                 capacity dispatch of models/moe.py) | "swiglu"
     #                 (gated, width d_ff) | "experts" (the no-drop share
-    #                 of models/experts.py plus its shared experts, sized
-    #                 by ``experts``)
-    # None: ("mha", "moe" on every moe_every-th layer, else "gelu"), the
-    # block this file has always built, parameter for parameter.
+    #                 of models/experts.py plus its shared expert, sized
+    #                 by ``experts``) | None
+    # A half that is None has no norm, no parameters and no residual: the
+    # layer is the other half alone. The pattern None: ("mha", "moe" on
+    # every moe_every-th layer, else "gelu"), the block this file has
+    # always built, parameter for parameter.
     layer_pattern: Optional[tuple] = None
     mla: Any = None      # models.mla.LatentAttentionConfig
     experts: Any = None  # models.experts.ExpertShareConfig
+    ssm: Any = None      # models.ssm.StateSpaceConfig
+    # "mha" only. Key/value heads (None: one a query head); query head i
+    # attends to key/value head i // (num_heads // num_kv_heads). The
+    # width of a head (None: d_model // num_heads). Rotary position
+    # embedding on q and k, or none at all.
+    num_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rotary: bool = True
+    norm_eps: float = 1e-6  # of every RMSNorm (flax's own default)
 
     def layers(self):
         """The (mixer, feed-forward) pair of every layer."""
@@ -143,12 +159,27 @@ class Attention(nn.Module):
     def __call__(self, x, positions, contiguous_positions=False,
                  cache=None):
         cfg = self.cfg
-        h, d = cfg.num_heads, cfg.d_model // cfg.num_heads
-        dense = lambda name: nn.DenseGeneral(  # noqa: E731
-            (h, d), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
-        q = _rotary(dense("query")(x), positions)
-        k = _rotary(dense("key")(x), positions)
-        v = dense("value")(x)
+        h, h_kv = cfg.num_heads, cfg.num_kv_heads or cfg.num_heads
+        d = cfg.head_dim or cfg.d_model // h
+        if h % h_kv:
+            raise ValueError(f"{h} query heads do not split over {h_kv} "
+                             f"key/value heads")
+        dense = lambda name, heads=h: nn.DenseGeneral(  # noqa: E731
+            (heads, d), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
+        q, k, v = dense("query")(x), dense("key", h_kv)(x), dense(
+            "value", h_kv)(x)
+        if cfg.rotary:
+            q, k = _rotary(q, positions), _rotary(k, positions)
+        if h_kv != h:
+            if cache is not None:
+                raise NotImplementedError(
+                    "the paged cache holds one key/value head a query head "
+                    "(serve/kvcache.py); grouped-query attention trains "
+                    "only")
+            # every kernel and path below takes one key/value head a query
+            # head: broadcast the shared heads, and the broadcast's
+            # transpose sums their query heads' gradients back
+            k, v = (jnp.repeat(a, h // h_kv, axis=2) for a in (k, v))
         if cache is not None:
             # incremental decode: attend over cached context ++ the new
             # tokens, and hand the new tokens' (post-rotary) K/V back to
@@ -204,29 +235,39 @@ class Attention(nn.Module):
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    mixer: str = "mha"
-    feed_forward: str = "gelu"
+    mixer: Optional[str] = "mha"
+    feed_forward: Optional[str] = "gelu"
 
     @nn.compact
     def __call__(self, x, positions, contiguous_positions=False,
                  cache=None):
         cfg = self.cfg
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
-        if self.mixer == "mha":
-            attention = Attention(cfg, name="attn")
-        elif self.mixer == "mla":
-            from horovod_tpu.models.mla import LatentAttention
-            attention = LatentAttention(cfg, name="attn")
-        else:
-            raise ValueError(f"unknown mixer {self.mixer!r}")
+        norm = lambda: nn.RMSNorm(  # noqa: E731
+            epsilon=cfg.norm_eps, dtype=cfg.dtype)
         new_kv = None
-        if cache is not None:
-            attn_out, new_kv = attention(y, positions, contiguous_positions,
-                                         cache)
-            x = x + attn_out
-        else:
-            x = x + attention(y, positions, contiguous_positions)
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
+        if self.mixer is not None:
+            y = norm()(x)
+            if self.mixer == "mha":
+                attention = Attention(cfg, name="attn")
+            elif self.mixer == "mla":
+                from horovod_tpu.models.mla import LatentAttention
+                attention = LatentAttention(cfg, name="attn")
+            elif self.mixer == "ssm":
+                from horovod_tpu.models.ssm import StateSpaceMixer
+                attention = StateSpaceMixer(cfg, name="mixer")
+            else:
+                raise ValueError(f"unknown mixer {self.mixer!r}")
+            out = attention(y, positions, contiguous_positions, cache)
+            if cache is not None:
+                out, new_kv = out
+            x = x + out
+        elif cache is not None:
+            raise NotImplementedError(
+                "a layer without a mixer has no cache entry: the serving "
+                "path stacks one key/value pair a layer")
+        if self.feed_forward is None:
+            return x if cache is None else (x, new_kv)
+        y = norm()(x)
         b, s, d = y.shape
         if self.feed_forward == "moe":
             from horovod_tpu.models.moe import MoE
@@ -245,7 +286,7 @@ class Block(nn.Module):
             from horovod_tpu.models.experts import SwiGLU
             y = SwiGLU(cfg.d_ff, dtype=cfg.dtype, name="mlp")(y)
         elif self.feed_forward == "experts":
-            from horovod_tpu.models.experts import ExpertShare, SwiGLU
+            from horovod_tpu.models.experts import ExpertShare, shared_expert
             e = cfg.experts
             # the share's buffers hold every token-slot, k times the
             # tokens, most of them an absent expert's: recomputed in the
@@ -253,8 +294,8 @@ class Block(nn.Module):
             routed = nn.remat(ExpertShare)(e, dtype=cfg.dtype,
                                            name="experts")(
                 y.reshape(b * s, d)).reshape(b, s, d)
-            y = routed + SwiGLU(e.n_shared_experts * e.moe_d_ff,
-                                dtype=cfg.dtype, name="shared_experts")(y)
+            y = routed + shared_expert(e, dtype=cfg.dtype,
+                                       name="shared_experts")(y)
         else:
             raise ValueError(f"unknown feed-forward {self.feed_forward!r}")
         if cache is not None:
@@ -310,7 +351,7 @@ class Transformer(nn.Module):
                     (ctx_k[i], ctx_v[i], ctx_positions))
                 new_ks.append(nk)
                 new_vs.append(nv)
-            x = nn.RMSNorm(dtype=cfg.dtype)(x)
+            x = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)(x)
             logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype,
                               use_bias=False, name="lm_head")(x)
             return (logits.astype(jnp.float32),
@@ -325,7 +366,7 @@ class Transformer(nn.Module):
         for i, (mixer, feed_forward) in enumerate(cfg.layers()):
             x = Block(cfg, mixer, feed_forward,
                       name=f"block_{i}")(x, positions, contiguous)
-        x = nn.RMSNorm(dtype=cfg.dtype)(x)
+        x = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=cfg.dtype)(x)
         logits = nn.Dense(cfg.vocab_size, dtype=cfg.dtype, use_bias=False,
                           name="lm_head")(x)
         return logits.astype(jnp.float32)

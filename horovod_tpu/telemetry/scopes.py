@@ -29,7 +29,15 @@ MLA = "hvd_mla"
 # router product, scores, top-k, sort, gather into expert order, the
 # weighted way back
 MOE_ROUTE = "hvd_moe_route"
-MOE_EXPERTS = "hvd_moe_experts"  # the grouped products and their SwiGLU
+# the grouped products and the experts' element-wise body between them
+MOE_EXPERTS = "hvd_moe_experts"
+# the state-space mixer outside its scan: in_proj, the convolution and its
+# silu, softplus, the gate and group norm, out_proj
+SSM = "hvd_ssm"
+# from (u, B, C, step) to o: cumulative decay, the products inside a
+# chunk, the chunk states, the scan over chunks, the inherited state's
+# part, D * u; forward, recomputation and backward
+SSM_SCAN = "hvd_ssm_scan"
 # host spans
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding

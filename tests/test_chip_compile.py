@@ -20,6 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.experimental import topologies  # noqa: E402
 from jax.experimental.compilation_cache import (  # noqa: E402
@@ -178,6 +179,84 @@ def test_grouped_product_compiles_for_v5e(v5e, k, n):
         shaped(98304, k), shaped(16, k, n), shaped(16, dtype=jnp.int32),
         shaped(98304, n)).compile().as_text()
     assert text.count("tpu_custom_call") == 3
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
+                         ids=["up", "down"])
+def test_grouped_product_compiles_for_v5e_off_the_lanes(v5e, k, n):
+    """The relu^2 experts' two grouped products at the sizes of the
+    ``nemotron-3-nano-30b-a3b-train-s4096`` cell (49,152 token-slots, 8
+    experts held, an expert 1856 wide: 14.5 x 128 lanes, which no tile of
+    ``experts._tile`` divides, so the tile is the whole width): three
+    Mosaic kernels, and no fall back to ``ragged_dot``."""
+    from horovod_tpu.models import experts
+
+    assert experts._width(1856) == 1856 and experts._width(2688) == 384
+    assert experts._width(4096) == 1024 and experts._width(4160) is None
+
+    def forward_backward(xs, w, sizes, g):
+        out, vjp = jax.vjp(lambda xs, w: experts._gmm(xs, w, sizes), xs, w)
+        return (out,) + vjp(g)
+
+    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    text = jax.jit(forward_backward).lower(
+        shaped(49152, k), shaped(8, k, n), shaped(8, dtype=jnp.int32),
+        shaped(49152, n)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+
+
+def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
+    """The whole train step of ``nemotron-3-nano-30b-a3b-train-s4096`` as
+    its benchmark family builds it (9 layers at the published widths, 2 x
+    4096 tokens, AdamW), compiled for one described v5e chip: it fits the
+    chip's 15.75 GiB with only the scan and the expert share recomputed,
+    the flash kernel and megablox are in it as kernels, and nothing fell
+    back. (Nothing recomputed compiled to 16.32 GiB, the whole mixer
+    recomputed to 11.79: PERF.md section 4.)"""
+    import json
+    import warnings
+
+    import horovod_tpu as hvd
+    from benchmark.families import ssm_moe_lm as family
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    load = lambda *parts: json.load(open(os.path.join(  # noqa: E731
+        repo, "benchmark", *parts)))
+    config = load("configs", "nemotron-3-nano-30b-a3b.json")
+    traffic = load("traffic", "b2-s4096.json")
+    (device,) = v5e.device_set
+    # the model's platform sniffing (auto flash, megablox) sees the chip
+    monkeypatch.setattr(jax, "devices", lambda *a: [device])
+    hvd.shutdown()
+    hvd.init(devices=[device])
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", fa.FlashFallbackWarning)
+            built = family.build(config, traffic, hvd.mesh(), 7)
+            replicated = NamedSharding(hvd.mesh(), P())
+            state = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=replicated),
+                jax.eval_shape(built.init_state))
+            tokens = jax.ShapeDtypeStruct(
+                (traffic["per_chip_batch"], traffic["seq_len"]), jnp.int32,
+                sharding=NamedSharding(hvd.mesh(), P("data")))
+            compiled = built.step.jitted.lower(state, tokens).compile()
+    finally:
+        hvd.shutdown()
+    m = compiled.memory_analysis()
+    footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.0 < footprint / 2 ** 30 < 15.75
+    parameters = sum(int(np.prod(a.shape)) for a in
+                     jax.tree_util.tree_leaves(state.params))
+    assert parameters == config["parameters"] == 666_963_456
+    # one attention layer's forward and backward kernel, and megablox's
+    # three a product, two products a layer, forward, recomputed, backward
+    assert compiled.as_text().count("tpu_custom_call") == 2 + 4 * 8
 
 
 def test_chip_smoke_refuses_to_run_without_a_chip():

@@ -92,6 +92,21 @@ def test_default_pattern_builds_the_old_block():
                                       "moe"}
     with pytest.raises(ValueError, match="layer_pattern names 1 layers"):
         dataclasses.replace(cfg, layer_pattern=(("mha", "gelu"),)).layers()
+    # what PR 31 added stays out of it: the key/value heads are the query
+    # heads, rotary is on, the norms keep flax's eps, and the kernels are
+    # the shapes they were
+    assert (cfg.num_kv_heads, cfg.head_dim, cfg.rotary, cfg.norm_eps,
+            cfg.ssm) == (None, None, True, 1e-6, None)
+    assert {name: leaf["kernel"].shape
+            for name, leaf in params["block_0"]["attn"].items()} == {
+        "query": (16, 2, 8), "key": (16, 2, 8), "value": (16, 2, 8),
+        "out": (2, 8, 16)}
+    # a half that is None takes its norm with it and leaves the other
+    # half's names alone
+    one = _init(dataclasses.replace(
+        cfg, moe_every=0, layer_pattern=(("mha", None), (None, "gelu"))))
+    assert set(one["block_0"]) == {"RMSNorm_0", "attn"}
+    assert set(one["block_1"]) == {"RMSNorm_0", "Dense_0", "Dense_1"}
 
 
 @pytest.mark.parametrize("dtype,rtol", [(jnp.float32, 2e-4),
