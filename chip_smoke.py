@@ -77,15 +77,16 @@ KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64),
 # tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
 KERNEL_REL_L2 = 2e-2
 KERNEL_ATOL = dict(out=5e-2, dq=8e-2, dk=8e-2, dv=8e-2)
-# the expert share's grouped products at its benchmark cells' sizes: all
-# T * k token-slots as rows, the held experts' share of them live, in
-# ragged groups. SwiGLU experts (16,384 * 6 slots, an eighth live, 16
-# groups): gate and up as one product [d, 2f], then down [f, d]. relu^2
-# experts (8,192 * 6 slots, a sixteenth live, 8 groups): up [d, f], down
-# [f, d], at a width off the 128 lanes (1856 = 14.5 * 128)
-GROUPED = (dict(rows=98304, live=12288, groups=16,
+# the expert share's grouped products at its benchmark cells' sizes: the
+# rows of its buffers in expert order (twice the slots the held experts
+# expect), the expected slots live, in ragged groups. SwiGLU experts
+# (16,384 * 6 slots, an eighth held, 16 groups): gate and up as one
+# product [d, 2f], then down [f, d]. relu^2 experts (8,192 * 6 slots, a
+# sixteenth held, 8 groups): up [d, f], down [f, d], at a width off the
+# 128 lanes (1856 = 14.5 * 128)
+GROUPED = (dict(rows=24576, live=12288, groups=16,
                 products=((2048, 1536), (768, 2048))),
-           dict(rows=49152, live=3072, groups=8,
+           dict(rows=6144, live=3072, groups=8,
                 products=((2688, 1856), (1856, 2688))))
 # the state-space mixer's chunked scan at its benchmark cell's sizes:
 # [B, S] positions, H heads of P channels, G groups of N states, chunks of

@@ -28,9 +28,32 @@ experts have the same body; the routing and the dispatch do not know it.
 Routing is sort-and-gather: the ``T * k`` token-slots are sorted by
 expert (held experts first, every slot of an absent expert after them),
 the tokens gathered into that order, and the experts applied as grouped
-products over the ragged groups. The buffers hold all ``T * k`` slots,
-the most that can ever be held, so no slot is dropped whatever the
-imbalance, and shapes stay static with no capacity.
+products over the ragged groups.
+
+The buffers in expert order (the gathered tokens, the experts' hidden
+rows and output, and their gradients) hold ``rows`` rows, a bound on the
+slots the held experts can receive that follows from shapes alone:
+``HELD_ROOM`` times the ``T * k * experts_held / n_routed_experts`` slots
+that balance would send them, rounded up to a megablox row tile, and
+never more than ``T * k`` (``held_rows``). A step whose held slots pass
+the bound takes the way out (``jax.lax.cond`` on ``total > rows``, under
+the device scope ``hvd_moe_overflow``): the same body over one window of
+``rows`` rows of the expert order after another, as many as the held
+slots fill, their parts of the result summed. No slot is
+dropped whatever the imbalance, shapes stay static with no capacity, and
+no buffer in expert order has a row a slot. A share that holds every
+expert has ``rows == T * k`` and one program with no conditional in it.
+The two gathers back into token order (the weighted way back, and the
+gradient's way back to the tokens) write ``T * k`` rows by nature and
+read from the ``rows``-row buffers; a slot whose row lies outside the
+buffer reads nothing (its index is clamped and its row masked).
+
+The share is recomputed in the backward pass and keeps nothing but its
+inputs: ``_routed`` is a ``jax.custom_vjp`` whose forward chooses the
+size once and whose backward chooses it again, on the same predicate,
+between each size's own recomputation and VJP. (Differentiating through
+the ``cond`` would make the taken branch hand back the other's residuals,
+zero-filled.)
 
 The grouped product on the TPU is the Pallas megablox kernel
 (``jax.experimental.pallas.ops.tpu.megablox``), which walks only the
@@ -41,6 +64,8 @@ the pass, would be lost on the device trace. PERF.md, PR 27.)
 """
 
 import dataclasses
+import functools
+import math
 import warnings
 from typing import Optional
 
@@ -50,7 +75,23 @@ import jax.numpy as jnp
 
 from horovod_tpu.telemetry import scopes
 
-GMM_ROWS = (512, 256, 128)  # of a megablox tile: the first that divides T * k
+GMM_ROWS = (512, 256, 128)  # of a megablox tile: the first that divides the rows
+# The expert-order buffers hold this many times the slots that balance
+# would send the held experts. Measured on v5e (PR 32; both sparse cells,
+# fourteen seeds each, every expert layer, at initialisation and every
+# eighth of the first 48 steps on the cell's fixed batch, random weights):
+# 0.38-1.68 times the expectation with 16 of 128 experts held (the first
+# expert layer climbs to 1.25-1.68 within eight steps and stays), 0.05-
+# 1.65 with 8 of 128 (1.65 at initialisation, falling as the batch is
+# learnt); 10 of 784 readings past 1.5, none past 1.7; a router trained
+# with its balancing bias sits near 1. At 2 a step of kanana-2-30b-a3b-
+# train-s4096 runs 9.6% faster than with buffers of every slot; at 4,
+# 0.7%: 49,152 rows of 2048 (201 MB) no longer fit the chip's 128 MiB of
+# fast memory, which the gathers' sources of 24,576 rows (101 MB) do. Past
+# the bound a step costs what buffers of every slot cost (two windows), so
+# the bound is set where the common step is fastest, not where no step
+# ever passes it.
+HELD_ROOM = 2
 
 
 class GroupedFallbackWarning(UserWarning):
@@ -122,18 +163,23 @@ def shared_expert(cfg, dtype, name):
 
 
 def _live(x, index, total):
-    """``x[index]`` with the rows read from past ``total`` set to zero:
-    the grouped products never visit those rows, so what they hold is
-    undefined. (The select fuses into the gather: no pass of its own.)"""
-    return jnp.where((index < total)[:, None], x[index], 0)
+    """``x[index]`` where ``0 <= index < total``, zero elsewhere. The
+    grouped products never visit the rows from ``total`` on, so what they
+    hold is undefined; and ``x`` may be a buffer of fewer rows than there
+    are slots, one window of the expert order, so that an index lies
+    before it or past its last row, where there is nothing to read: such
+    an index reads an end row, and the mask drops it. (The select fuses
+    into the gather: no pass of its own.)"""
+    return jnp.where(((index >= 0) & (index < total))[:, None],
+                     x[jnp.clip(index, 0, x.shape[0] - 1)], 0)
 
 
 @jax.custom_vjp
 def _dispatch(y, order, inverse, total):
-    """Token-slot ``order[r]`` into row ``r``. Slots are numbered
-    choice-major, slot ``j * T + i`` is choice ``j`` of token ``i``, so
-    ``[k * T, d]`` splits into ``[k, T, d]`` along its major axis and no
-    tile is re-laid. The backward is written as the gather it is (each
+    """Token-slot ``order[r]`` into row ``r``, for as many rows as
+    ``order`` has. Slots are numbered choice-major, slot ``j * T + i`` is
+    choice ``j`` of token ``i``, so ``[k * T, d]`` splits into ``[k, T,
+    d]`` along its major axis and no tile is re-laid. The backward is written as the gather it is (each
     token sums its k slots), not as the scatter-add a gather's transpose
     would be."""
     del inverse, total
@@ -162,8 +208,8 @@ def _weighted(rows, w):
 @jax.custom_vjp
 def _combine(out, w, order, inverse, total):
     """The way back: each token's k rows of ``out`` (in expert order;
-    a slot of an absent expert reads a row past ``total``: zero),
-    weighted by ``w`` [k, T] and summed. Backward: a row's gradient is
+    a slot of an absent expert, or of another window, reads a row
+    outside ``0 .. total``: zero), weighted by ``w`` [k, T] and summed. Backward: a row's gradient is
     its token's, times its weight, gathered from ``[T, d]``."""
     t = w.shape[1]
     rows = _live(out, inverse, total).reshape(-1, t, out.shape[1])
@@ -267,68 +313,191 @@ def grouped_matmul(xs, w, group_sizes):
     return jax.lax.ragged_dot(xs, w, group_sizes)
 
 
+def held_rows(slots, cfg):
+    """The rows of the buffers in expert order, for ``slots`` token-slots
+    a step: ``HELD_ROOM`` times what balance sends the held experts, up to
+    a megablox row tile, and at most every slot."""
+    expected = slots * cfg.experts_held / cfg.n_routed_experts
+    tile = GMM_ROWS[0]
+    return min(slots, math.ceil(HELD_ROOM * expected / tile) * tile)
+
+
+def _prepared(c, dtype, y, params):
+    """What the share starts from at either size: ``((w [k, T], the
+    experts' matrices as the products take them), (idx [T, k], (order,
+    inverse, group_sizes, total)))``, the second pair without a
+    gradient."""
+    held, k = c.experts_held, c.num_experts_per_tok
+    with scopes.device(scopes.MOE_ROUTE):
+        # the router in float32: a score's eighth bit decides a choice
+        scores = jax.nn.sigmoid(jnp.dot(
+            y.astype(jnp.float32), params["router"],
+            precision=jax.lax.Precision.HIGHEST))
+        idx, w = route(scores, params["e_score_correction_bias"], k,
+                       c.routed_scaling_factor)
+        # choice-major slots: slot j * T + i is choice j of token i
+        local = idx.T.reshape(-1) - c.expert_offset
+        is_held = (local >= 0) & (local < held)
+        local = jnp.where(is_held, local, held)  # absent: sorted last
+        order = jnp.argsort(local, stable=True)
+        inverse = jnp.argsort(order)
+        group_sizes = jnp.sum(local[:, None] == jnp.arange(held), axis=0,
+                              dtype=jnp.int32)
+        total = jnp.sum(group_sizes)
+    with scopes.device(scopes.MOE_EXPERTS):
+        cast = lambda name: params[name].astype(dtype)  # noqa: E731
+        # gate and up as one product: the rows are read once, and one
+        # gradient comes back to them
+        first = (jnp.concatenate([cast("gate_proj"), cast("up_proj")], 2)
+                 if c.expert_body == "swiglu" else cast("up_proj"))
+        weights = first, cast("down_proj")
+    return (w.T, weights), (idx, (order, inverse, group_sizes, total))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _held(c, rows, routing, window, y, w, weights):
+    """The part of the result, ``[T, d]``, that the slots in rows
+    ``window * rows`` .. ``(window + 1) * rows`` of the expert order give,
+    through buffers of ``rows`` rows: with ``window`` 0 the held experts'
+    whole part wherever ``total <= rows``. A function of its own under
+    ``jax.jit``, so that the layers of a model, the two branches of
+    ``_sized`` and both passes trace and lower ONE body a shape (the
+    compiled program is the same: the compiler inlines it)."""
+    order, inverse, group_sizes, total = routing
+    first, w_down = weights
+    start = window * rows
+    # the window's own routing: its slots, where each slot's row lies in
+    # it (before it or past it: dead), and its share of every group
+    order = jax.lax.dynamic_slice_in_dim(
+        jnp.pad(order, (0, -order.shape[0] % rows)), start, rows)
+    ends = jnp.cumsum(group_sizes)
+    inside = lambda edge: jnp.clip(edge - start, 0, rows)  # noqa: E731
+    group_sizes = inside(ends) - inside(ends - group_sizes)
+    inverse, total = inverse - start, inside(total)
+    with scopes.device(scopes.MOE_ROUTE):
+        xs = _dispatch(y.astype(w_down.dtype), order, inverse, total)
+    with scopes.device(scopes.MOE_EXPERTS):
+        hidden = grouped_matmul(xs, first, group_sizes)
+        if c.expert_body == "swiglu":
+            gate, up = jnp.split(hidden, 2, axis=1)
+            hidden = nn.silu(gate) * up
+        else:
+            hidden = _relu2(hidden)
+        out = grouped_matmul(hidden, w_down, group_sizes)
+    with scopes.device(scopes.MOE_ROUTE):
+        return _combine(out, w, order, inverse, total)
+
+
+def _sized(c, routing, body, like, *operands):
+    """``body(rows, 0, *operands)``, the whole expert order's live part in
+    one window of ``rows = held_rows(T * k, c)`` rows, where the held
+    slots fit it; else the way out that is always right, the sum of
+    ``body(rows, window, *operands)`` over as many windows as the held
+    slots fill (shaped and typed ``like`` the result: in its own
+    precision); where one window holds every slot, that program and no
+    conditional."""
+    order, *_, total = routing
+    slots = order.shape[0]
+    rows = held_rows(slots, c)
+    bounded = functools.partial(body, rows, jnp.int32(0))
+    if rows == slots:
+        return bounded(*operands)
+
+    def way_out(*operands):
+        with scopes.device(scopes.MOE_OVERFLOW):
+            add = lambda window, acc: jax.tree_util.tree_map(  # noqa: E731
+                jnp.add, acc, body(rows, window, *operands))
+            return jax.lax.fori_loop(
+                jnp.int32(0), -(-total // rows), add, jax.tree_util.tree_map(
+                    lambda a: jnp.zeros(a.shape, a.dtype), like))
+
+    # the bounded size as the false branch, the conditional's first: what
+    # the compiler finds alike in both branches it may move out of them,
+    # under the first one's names (it did when the way out was one
+    # straight-line body: the sums over a token's k slots), and work that
+    # every step does is no overflow
+    return jax.lax.cond(total > rows, way_out, bounded, *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _routed(c, dtype, y, params):
+    """``(the held experts' part of the result [T, d], idx [T, k])`` of
+    ``y [T, d]`` under the share's parameters. Recomputed in the backward
+    pass from ``y`` and the parameters, which are all it keeps."""
+    (w, weights), (idx, routing) = _prepared(c, dtype, y, params)
+
+    def body(rows, window, y, w, weights):
+        return _held(c, rows, routing, window, y, w, weights)
+
+    like = jax.ShapeDtypeStruct(y.shape, dtype)
+    return _sized(c, routing, body, like, y, w, weights), idx
+
+
+def _routed_fwd(c, dtype, y, params):
+    return _routed(c, dtype, y, params), (y, params)
+
+
+def _routed_bwd(c, dtype, res, cotangents):
+    g, _ = cotangents  # the choices carry none
+    # as jax.checkpoint: what is computed again below is not merged with
+    # the forward pass's, whose buffers would then live until here
+    y, params, g = jax.lax.optimization_barrier((*res, g))
+    (w, weights), prepared_vjp, (_, routing) = jax.vjp(
+        functools.partial(_prepared, c, dtype), y, params, has_aux=True)
+
+    def body_vjp(rows, window, y, w, weights, g):
+        return jax.vjp(functools.partial(_held, c, rows, routing, window),
+                       y, w, weights)[1](g)
+
+    d_y, d_w, d_weights = _sized(c, routing, body_vjp, (y, w, weights),
+                                 y, w, weights, g)
+    # the optimizer's converts stay outside the conditional: moved into
+    # both branches, they make every weight gradient leave it twice, in
+    # bfloat16 and in float32
+    d_weights = jax.lax.optimization_barrier(d_weights)
+    d_y_scores, d_params = prepared_vjp((d_w, d_weights))
+    with scopes.device(scopes.MOE_ROUTE):
+        return d_y + d_y_scores, d_params
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 class ExpertShare(nn.Module):
     """``y [T, d] -> [T, d]``: the routed part of the layer's result that
-    the held experts give. ``cfg`` is an ``ExpertShareConfig``."""
+    the held experts give. ``cfg`` is an ``ExpertShareConfig``. Its
+    buffers in expert order hold ``held_rows(T * k, cfg)`` rows; a step
+    that sends the held experts more slots takes the way out, window by
+    window through smaller buffers, and drops nothing (the module
+    docstring). Recomputed in the backward pass, so a caller wraps it in
+    no ``remat``."""
     cfg: ExpertShareConfig
     dtype: object = jnp.bfloat16
 
     @nn.compact
     def __call__(self, y):
         c = self.cfg
-        t, d = y.shape
-        n, held, k, f = (c.n_routed_experts, c.experts_held,
-                         c.num_experts_per_tok, c.moe_d_ff)
+        d = y.shape[1]
+        n, held, f = c.n_routed_experts, c.experts_held, c.moe_d_ff
         if not 0 <= c.expert_offset <= n - held:
             raise ValueError(f"experts {c.expert_offset}.."
                              f"{c.expert_offset + held} of {n}")
         if c.expert_body not in BODIES:
             raise ValueError(f"unknown expert body {c.expert_body!r}")
-        gated = c.expert_body == "swiglu"
         expert = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                               batch_axis=(0,))
-        if gated:
-            w_gate = self.param("gate_proj", expert, (held, d, f))
-        w_up = self.param("up_proj", expert, (held, d, f))
-        w_down = self.param("down_proj", expert, (held, f, d))
-        router = self.param("router", nn.initializers.lecun_normal(), (d, n))
-        bias = self.param("e_score_correction_bias",
-                          nn.initializers.normal(c.selection_bias_std)
-                          if c.selection_bias_std else nn.initializers.zeros,
-                          (n,))
-
-        with scopes.device(scopes.MOE_ROUTE):
-            # the router in float32: a score's eighth bit decides a choice
-            scores = jax.nn.sigmoid(jnp.dot(
-                y.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST))
-            idx, w = route(scores, bias, k, c.routed_scaling_factor)
-            # for a caller that asks (mutable=["intermediates"]); else no-op
-            self.sow("intermediates", "chosen", idx)
-            # choice-major slots: slot j * T + i is choice j of token i
-            local = idx.T.reshape(-1) - c.expert_offset
-            is_held = (local >= 0) & (local < held)
-            local = jnp.where(is_held, local, held)  # absent: sorted last
-            order = jnp.argsort(local, stable=True)
-            inverse = jnp.argsort(order)
-            group_sizes = jnp.sum(local[:, None] == jnp.arange(held), axis=0,
-                                  dtype=jnp.int32)
-            total = jnp.sum(group_sizes)
-            xs = _dispatch(y.astype(self.dtype), order, inverse, total)
-
-        with scopes.device(scopes.MOE_EXPERTS):
-            cast = lambda a: a.astype(self.dtype)  # noqa: E731
-            if gated:
-                # gate and up as one product: xs is read once, and one
-                # gradient comes back to it
-                gate_up = grouped_matmul(
-                    xs, jnp.concatenate([cast(w_gate), cast(w_up)], 2),
-                    group_sizes)
-                gate, up = jnp.split(gate_up, 2, axis=1)
-                hidden = nn.silu(gate) * up
-            else:
-                hidden = _relu2(grouped_matmul(xs, cast(w_up), group_sizes))
-            out = grouped_matmul(hidden, cast(w_down), group_sizes)
-
-        with scopes.device(scopes.MOE_ROUTE):
-            return _combine(out, w.T, order, inverse, total)
+        shapes = {"up_proj": (held, d, f), "down_proj": (held, f, d)}
+        if c.expert_body == "swiglu":
+            shapes = {"gate_proj": (held, d, f), **shapes}
+        params = {name: self.param(name, expert, shape)
+                  for name, shape in shapes.items()}
+        params["router"] = self.param(
+            "router", nn.initializers.lecun_normal(), (d, n))
+        params["e_score_correction_bias"] = self.param(
+            "e_score_correction_bias",
+            nn.initializers.normal(c.selection_bias_std)
+            if c.selection_bias_std else nn.initializers.zeros, (n,))
+        out, idx = _routed(c, self.dtype, y, params)
+        # for a caller that asks (mutable=["intermediates"]); else no-op
+        self.sow("intermediates", "chosen", idx)
+        return out

@@ -288,11 +288,9 @@ class Block(nn.Module):
         elif self.feed_forward == "experts":
             from horovod_tpu.models.experts import ExpertShare, shared_expert
             e = cfg.experts
-            # the share's buffers hold every token-slot, k times the
-            # tokens, most of them an absent expert's: recomputed in the
-            # backward pass, never kept
-            routed = nn.remat(ExpertShare)(e, dtype=cfg.dtype,
-                                           name="experts")(
+            # the share recomputes itself in the backward pass and keeps
+            # none of its buffers of token-slots
+            routed = ExpertShare(e, dtype=cfg.dtype, name="experts")(
                 y.reshape(b * s, d)).reshape(b, s, d)
             y = routed + shared_expert(e, dtype=cfg.dtype,
                                        name="shared_experts")(y)

@@ -31,6 +31,10 @@ MLA = "hvd_mla"
 MOE_ROUTE = "hvd_moe_route"
 # the grouped products and the experts' element-wise body between them
 MOE_EXPERTS = "hvd_moe_experts"
+# around both of the above in the expert share's full-size branch, the one
+# a step takes when its held slots pass the expert-order buffers' bound:
+# device time here is how often, and for how long, the bound was passed
+MOE_OVERFLOW = "hvd_moe_overflow"
 # the state-space mixer outside its scan: in_proj, the convolution and its
 # silu, softplus, the gate and group norm, out_proj
 SSM = "hvd_ssm"

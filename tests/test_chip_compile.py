@@ -163,11 +163,16 @@ def test_flash_kernel_skipped_steps_fetch_nothing(v5e, shape):
                          ids=["gate_up", "down"])
 def test_grouped_product_compiles_for_v5e(v5e, k, n):
     """The expert share's grouped products under ``experts._gmm``'s own
-    VJP, at the sizes of the ``kanana-2-30b-a3b-train-s4096`` cell
-    (98,304 token-slots, 16 experts held): three Mosaic kernels, the
-    product, the input gradient (``transpose_rhs``) and the weight
-    gradient (``tgmm``), each with the tiling ``experts._tile`` picks."""
+    VJP, at the sizes of the ``kanana-2-30b-a3b-train-s4096`` cell (16
+    experts held; 98,304 token-slots, of which the buffers in expert order
+    hold 24,576): three Mosaic kernels, the product, the input gradient
+    (``transpose_rhs``) and the weight gradient (``tgmm``), each with the
+    tiling ``experts._tile`` picks."""
     from horovod_tpu.models import experts
+
+    cell = experts.ExpertShareConfig(n_routed_experts=128, experts_held=16)
+    rows = experts.held_rows(98304, cell)
+    assert rows == 24576
 
     def forward_backward(xs, w, sizes, g):
         out, vjp = jax.vjp(lambda xs, w: experts._gmm(xs, w, sizes), xs, w)
@@ -176,8 +181,8 @@ def test_grouped_product_compiles_for_v5e(v5e, k, n):
     shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=v5e)
     text = jax.jit(forward_backward).lower(
-        shaped(98304, k), shaped(16, k, n), shaped(16, dtype=jnp.int32),
-        shaped(98304, n)).compile().as_text()
+        shaped(rows, k), shaped(16, k, n), shaped(16, dtype=jnp.int32),
+        shaped(rows, n)).compile().as_text()
     assert text.count("tpu_custom_call") == 3
 
 
@@ -185,11 +190,16 @@ def test_grouped_product_compiles_for_v5e(v5e, k, n):
                          ids=["up", "down"])
 def test_grouped_product_compiles_for_v5e_off_the_lanes(v5e, k, n):
     """The relu^2 experts' two grouped products at the sizes of the
-    ``nemotron-3-nano-30b-a3b-train-s4096`` cell (49,152 token-slots, 8
-    experts held, an expert 1856 wide: 14.5 x 128 lanes, which no tile of
+    ``nemotron-3-nano-30b-a3b-train-s4096`` cell (8 experts held; 49,152
+    token-slots, of which the buffers in expert order hold 6,144; an
+    expert 1856 wide: 14.5 x 128 lanes, which no tile of
     ``experts._tile`` divides, so the tile is the whole width): three
     Mosaic kernels, and no fall back to ``ragged_dot``."""
     from horovod_tpu.models import experts
+
+    cell = experts.ExpertShareConfig(n_routed_experts=128, experts_held=8)
+    rows = experts.held_rows(49152, cell)
+    assert rows == 6144
 
     assert experts._width(1856) == 1856 and experts._width(2688) == 384
     assert experts._width(4096) == 1024 and experts._width(4160) is None
@@ -201,8 +211,8 @@ def test_grouped_product_compiles_for_v5e_off_the_lanes(v5e, k, n):
     shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=v5e)
     text = jax.jit(forward_backward).lower(
-        shaped(49152, k), shaped(8, k, n), shaped(8, dtype=jnp.int32),
-        shaped(49152, n)).compile().as_text()
+        shaped(rows, k), shaped(8, k, n), shaped(8, dtype=jnp.int32),
+        shaped(rows, n)).compile().as_text()
     assert text.count("tpu_custom_call") == 3
 
 
@@ -211,8 +221,9 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     its benchmark family builds it (9 layers at the published widths, 2 x
     4096 tokens, AdamW), compiled for one described v5e chip: it fits the
     chip's 15.75 GiB with only the scan and the expert share recomputed,
-    the flash kernel and megablox are in it as kernels, and nothing fell
-    back. (Nothing recomputed compiled to 16.32 GiB, the whole mixer
+    the flash kernel and megablox are in it as kernels, nothing fell
+    back, and the expert share's way out past its buffers' bound is a
+    branch of its own whose scope is on nothing else. (Nothing recomputed compiled to 16.32 GiB, the whole mixer
     recomputed to 11.79: PERF.md section 4.)"""
     import json
     import warnings
@@ -255,8 +266,19 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
                      jax.tree_util.tree_leaves(state.params))
     assert parameters == config["parameters"] == 666_963_456
     # one attention layer's forward and backward kernel, and megablox's
-    # three a product, two products a layer, forward, recomputed, backward
-    assert compiled.as_text().count("tpu_custom_call") == 2 + 4 * 8
+    # three a product, two products a layer, forward, recomputed, backward,
+    # at both sizes of the share's buffers in expert order
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 + 4 * 8 * 2
+    # one conditional a layer each way, and no instruction outside their
+    # second branches, the way out, reads as overflow
+    entry = text[text.index("\nENTRY "):]
+    assert entry.count(" conditional(") == 2 * 4
+    assert text.count("hvd_moe_overflow") > 0
+    assert "hvd_moe_overflow" not in entry
+    # no buffer of every slot is left in expert order, and the taken
+    # branch hands back no zeros for the other's residuals
+    assert footprint / 2 ** 30 < 14.1  # 14.091 until PR 32 (PERF.md)
 
 
 def test_chip_smoke_refuses_to_run_without_a_chip():

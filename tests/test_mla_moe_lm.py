@@ -8,6 +8,7 @@ yardstick may not move with the program), and one test holds the two to
 the same numbers.
 """
 
+import contextlib
 import dataclasses
 import os
 
@@ -18,6 +19,7 @@ import optax
 import pytest
 
 import reference_mla_moe_lm as reference
+import reference_ssm_moe_lm as reference_ssm
 from horovod_tpu import training
 from horovod_tpu.models import experts as experts_lib
 from horovod_tpu.models import mla as mla_lib
@@ -421,18 +423,24 @@ def test_a_grouped_product_that_falls_back_on_the_tpu_says_so(
                                    np.asarray(xs[lo:hi] @ w[g]), rtol=1e-5)
 
 
-def test_rows_past_the_groups_may_hold_anything(rng):
+@pytest.mark.parametrize("rows", [24, 12], ids=["every_slot", "bounded"])
+def test_rows_past_the_groups_may_hold_anything(rng, rows):
     """The grouped products never visit the rows past the groups' end, on
     the TPU what those rows hold is undefined: NaN there, in the experts'
     output and in the gradient that comes back to the dispatch, reaches
-    neither the result nor a gradient."""
+    neither the result nor a gradient. So with buffers of every slot, and
+    so with buffers of fewer rows than there are slots, where the two
+    gathers back into token order meet indices past the buffer: a dead
+    slot reads the buffer's last row (NaN here) and is masked, and no
+    live slot reads it."""
     t, k, d, total = 8, 3, 4, 10
     local = jnp.asarray(rng.permutation(np.repeat([0, 1, 2], t * k // 3)))
-    order = jnp.argsort(local, stable=True)
-    inverse = jnp.argsort(order)
+    whole_order = jnp.argsort(local, stable=True)
+    inverse = jnp.argsort(whole_order)
+    order = whole_order[:rows]
     w = jnp.asarray(rng.random((k, t)), jnp.float32)
     out = jnp.asarray(rng.standard_normal((t * k, d)), jnp.float32)
-    dirty = out.at[total:].set(jnp.nan)
+    dirty = out[:rows].at[total:].set(jnp.nan)
     clean = out.at[total:].set(0.0)
     want = np.einsum("jtd,jt->td",
                      np.asarray(clean)[np.asarray(inverse)].reshape(k, t, d),
@@ -442,13 +450,13 @@ def test_rows_past_the_groups_may_hold_anything(rng):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
     g = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
     d_out, d_w = vjp(g)
-    assert np.isfinite(np.asarray(d_out)).all()
+    assert d_out.shape == (rows, d) and np.isfinite(np.asarray(d_out)).all()
     want_vjp = jax.vjp(lambda o, w: jnp.einsum(
         "jtd,jt->td", o[inverse].reshape(k, t, d), w), clean, w)[1](g)
     np.testing.assert_allclose(np.asarray(d_w), np.asarray(want_vjp[1]),
                                rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(d_out), np.asarray(want_vjp[0]),
-                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(d_out),
+                               np.asarray(want_vjp[0])[:rows], rtol=1e-5)
     # the dispatch's backward: a token sums its live slots' rows only
     y = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
     xs, vjp = jax.vjp(lambda y: experts_lib._dispatch(
@@ -456,11 +464,204 @@ def test_rows_past_the_groups_may_hold_anything(rng):
     np.testing.assert_array_equal(np.asarray(xs),
                                   np.asarray(y)[np.asarray(order) % t])
     g = jnp.asarray(rng.standard_normal((t * k, d)), jnp.float32)
-    (d_y,) = vjp(g.at[total:].set(jnp.nan))
+    (d_y,) = vjp(g[:rows].at[total:].set(jnp.nan))
     live = np.where((np.asarray(inverse) < total)[:, None],
                     np.asarray(g)[np.asarray(inverse)], 0.0)
     np.testing.assert_allclose(np.asarray(d_y),
                                live.reshape(k, t, d).sum(0), rtol=1e-5)
+
+
+# Shapes at which the expert-order buffers are smaller than the slots: 640
+# tokens x 3 choices = 1920 slots; with 1 of 16 experts held 120 are
+# expected, with 2 held 240, and ``held_rows`` is 512 both times (a
+# megablox row tile above HELD_ROOM times as many).
+BOUNDED = dataclasses.replace(EXPERTS, experts_held=1, expert_offset=4)
+BOUNDED_T = 640
+# the experts a selection bias sends every token to (None: no bias),
+# how many of them the share holds from expert 4 on, and the held slots
+# that gives: about 120 of 512 rows; 640, past 512 rows (two windows);
+# 1280 (three windows, and the 1920 slots end in the middle of the fourth)
+CROWDS = {"under": (None, 1, None), "over": ((4, 9, 10), 1, 640),
+          "over_ragged": ((4, 5, 9), 2, 1280)}
+
+
+def _bounded_share(body, rng, crowd):
+    """``(share, parameters, y, rows)`` of a share whose buffers hold
+    fewer rows than the 1920 slots, under ``CROWDS[crowd]``."""
+    chosen, held, _ = CROWDS[crowd]
+    share = dataclasses.replace(BOUNDED, expert_body=body, experts_held=held)
+    y = jnp.asarray(rng.standard_normal((BOUNDED_T, 32)), jnp.float32)
+    params = dict(experts_lib.ExpertShare(share, dtype=jnp.float32).init(
+        jax.random.PRNGKey(1), y)["params"])
+    if chosen:
+        params["e_score_correction_bias"] = jnp.zeros(16).at[
+            jnp.asarray(chosen)].set(10.0)
+    rows = experts_lib.held_rows(BOUNDED_T * 3, share)
+    assert rows == 512 and (BOUNDED_T * 3) % rows == 384
+    return share, params, y, rows
+
+
+def _share_and_gradients(share, params, y, g):
+    """The share's output for ``y`` and the gradients of ``sum(out * g)``
+    by ``y`` and every parameter, with the held slots of the step."""
+    def run(y, params):
+        out, state = experts_lib.ExpertShare(share, dtype=jnp.float32).apply(
+            {"params": params}, y, mutable=["intermediates"])
+        idx = state["intermediates"]["chosen"][0]
+        held = jnp.sum((idx >= share.expert_offset)
+                       & (idx < share.expert_offset + share.experts_held))
+        return jnp.sum(out * g), (out, held)
+
+    (_, (out, held)), grads = jax.jit(jax.value_and_grad(
+        run, argnums=(0, 1), has_aux=True))(y, params)
+    return out, grads, int(held)
+
+
+@pytest.fixture
+def overflow_branch_runs(monkeypatch):
+    """A mark for every time the share's way out ran on the device: a
+    callback traced into the ``hvd_moe_overflow`` scope."""
+    runs = []
+    device = experts_lib.scopes.device
+
+    @contextlib.contextmanager
+    def counting(name):
+        with device(name):
+            if name == experts_lib.scopes.MOE_OVERFLOW:
+                jax.debug.callback(lambda: runs.append(1))
+            yield
+
+    monkeypatch.setattr(experts_lib.scopes, "device", counting)
+    return runs
+
+
+@pytest.mark.parametrize("crowd", sorted(CROWDS))
+@pytest.mark.parametrize("body", ["swiglu", "relu2"])
+def test_bounded_buffers_give_what_full_size_buffers_give(
+        rng, monkeypatch, overflow_branch_runs, body, crowd):
+    """Under the bound the share runs through buffers of ``held_rows``
+    rows once; past it the way out runs, forward and backward once each,
+    a window of the expert order at a time. Output and every gradient are
+    what a share whose buffers hold every slot gives, and the output the
+    reference's."""
+    share, params, y, rows = _bounded_share(body, rng, crowd)
+    g = jnp.asarray(rng.standard_normal(y.shape), jnp.float32)
+    out, grads, held = _share_and_gradients(share, params, y, g)
+    jax.effects_barrier()
+    crowded = CROWDS[crowd][2]
+    assert held == crowded if crowded else 0 < held <= rows
+    assert len(overflow_branch_runs) == (2 if held > rows else 0)
+    with monkeypatch.context() as m:
+        m.setattr(experts_lib, "HELD_ROOM", 16)  # every slot, held or not
+        assert experts_lib.held_rows(BOUNDED_T * 3, share) == BOUNDED_T * 3
+        want, want_grads, _ = _share_and_gradients(share, params, y, g)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    flat, flat_want = (jax.tree_util.tree_leaves_with_path(t)
+                       for t in (grads, want_grads))
+    assert len(flat) == (6 if body == "swiglu" else 5)
+    for (path, got_leaf), (_, want_leaf) in zip(flat, flat_want):
+        np.testing.assert_allclose(
+            np.asarray(got_leaf), np.asarray(want_leaf), rtol=1e-5,
+            atol=1e-6 * float(jnp.abs(want_leaf).max()) + 1e-12,
+            err_msg=jax.tree_util.keystr(path))
+    assert not np.any(grads[1]["e_score_correction_bias"])
+    assert np.any(grads[1]["router"]) and np.any(grads[0])
+    plain_reference = reference if body == "swiglu" else reference_ssm
+    with jax.default_matmul_precision("highest"):
+        plain, _ = plain_reference._routed(params, y, _arch(share))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(plain), atol=2e-5)
+
+
+def _share_gradient(share, t):
+    """``(value and gradient of the share's summed output by y and the
+    parameters, y, the parameters' shapes)`` for ``t`` tokens."""
+    y = jnp.zeros((t, 32), jnp.float32)
+    module = experts_lib.ExpertShare(share, dtype=jnp.float32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), y)["params"]
+    return jax.value_and_grad(
+        lambda y, p: jnp.sum(module.apply({"params": p}, y)),
+        argnums=(0, 1)), y, params
+
+
+def _share_gradient_jaxpr(share, t):
+    fn, y, params = _share_gradient(share, t)
+    return jax.make_jaxpr(fn)(y, params)
+
+
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations call."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def test_a_share_that_holds_every_expert_has_no_conditional():
+    """Where the buffers' bound is every slot there is one program, not a
+    choice of two: no ``cond`` in the jaxpr, no conditional in what it
+    lowers to; a share of an eighth has one each way."""
+    def lowered(share):
+        fn, y, params = _share_gradient(share, BOUNDED_T)
+        text = jax.jit(fn).lower(y, params).as_text()
+        return text.count("stablehlo.case") + text.count("stablehlo.if")
+
+    conds = lambda share: sum(  # noqa: E731
+        eqn.primitive.name == "cond" for eqn in _equations(
+            _share_gradient_jaxpr(share, BOUNDED_T).jaxpr))
+    assert conds(EXPERTS) == 0 and lowered(EXPERTS) == 0
+    # the conditional each way, and the loop over windows in its way out
+    assert conds(BOUNDED) == 2 and lowered(BOUNDED) >= 2
+
+
+@pytest.mark.parametrize("body", ["swiglu", "relu2"])
+def test_neither_direction_holds_an_array_of_every_slot(body):
+    """In both branches, forward and backward, nothing has a row a slot
+    but the index vectors and what the gathers back into token order give
+    (``_live``: a gather, and the select that puts a scalar zero in its
+    dead rows): no buffer in expert order, and no zeros handed back for
+    another branch's residuals, which would be a result of the branch.
+    The buffers in expert order are ``held_rows`` long in both, and the
+    way out is a loop over windows."""
+    share = dataclasses.replace(BOUNDED, expert_body=body)
+    slots, rows = BOUNDED_T * 3, 512
+    assert experts_lib.held_rows(slots, share) == rows
+    jaxpr = _share_gradient_jaxpr(share, BOUNDED_T).jaxpr
+    conds = [eqn for eqn in _equations(jaxpr)
+             if eqn.primitive.name == "cond"]
+    assert len(conds) == 2
+
+    def wide(branch):
+        """(primitive, shapes it reads) of every equation of ``branch``
+        that makes a float array with a row a slot."""
+        return [(eqn.primitive.name,
+                 [v.aval.shape for v in eqn.invars if hasattr(v, "aval")])
+                for eqn in _equations(branch.jaxpr) for v in eqn.outvars
+                if v.aval.ndim > 1 and v.aval.shape[0] == slots
+                and jnp.issubdtype(v.aval.dtype, jnp.inexact)]
+
+    for cond in conds:
+        bounded, way_out = cond.params["branches"]
+        for branch in (bounded, way_out):
+            assert all(v.aval.shape[0] != slots
+                       for v in branch.jaxpr.outvars)
+            made = wide(branch)
+            assert {name for name, _ in made} <= {
+                "gather", "select_n", "jit", "pjit", "broadcast_in_dim"}
+            # a gather back reads a buffer of ``rows`` rows; what is
+            # broadcast is the scalar zero of a mask
+            gathers = [reads for name, reads in made if name == "gather"]
+            assert gathers and all(reads[0][0] == rows for reads in gathers)
+            assert all(reads == [()] for name, reads in made
+                       if name == "broadcast_in_dim")
+            # the grouped products run on ``rows`` rows
+            products = [v.aval.shape for eqn in _equations(branch.jaxpr)
+                        if eqn.primitive.name.startswith("ragged_dot")
+                        for v in eqn.outvars if v.aval.ndim == 2]
+            assert products and all(shape[0] == rows for shape in products)
+        names = lambda branch: {  # noqa: E731
+            eqn.primitive.name for eqn in _equations(branch.jaxpr)}
+        assert "while" in names(way_out) and "while" not in names(bounded)
 
 
 @pytest.fixture(scope="module")

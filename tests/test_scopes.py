@@ -350,3 +350,84 @@ def test_jitted_step_is_named_for_what_it_is(texts, builder, module):
     # would hand that executable, and its op_names, to the scoped program
     # if both were jit_outer: every phase metric would read nothing.
     assert texts[builder].startswith(f"HloModule {module},")
+
+
+def _computations(text):
+    """``{computation: [its instruction lines]}`` of a compiled module."""
+    comps, current = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head:
+            current = head.group(1)
+            comps[current] = []
+        elif current and _INSTR_RE.match(line):
+            comps[current].append(line)
+    return comps
+
+
+def _op_names_reached(comps, name, seen=None):
+    """The ``op_name``s of ``name``'s instructions and of every
+    computation they call (fusions, nested control flow; not a reduction's
+    scalar ``to_apply``, which runs as part of its caller and is shared
+    between callers)."""
+    seen = set() if seen is None else seen
+    if name in seen or name not in comps:
+        return []
+    seen.add(name)
+    out = []
+    for line in comps[name]:
+        out += _OP_NAME_RE.findall(line)
+        for called in re.findall(
+                r"(?:calls|body|condition)=%?([\w.\-]+)", line):
+            out += _op_names_reached(comps, called, seen)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+            for called in group.split(","):
+                out += _op_names_reached(comps, called.strip().lstrip("%"),
+                                         seen)
+    return out
+
+
+def test_overflow_scope_is_on_the_way_out_alone():
+    """An expert share whose buffers hold fewer rows than there are slots
+    compiles to one conditional each way. ``hvd_moe_route`` and
+    ``hvd_moe_experts`` are on the instructions of both branches;
+    ``hvd_moe_overflow`` is around them in the second branch, the way out
+    past the buffers' bound, and on nothing else in the program: device
+    time under it is time spent past the bound."""
+    from horovod_tpu.models import experts as experts_lib
+
+    share = experts_lib.ExpertShareConfig(
+        n_routed_experts=16, experts_held=2, expert_offset=4,
+        num_experts_per_tok=3, moe_d_ff=16)
+    y = jnp.zeros((384, 32), jnp.float32)
+    assert experts_lib.held_rows(384 * 3, share) == 512
+    module = experts_lib.ExpertShare(share, dtype=jnp.float32)
+    params = jax.eval_shape(module.init, jax.random.PRNGKey(0), y)["params"]
+    text = jax.jit(jax.value_and_grad(
+        lambda y, p: jnp.sum(module.apply({"params": p}, y)),
+        argnums=(0, 1))).lower(y, params).compile().as_text()
+    comps = _computations(text)
+    conditionals = [line for lines in comps.values() for line in lines
+                    if _INSTR_RE.match(line).group(1) == "conditional"]
+    assert len(conditionals) == 2
+    has = lambda names, scope: [n for n in names if scope in n]  # noqa: E731
+    inside = set()
+    for line in conditionals:
+        branches = [b.strip().lstrip("%") for b in re.search(
+            r"branch_computations=\{([^}]*)\}", line).group(1).split(",")]
+        bounded, full = (_op_names_reached(comps, b, inside)
+                         for b in branches)
+        for names in (bounded, full):
+            assert has(names, scopes.MOE_ROUTE)
+            assert has(names, scopes.MOE_EXPERTS)
+        assert not has(bounded, scopes.MOE_OVERFLOW)
+        scoped = has(full, scopes.MOE_ROUTE) + has(full, scopes.MOE_EXPERTS)
+        assert scoped and all(scopes.MOE_OVERFLOW in n for n in scoped)
+        assert all(n.index(scopes.MOE_OVERFLOW) < n.index(scope)
+                   for n in scoped
+                   for scope in (scopes.MOE_ROUTE, scopes.MOE_EXPERTS)
+                   if scope in n)
+    outside = [n for name, lines in comps.items() if name not in inside
+               for line in lines for n in _OP_NAME_RE.findall(line)]
+    assert has(outside, scopes.MOE_ROUTE)  # the router, top-k, the sorts
+    assert not has(outside, scopes.MOE_OVERFLOW)
