@@ -23,6 +23,11 @@ points users call, at the full width of two models the repo supports:
 * ``state_space_scan`` the state-space mixer's chunked scan against the
                     recurrence one step at a time, result and gradients,
                     at its benchmark cell's sizes; then timed.
+* ``delta_scan``    delta attention's chunked scan (``models/kda.py``)
+                    against the delta rule one position at a time, result
+                    and gradients, at its benchmark cell's sizes; then the
+                    whole mixer forward and backward, timed, with both its
+                    device scopes in the compiled text.
 * ``lm``            the decoder LM, 8 layers d2048 16 heads, vocab 32000,
                     sequence 2048, batch 8, flash on (``make_lm_bench`` ->
                     ``make_lm_train_step``): loss finite and falling on a
@@ -94,6 +99,11 @@ GROUPED = (dict(rows=24576, live=12288, groups=16,
 SCAN = dict(batch=2, seq_len=4096, heads=64, head_dim=64, groups=8,
             states=128, chunk=128)
 SCAN_REL_L2 = 2e-2
+# delta attention at its benchmark cell's sizes: [B, S] positions, H heads
+# of D channels (q, k and v alike), chunks of 64, a model 2304 wide;
+# against the delta rule one position at a time in float32
+DELTA = dict(batch=2, seq_len=4096, heads=32, head_dim=128, chunk=64,
+             d_model=2304)
 LM = dict(layers=8, d_model=2048, heads=16, vocab=32000, seq_len=2048,
           batch=8, steps=4)
 RESNET = dict(model="resnet101", batch=256, image=224, steps=3)
@@ -457,6 +467,42 @@ def _grouped_case(a, rng):
     return results
 
 
+def _with_gradients(scan, count):
+    """Jitted ``(o, gradients of the first count arguments)`` of
+    ``sum(scan(*arguments but the last) * the last)``. The weight is an
+    argument: closed over, its 134 MB would be a constant of the
+    executable and evict the compile cache."""
+    import jax
+    import jax.numpy as jnp
+
+    def value(*x):
+        o = scan(*x[:-1])
+        return jnp.sum(o.astype(jnp.float32) * x[-1]), o
+
+    def run(*x):
+        (_, o), grads = jax.value_and_grad(
+            value, argnums=tuple(range(count)), has_aux=True)(*x)
+        return (o,) + grads
+    return jax.jit(run)
+
+
+def _scan_errors(what, names, got, want):
+    """``{name: rel_l2}`` of a chunked scan's tensors against the
+    recurrence's; raises past ``SCAN_REL_L2``."""
+    import numpy as np
+
+    errors = {}
+    for name, x, y in zip(names, got, want):
+        x, y = (np.asarray(z, np.float32) for z in (x, y))
+        errors[name] = round(float(np.linalg.norm(x - y)
+                                   / np.linalg.norm(y)), 5)
+        if not errors[name] <= SCAN_REL_L2:
+            raise RuntimeError(
+                f"{what}: {name} disagrees with the recurrence: rel_l2 "
+                f"{errors[name]} (allowed {SCAN_REL_L2})")
+    return errors
+
+
 def phase_state_space_scan():
     """``models/ssm.chunked_scan`` in bfloat16 at the sizes of its
     benchmark cell against the benchmark reference's recurrence, one step
@@ -484,19 +530,6 @@ def phase_state_space_scan():
     decay = -jnp.asarray(rng.uniform(1.0, 16.0, a["heads"]), jnp.float32)
     skip = jnp.ones((a["heads"],), jnp.float32)
 
-    def both(scan):
-        # the weight is an argument: closed over, its 134 MB would be a
-        # constant of the executable and evict the compile cache
-        def value(u, b, c, dt, weight):
-            o = scan(u, b, c, dt)
-            return jnp.sum(o.astype(jnp.float32) * weight), o
-
-        def run(*x):
-            (_, o), grads = jax.value_and_grad(
-                value, argnums=(0, 1, 2, 3), has_aux=True)(*x)
-            return (o,) + grads
-        return jax.jit(run)
-
     def recurrence(u, b, c, dt):
         share = a["heads"] // a["groups"]
         f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
@@ -505,22 +538,82 @@ def phase_state_space_scan():
                 f32(u), jnp.repeat(f32(b), share, 2),
                 jnp.repeat(f32(c), share, 2), dt, dt * decay, skip)
 
-    chunked = both(lambda *x: ssm.chunked_scan(*x, decay, skip, a["chunk"]))
+    chunked = _with_gradients(
+        lambda *x: ssm.chunked_scan(*x, decay, skip, a["chunk"]), 4)
     with CompileWatch() as watch:
         args = (u, b, c, dt, weight)
-        got, want = chunked(*args), both(recurrence)(*args)
-        errors = {}
-        for name, x, y in zip(("o", "d_u", "d_B", "d_C", "d_step"), got,
-                              want):
-            x, y = (np.asarray(z, np.float32) for z in (x, y))
-            errors[name] = round(float(np.linalg.norm(x - y)
-                                       / np.linalg.norm(y)), 5)
-            if not errors[name] <= SCAN_REL_L2:
-                raise RuntimeError(
-                    f"chunked scan: {name} disagrees with the recurrence: "
-                    f"rel_l2 {errors[name]} (allowed {SCAN_REL_L2})")
+        errors = _scan_errors(
+            "chunked scan", ("o", "d_u", "d_B", "d_C", "d_step"),
+            chunked(*args), _with_gradients(recurrence, 4)(*args))
         _emit("state_space_scan", sizes=a, dtype="bfloat16", rel_l2=errors,
               forward_backward_ms=_ms_per_call(chunked, args),
+              tolerance={"rel_l2": SCAN_REL_L2}, **watch.fields())
+
+
+def phase_delta_scan():
+    """``models/kda.chunked_delta_scan`` in bfloat16 at the sizes of its
+    benchmark cell against the benchmark reference's recurrence, one
+    position at a time in float32: the result and the gradients of q, k,
+    v, the log-decay and beta. Keys and queries are unit vectors and the
+    log-decays are drawn as the family initialises them, so the slow
+    channels carry a state across every one of the 64 chunks. Then the
+    whole mixer, forward and backward, with its two scopes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.reference import kda_moe_lm as reference
+    from horovod_tpu.models import kda
+    from horovod_tpu.models.transformer import TransformerConfig
+    from horovod_tpu.telemetry import scopes
+
+    a = DELTA
+    rng = np.random.default_rng(0)
+    shape = (a["batch"], a["seq_len"], a["heads"])
+    normal = lambda *tail: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape + tail), jnp.float32)
+    unit = lambda x: (x / jnp.linalg.norm(  # noqa: E731
+        x, axis=-1, keepdims=True)).astype(jnp.bfloat16)
+    q, k = unit(normal(a["head_dim"])), unit(normal(a["head_dim"]))
+    v = normal(a["head_dim"]).astype(jnp.bfloat16)
+    weight = normal(a["head_dim"])
+    g = -jnp.asarray(rng.uniform(1.0, 16.0, (a["heads"], 1)) * np.exp(
+        rng.uniform(np.log(0.001), np.log(0.1), shape + (a["head_dim"],))),
+        jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 1.0, shape), jnp.float32)
+
+    def recurrence(q, k, v, g, beta):
+        with jax.default_matmul_precision("highest"):
+            return reference._recurrence(
+                *(x.astype(jnp.float32) for x in (q, k, v)), g, beta)
+
+    chunked = _with_gradients(
+        lambda *x: kda.chunked_delta_scan(*x, a["chunk"]), 5)
+    with CompileWatch() as watch:
+        args = (q, k, v, g, beta, weight)
+        errors = _scan_errors(
+            "chunked delta scan",
+            ("o", "d_q", "d_k", "d_v", "d_g", "d_beta"),
+            chunked(*args), _with_gradients(recurrence, 5)(*args))
+        scan_ms = _ms_per_call(chunked, args)
+        del args
+        mixer = kda.DeltaAttention(TransformerConfig(
+            d_model=a["d_model"], norm_eps=1e-5, kda=kda.DeltaAttentionConfig(
+                num_heads=a["heads"], head_dim=a["head_dim"],
+                chunk_size=a["chunk"])))
+        x = jnp.asarray(rng.standard_normal(
+            shape[:2] + (a["d_model"],)), jnp.bfloat16)
+        params = jax.jit(mixer.init)(jax.random.PRNGKey(0), x)["params"]
+        step = jax.jit(jax.grad(lambda p, x: jnp.sum(mixer.apply(
+            {"params": p}, x).astype(jnp.float32)), argnums=(0, 1)))
+        text = step.lower(params, x).compile().as_text()
+        for scope in (scopes.KDA, scopes.KDA_SCAN):
+            if f"/{scope}/" not in text:
+                raise RuntimeError(f"delta attention: no instruction of the "
+                                   f"compiled mixer is under {scope!r}")
+        _emit("delta_scan", sizes=a, dtype="bfloat16", rel_l2=errors,
+              scan_forward_backward_ms=scan_ms,
+              mixer_forward_backward_ms=_ms_per_call(step, (params, x)),
               tolerance={"rel_l2": SCAN_REL_L2}, **watch.fields())
 
 
@@ -766,7 +859,8 @@ def main():
                  f"{found}")
     phases = ([phase_init, phase_data_parallel] if args.four_chips else
               [phase_init, phase_flash_kernel, phase_grouped_kernel,
-               phase_state_space_scan, phase_lm, phase_resnet, phase_serve])
+               phase_state_space_scan, phase_delta_scan, phase_lm,
+               phase_resnet, phase_serve])
     for phase in phases:
         phase()
         gc.collect()  # the next phase needs the device memory back

@@ -18,7 +18,10 @@ the framework is benchmarkable and usable standalone:
 * ``mla``         — multi-head latent attention (the DeepSeek-V3 family's
   mixer), through the one flash kernel at two head sizes.
 * ``ssm``         — a Mamba-2 state-space mixer with a chunked scan (the
-  ``nemotron_h`` family's), the third mixer kind.
+  ``nemotron_h`` family's).
+* ``kda``         — Kimi Delta Attention (the ``kimi_linear`` family's
+  gated delta rule with a decay a channel), chunked; the fourth mixer
+  kind.
 * ``moe``         — capacity-dispatch mixture of experts over an expert
   mesh axis.
 * ``experts``     — a no-drop expert layer that holds one chip's share of
@@ -45,11 +48,12 @@ from horovod_tpu.models.mla import LatentAttention, LatentAttentionConfig
 from horovod_tpu.models.experts import (ExpertShare, ExpertShareConfig,
                                         Relu2, SwiGLU)
 from horovod_tpu.models.ssm import StateSpaceConfig, StateSpaceMixer
+from horovod_tpu.models.kda import DeltaAttention, DeltaAttentionConfig
 
 __all__ = [
     "ResNet", "ResNet18", "ResNet34", "ResNet50", "ResNet101", "ResNet152",
     "MNISTConvNet", "MLP", "VGG16", "Transformer", "TransformerConfig",
     "MoE", "LatentAttention", "LatentAttentionConfig", "ExpertShare",
     "ExpertShareConfig", "SwiGLU", "Relu2", "StateSpaceConfig",
-    "StateSpaceMixer",
+    "StateSpaceMixer", "DeltaAttention", "DeltaAttentionConfig",
 ]

@@ -2,12 +2,16 @@
 
 The mixer of the DeepSeek-V3 family (``model_type deepseek_v3``): keys and
 values are expanded from one low-rank latent a token, and position enters
-through a separate rotary part that all heads share on the key side:
+through a separate rotary part that all heads share on the key side
+(where ``rotary`` is off, the ``kimi_linear`` family's ``mla_use_nope``,
+that part keeps its channels and is not turned: position then comes from
+the model's other mixers):
 
     q = x Wq                      -> per head [q_nope | q_pe]
     [c | k_pe] = x Wkva ;  c = rmsnorm(c)
     [k_nope | v] per head = c Wkvb
     q_pe, k_pe = rope(., theta, interleaved pairs); k_pe is ONE head
+                                  (``rotary`` off: both as they are)
     o = softmax(causal([q_nope|q_pe] [k_nope|k_pe]^T / sqrt(d_qk))) v
     out = concat(o) Wo
 
@@ -35,6 +39,7 @@ class LatentAttentionConfig:
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 1e6
+    rotary: bool = True  # off: q_pe and k_pe are concatenated unrotated
 
 
 def rotary_interleaved(x, positions, theta):
@@ -85,9 +90,10 @@ class LatentAttention(nn.Module):
                        "kv_b_proj")(c)
             k_nope, v = jnp.split(kv, [m.qk_nope_head_dim], axis=-1)
             q_nope, q_pe = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-            q_pe = rotary_interleaved(q_pe, positions, m.rope_theta)
-            k_pe = rotary_interleaved(k_pe[:, :, None, :], positions,
-                                      m.rope_theta)
+            k_pe = k_pe[:, :, None, :]
+            if m.rotary:
+                q_pe = rotary_interleaved(q_pe, positions, m.rope_theta)
+                k_pe = rotary_interleaved(k_pe, positions, m.rope_theta)
             q = jnp.concatenate([q_nope, q_pe], axis=-1)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)

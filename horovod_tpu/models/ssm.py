@@ -1,7 +1,7 @@
 """A Mamba-2 state-space mixer, the training form, with a chunked scan.
 
-The third mixer kind of ``models/transformer.py`` beside ``mha`` and
-``mla``: the mixer of the ``nemotron_h`` family's ``M`` layers. For a
+A mixer kind of ``models/transformer.py`` beside ``mha``, ``mla`` and
+``kda``: the mixer of the ``nemotron_h`` family's ``M`` layers. For a
 normed input ``y`` [S, d_model], ``H`` heads of ``P`` channels, ``G``
 groups that share one ``B`` and one ``C`` of ``N`` states each (head ``h``
 reads group ``h // (H / G)``), and a causal depthwise convolution of
@@ -131,10 +131,12 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 class CausalConv1d(nn.Module):
     """Depthwise over the channels, ``taps - 1`` zeros on the left, with
-    bias: ``out_t = bias + sum_k kernel[k] * x_{t - (taps - 1) + k}``, as
-    shifted multiplies that fuse into one pass."""
+    bias unless ``use_bias`` is off: ``out_t = bias + sum_k kernel[k] *
+    x_{t - (taps - 1) + k}``, as shifted multiplies that fuse into one
+    pass."""
     taps: int
     dtype: object = jnp.bfloat16
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -143,12 +145,13 @@ class CausalConv1d(nn.Module):
             "kernel", nn.initializers.variance_scaling(
                 1.0 / 3.0, "fan_in", "uniform", in_axis=0, out_axis=1),
             (self.taps, channels))
-        bias = self.param("bias", lambda key, shape: jax.random.uniform(
-            key, shape, jnp.float32, -1.0, 1.0) / math.sqrt(self.taps),
-            (channels,))
+        out = 0
+        if self.use_bias:
+            out = self.param("bias", lambda key, shape: jax.random.uniform(
+                key, shape, jnp.float32, -1.0, 1.0) / math.sqrt(self.taps),
+                (channels,)).astype(self.dtype)
         x = x.astype(self.dtype)
         padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        out = bias.astype(self.dtype)
         for k in range(self.taps):
             out = out + kernel[k].astype(self.dtype) * padded[:, k:k + s]
         return out

@@ -9,8 +9,9 @@ of the TPU framework. Design:
 * A layer is a pair (mixer, feed-forward) read from
   ``TransformerConfig.layer_pattern``: multi-head attention (grouped-query
   where ``num_kv_heads`` says so, without rotary where ``rotary`` is
-  off), latent attention (``models/mla.py``) or a state-space mixer
-  (``models/ssm.py``); GELU, SwiGLU, the capacity-dispatch MoE
+  off), latent attention (``models/mla.py``, with or without its rotary
+  part), a state-space mixer (``models/ssm.py``) or delta attention
+  (``models/kda.py``); GELU, SwiGLU, the capacity-dispatch MoE
   (``models/moe.py``) or the no-drop expert share with its shared experts
   (``models/experts.py``). Either half may be ``None``: a layer of one
   sublayer, behind one norm and one residual. The default pattern is the
@@ -81,7 +82,8 @@ class TransformerConfig:
     # A layer is a pair (mixer, feed-forward), one pair a layer:
     #   mixer         "mha" (Attention below) | "mla" (models/mla.py,
     #                 sized by ``mla``) | "ssm" (models/ssm.py, sized by
-    #                 ``ssm``) | None
+    #                 ``ssm``) | "kda" (models/kda.py, sized by ``kda``)
+    #                 | None
     #   feed-forward  "gelu" (two matrices, width d_ff) | "moe" (the
     #                 capacity dispatch of models/moe.py) | "swiglu"
     #                 (gated, width d_ff) | "experts" (the no-drop share
@@ -95,6 +97,7 @@ class TransformerConfig:
     mla: Any = None      # models.mla.LatentAttentionConfig
     experts: Any = None  # models.experts.ExpertShareConfig
     ssm: Any = None      # models.ssm.StateSpaceConfig
+    kda: Any = None      # models.kda.DeltaAttentionConfig
     # "mha" only. Key/value heads (None: one a query head); query head i
     # attends to key/value head i // (num_heads // num_kv_heads). The
     # width of a head (None: d_model // num_heads). Rotary position
@@ -255,6 +258,9 @@ class Block(nn.Module):
             elif self.mixer == "ssm":
                 from horovod_tpu.models.ssm import StateSpaceMixer
                 attention = StateSpaceMixer(cfg, name="mixer")
+            elif self.mixer == "kda":
+                from horovod_tpu.models.kda import DeltaAttention
+                attention = DeltaAttention(cfg, name="mixer")
             else:
                 raise ValueError(f"unknown mixer {self.mixer!r}")
             out = attention(y, positions, contiguous_positions, cache)

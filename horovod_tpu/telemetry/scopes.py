@@ -42,6 +42,15 @@ SSM = "hvd_ssm"
 # chunk, the chunk states, the scan over chunks, the inherited state's
 # part, D * u; forward, recomputation and backward
 SSM_SCAN = "hvd_ssm_scan"
+# delta attention outside its scan: the three projections, their
+# convolutions and silu, the L2 norms, the low-rank decay and its
+# softplus, beta, the gated head norm, the output projection
+KDA = "hvd_kda"
+# from (q, k, v, g, beta) to o: the cumulative log-decay, the decayed
+# k.k and q.k triangles of every chunk, the unit-triangular inverse, its
+# products, the scan over chunks with the state; forward, recomputation
+# and backward
+KDA_SCAN = "hvd_kda_scan"
 # host spans
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding

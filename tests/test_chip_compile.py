@@ -281,6 +281,81 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     assert footprint / 2 ** 30 < 14.1  # 14.091 until PR 32 (PERF.md)
 
 
+def test_delta_attention_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
+    """The whole train step of ``kimi-linear-48b-a3b-train-s4096`` as its
+    benchmark family builds it (5 layers at the published widths, 2 x 4096
+    tokens, AdamW), compiled for one described v5e chip: it fits the
+    chip's 15.75 GiB with the delta scan, the element-wise chains around it
+    and the expert share recomputed, nothing rematerialised by the
+    compiler, the flash kernel and megablox are in it as kernels, nothing
+    fell back, and the two delta scopes are on the delta layers'
+    instructions alone. (With the scan over all 32 heads at once and the
+    element-wise chains kept it compiled to 15.55 GiB with 76 ``.remat``
+    instructions; as it is, to 13.22 with none: PERF.md section 4.)"""
+    import json
+    import re
+    import warnings
+
+    import horovod_tpu as hvd
+    from benchmark.families import kda_moe_lm as family
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    load = lambda *parts: json.load(open(os.path.join(  # noqa: E731
+        repo, "benchmark", *parts)))
+    config = load("configs", "kimi-linear-48b-a3b.json")
+    traffic = load("traffic", "b2-s4096.json")
+    (device,) = v5e.device_set
+    # the model's platform sniffing (auto flash, megablox) sees the chip
+    monkeypatch.setattr(jax, "devices", lambda *a: [device])
+    hvd.shutdown()
+    hvd.init(devices=[device])
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", fa.FlashFallbackWarning)
+            built = family.build(config, traffic, hvd.mesh(), 7)
+            replicated = NamedSharding(hvd.mesh(), P())
+            state = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=replicated),
+                jax.eval_shape(built.init_state))
+            tokens = jax.ShapeDtypeStruct(
+                (traffic["per_chip_batch"], traffic["seq_len"]), jnp.int32,
+                sharding=NamedSharding(hvd.mesh(), P("data")))
+            compiled = built.step.jitted.lower(state, tokens).compile()
+    finally:
+        hvd.shutdown()
+    m = compiled.memory_analysis()
+    footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 12.0 < footprint / 2 ** 30 < 13.5  # 13.22 (PERF.md)
+    parameters = sum(int(np.prod(a.shape)) for a in
+                     jax.tree_util.tree_leaves(state.params))
+    assert parameters == config["parameters"] == 602_450_816
+    text = compiled.as_text()
+    assert not re.findall(r"\.remat[.\d]* = ", text)
+    # one latent-attention layer's forward and backward kernel, and
+    # megablox's three a product, two products a layer, forward,
+    # recomputed, backward, at both sizes of the share's buffers
+    assert text.count("tpu_custom_call") == 2 + 4 * 8 * 2
+    # the scopes: on the delta layers (blocks 0, 1, 2 and 4), not on the
+    # latent-attention layer (block 3) or anything outside a mixer
+    names = re.findall(r'op_name="([^"]*)"', text)
+    scan = [n for n in names if re.search(r"hvd_kda_scan(?![\w.])", n)]
+    rest = [n for n in names if re.search(r"hvd_kda(?![\w.])", n)]
+    assert scan and rest
+    assert all("/mixer/" in n and "block_3" not in n for n in scan + rest)
+    assert {re.search(r"block_\d", n).group() for n in scan} == {
+        "block_0", "block_1", "block_2", "block_4"}
+    assert any("block_3/attn/hvd_mla" in n for n in names)
+    # the scan's products and its loop over the chunks are under its
+    # scope; the projections under the mixer's
+    assert any(n.endswith("dot_general") and "/while/" in n for n in scan)
+    assert any("q_proj" in n for n in rest)
+    assert not any("_proj" in n for n in scan)
+
+
 def test_chip_smoke_refuses_to_run_without_a_chip():
     """chip_smoke.py on a machine whose jax finds no TPU runs no phase,
     prints no result and exits non-zero naming the platform it found —

@@ -431,3 +431,48 @@ def test_overflow_scope_is_on_the_way_out_alone():
                for line in lines for n in _OP_NAME_RE.findall(line)]
     assert has(outside, scopes.MOE_ROUTE)  # the router, top-k, the sorts
     assert not has(outside, scopes.MOE_OVERFLOW)
+
+
+def test_delta_scopes_are_on_the_delta_mixer_and_split_it():
+    """``hvd_kda_scan`` is on the delta rule from ``(q, k, v, g, beta)``
+    to ``o`` (its products, its loop over the chunks; forward, recomputed
+    and backward), ``hvd_kda`` on the rest of the mixer (projections,
+    convolutions, norms, gates), every product of a delta layer's mixer
+    is under exactly one of them, and nothing outside a delta mixer, the
+    latent-attention layer beside it least of all, carries either."""
+    from horovod_tpu.models.kda import DeltaAttentionConfig
+    from horovod_tpu.models.mla import LatentAttentionConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=2, d_model=16, d_ff=32,
+        dtype=jnp.float32, norm_eps=1e-5, flash_attention=False,
+        layer_pattern=(("kda", "swiglu"), ("mla", "swiglu")),
+        kda=DeltaAttentionConfig(num_heads=2, head_dim=8, chunk_size=8,
+                                 gate_rank=4),
+        mla=LatentAttentionConfig(kv_lora_rank=8, qk_nope_head_dim=8,
+                                  qk_rope_head_dim=4, v_head_dim=8,
+                                  rotary=False))
+    model = Transformer(cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)[
+        "params"]
+    text = jax.jit(jax.grad(lambda p: jnp.sum(model.apply(
+        {"params": p}, tokens)))).lower(params).compile().as_text()
+    names = _OP_NAME_RE.findall(text)
+    under = lambda scope: [n for n in names if re.search(  # noqa: E731
+        scope + r"(?![\w.])", n)]
+    scan, rest = under(scopes.KDA_SCAN), under(scopes.KDA)
+    assert scan and rest and not set(scan) & set(rest)
+    assert all("block_0/mixer/" in n for n in scan + rest)
+    assert any("/while/" in n and n.endswith("dot_general") for n in scan)
+    assert any("rematted_computation" in n for n in scan)
+    assert any("transpose(jvp(" in n for n in scan)
+    assert not any("_proj" in n or "conv1d" in n or "o_norm" in n
+                   for n in scan)
+    for part in ("q_proj", "k_conv1d", "f_b_proj", "b_proj", "g_b_proj",
+                 "o_norm", "o_proj"):
+        assert any(part in n for n in rest), part
+    products = [n for n in names if "block_0/mixer/" in n
+                and n.endswith("dot_general")]
+    assert products and all(n in scan or n in rest for n in products)
+    assert any("block_1/attn/hvd_mla" in n for n in names)
