@@ -23,10 +23,13 @@ points users call, at the full width of two models the repo supports:
 * ``state_space_scan`` the state-space mixer's chunked scan against the
                     recurrence one step at a time, result and gradients,
                     at its benchmark cell's sizes; then timed.
-* ``delta_scan``    delta attention's chunked scan (``models/kda.py``)
-                    against the delta rule one position at a time, result
-                    and gradients, at its benchmark cell's sizes; then the
-                    whole mixer forward and backward, timed, with both its
+* ``delta_scan``    delta attention's chunked scan (``models/kda.py``:
+                    the Pallas kernels of ``ops/delta_scan.py`` at these
+                    sizes, reported as ``path``) against the delta rule one
+                    position at a time, result and gradients, at its
+                    benchmark cell's sizes; forward and backward timed
+                    beside the plain ``jax.numpy`` form; then the whole
+                    mixer forward and backward, timed, with both its
                     device scopes in the compiled text.
 * ``lm``            the decoder LM, 8 layers d2048 16 heads, vocab 32000,
                     sequence 2048, batch 8, flash on (``make_lm_bench`` ->
@@ -556,8 +559,11 @@ def phase_delta_scan():
     position at a time in float32: the result and the gradients of q, k,
     v, the log-decay and beta. Keys and queries are unit vectors and the
     log-decays are drawn as the family initialises them, so the slow
-    channels carry a state across every one of the 64 chunks. Then the
-    whole mixer, forward and backward, with its two scopes."""
+    channels carry a state across every one of the 64 chunks. Which path
+    the shapes chose (the Pallas kernels of ``ops/delta_scan.py`` here),
+    its forward and backward milliseconds a layer beside the plain
+    form's in the same call, then the whole mixer, forward and backward,
+    with its two scopes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -565,6 +571,7 @@ def phase_delta_scan():
     from benchmark.reference import kda_moe_lm as reference
     from horovod_tpu.models import kda
     from horovod_tpu.models.transformer import TransformerConfig
+    from horovod_tpu.ops import delta_scan
     from horovod_tpu.telemetry import scopes
 
     a = DELTA
@@ -587,15 +594,30 @@ def phase_delta_scan():
             return reference._recurrence(
                 *(x.astype(jnp.float32) for x in (q, k, v)), g, beta)
 
-    chunked = _with_gradients(
-        lambda *x: kda.chunked_delta_scan(*x, a["chunk"]), 5)
+    # the form every size off the lanes takes
+    plain = lambda *x: kda._plain_scan(*x, a["chunk"])  # noqa: E731
+    scan = lambda *x: kda.chunked_delta_scan(*x, a["chunk"])  # noqa: E731
+    path = ("kernel" if delta_scan.supported(
+        a["chunk"], a["head_dim"], a["head_dim"], v.dtype) else "plain")
     with CompileWatch() as watch:
         args = (q, k, v, g, beta, weight)
+        chunked = _with_gradients(scan, 5)
+        if path == "kernel":
+            assert_kernel_compiled(
+                chunked.lower(*args).compile().as_text(), "delta scan")
         errors = _scan_errors(
             "chunked delta scan",
             ("o", "d_q", "d_k", "d_v", "d_g", "d_beta"),
             chunked(*args), _with_gradients(recurrence, 5)(*args))
-        scan_ms = _ms_per_call(chunked, args)
+        ms = {}
+        for name, fn, with_gradients in (
+                ("chosen", scan, chunked),
+                ("plain", plain, _with_gradients(plain, 5))):
+            forward = _ms_per_call(jax.jit(fn), args[:5])
+            both = _ms_per_call(with_gradients, args)
+            ms[name] = {"forward_ms": forward,
+                        "backward_ms": round(both - forward, 3),
+                        "forward_backward_ms": both}
         del args
         mixer = kda.DeltaAttention(TransformerConfig(
             d_model=a["d_model"], norm_eps=1e-5, kda=kda.DeltaAttentionConfig(
@@ -611,8 +633,9 @@ def phase_delta_scan():
             if f"/{scope}/" not in text:
                 raise RuntimeError(f"delta attention: no instruction of the "
                                    f"compiled mixer is under {scope!r}")
-        _emit("delta_scan", sizes=a, dtype="bfloat16", rel_l2=errors,
-              scan_forward_backward_ms=scan_ms,
+        _emit("delta_scan", sizes=a, dtype="bfloat16", path=path,
+              rel_l2=errors, scan_ms=ms,
+              scan_forward_backward_ms=ms["chosen"]["forward_backward_ms"],
               mixer_forward_backward_ms=_ms_per_call(step, (params, x)),
               tolerance={"rel_l2": SCAN_REL_L2}, **watch.fields())
 

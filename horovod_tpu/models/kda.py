@@ -40,18 +40,28 @@ exp(G_b - G_i)``, both factors at most 1, which makes those blocks plain
 matrix products. ``(I + A)^-1`` is built from the inverses of its
 diagonal blocks (``A`` is strictly lower, so nilpotent: ``(I - A)(I +
 A^2)(I + A^4)..``) merged two by two, ``[[P, 0], [X, Q]]^-1 = [[P^-1, 0],
-[-Q^-1 X P^-1, Q^-1]]``: products of matrices 8 to 32 wide, which run
-on the vector unit as multiplies and sums. Applied to ``beta V`` and to
-``beta K exp(G)`` it leaves ``U = U_v - W S_0``, so everything but what
-meets the state (two stacked products a chunk) is computed for all chunks
-at once, and a ``lax.scan`` carries ``S`` over them. ``g``, ``G``,
-``beta``, ``A``, the inverse and the carried state are float32; the
-operands of the products with q, k, v and u are the inputs' dtype with
-float32 accumulation.
+[-Q^-1 X P^-1, Q^-1]]``. ``g``, ``G``, ``beta``, ``A``, the inverse and
+the carried state are float32; the operands of the products with q, k, v
+and u are the inputs' dtype with float32 accumulation.
 
-Two device scopes: ``hvd_kda_scan`` from ``(q, k, v, g, beta)`` to ``o``,
-and ``hvd_kda`` for the rest of the mixer. The scan is recomputed in the
-backward pass (``jax.checkpoint``), a group of heads at a time.
+Two paths compute it, chosen by the shape alone. Channels that fill the
+128 lanes and a chunk of whole sublane tiles (the published 32 heads of
+128 in chunks of 64) take the Pallas kernels of ``ops/delta_scan.py``: a
+chunk lives in VMEM from its inputs to its outputs, the state is carried
+in a VMEM scratch, and a backward kernel of its own walks the chunks in
+reverse from the states the forward kept. Every other size (the small
+cells of the tests) takes ``_plain_scan`` below, plain ``jax.numpy``
+differentiated by jax, which is also what the kernels are tested against:
+there the products of the inverse, 8 to 32 wide, run on the vector unit
+as multiplies and sums; applied to ``beta V`` and to ``beta K exp(G)`` the
+inverse leaves ``U = U_v - W S_0``, so everything but what meets the state
+(two stacked products a chunk) is computed for all chunks at once, and a
+``lax.scan`` carries ``S`` over them, a group of heads at a time with the
+group recomputed in the backward pass (``jax.checkpoint``).
+
+Two device scopes: ``hvd_kda_scan`` from ``(q, k, v, g, beta)`` to ``o``
+(either path, forward and backward), and ``hvd_kda`` for the rest of the
+mixer.
 
 Training only: decode against three convolution windows and a state cache
 is ROADMAP's (queue R), and ``cache=`` is refused, not approximated.
@@ -66,22 +76,27 @@ import jax.numpy as jnp
 
 from horovod_tpu.models.ssm import (CausalConv1d, StateSpaceConfig,
                                     _a_log_init, _dt_bias_init)
+from horovod_tpu.ops import delta_scan
 from horovod_tpu.telemetry import scopes
 
+# The two constants below are ``_plain_scan``'s (the kernels of
+# ``ops/delta_scan.py`` have their own ``SUB``, the sublanes of a register,
+# and work on one head at a time).
 # Positions of a diagonal block, whose decays are formed pair by pair in
 # float32 on the vector unit (SUB x D multiplies, an exp and two sums a
 # position and head, and several times that in the backward pass: most of
-# the scan's time on a v5e); the blocks below the diagonal cost one exp and
-# one multiply a position, block and channel, and a matrix product. At 32
-# heads of 128 and 8,192 tokens, forward and backward a layer (my chip
-# runs, PR 33): 45.1 ms at 16, 38.2 at 8, 38.5 at 4.
+# the plain scan's time on a v5e); the blocks below the diagonal cost one
+# exp and one multiply a position, block and channel, and a matrix product.
+# The plain form at 32 heads of 128 and 8,192 tokens, forward and backward
+# a layer (my chip runs, PR 33, when it ran the cell): 45.1 ms at 16, 38.2
+# at 8, 38.5 at 4.
 SUB = 8
-# Heads whose chunks are worked on at once. What a pass holds between the
-# scan's forward and backward halves is a few float32 arrays a token and
-# head (3.6 GiB for 32 heads at those sizes, 1 GiB for 8), and the smaller
-# it is the more of it stays in the chip's fast memory: 41.9 ms a layer at
-# 8 heads, 38.2 at 4, 38.6 at 2 (with 16 x 16 blocks: 53.8 at 32, 51.8 at
-# 8, 44.3 at 4).
+# Heads whose chunks the plain form works on at once. What a pass holds
+# between the scan's forward and backward halves is a few float32 arrays a
+# token and head (3.6 GiB for 32 heads at those sizes, 1 GiB for 8), and the
+# smaller it is the more of it stays in the chip's fast memory: 41.9 ms a
+# layer at 8 heads, 38.2 at 4, 38.6 at 2 (with 16 x 16 blocks: 53.8 at 32,
+# 51.8 at 8, 44.3 at 4).
 HEADS_AT_ONCE = 4
 
 
@@ -128,10 +143,23 @@ def chunked_delta_scan(q, k, v, g, beta, chunk):
     q, k, v [B, S, H, D], q and k already normed (and q scaled); g
     [B, S, H, D] float32, not positive; beta [B, S, H] float32. ``chunk``
     must divide S. The products with q, k, v and u run in ``v.dtype`` with
-    float32 accumulation. ``HEADS_AT_ONCE`` heads at a time, each group
-    under ``jax.checkpoint``: the backward pass computes a group's
-    forward again and keeps nothing of it but what went in, so a caller
-    wraps this in no ``remat``."""
+    float32 accumulation. The shape chooses the path: channels that fill
+    the 128 lanes and a chunk of whole sublane tiles go through the Pallas
+    kernels (``ops/delta_scan.py``), which keep their inputs and the state
+    each chunk inherits for a backward pass of their own; every other size
+    through ``_plain_scan``. Either way a caller wraps this in no
+    ``remat``."""
+    if delta_scan.supported(chunk, k.shape[-1], v.shape[-1], v.dtype):
+        return delta_scan.delta_scan(q, k, v, g, beta, chunk)
+    return _plain_scan(q, k, v, g, beta, chunk)
+
+
+def _plain_scan(q, k, v, g, beta, chunk):
+    """``chunked_delta_scan`` in plain ``jax.numpy`` differentiated by jax:
+    what every size off the lanes takes, and what the kernels are tested
+    against. ``HEADS_AT_ONCE`` heads at a time, each group under
+    ``jax.checkpoint``: the backward pass computes a group's forward again
+    and keeps nothing of it but what went in."""
     bsz, s, h = k.shape[:3]
     at_once = math.gcd(h, HEADS_AT_ONCE)
     # [B, S, H, ..] -> [group, B, head of the group, S, ..]: one transpose
@@ -306,8 +334,9 @@ class DeltaAttention(nn.Module):
             g = jax.checkpoint(_log_decay)(rate, a_log, dt_bias)
             beta = jax.nn.sigmoid(dense(h, "b_proj")(x).astype(jnp.float32))
         with scopes.device(scopes.KDA_SCAN):
-            # recomputes itself in the backward pass: a chunk's triangles,
-            # its inverse and the state every chunk inherits are never kept
+            # makes a chunk's triangles and inverse again in the backward
+            # pass, on either path; the kernels keep the state every chunk
+            # inherits, the plain form not even that
             o = chunked_delta_scan(q, k, v, g, beta, m.chunk_size)
         with scopes.device(scopes.KDA):
             gate = dense(h * d, "g_b_proj", bias=True)(
