@@ -20,7 +20,15 @@ Top-level namespace re-exports the JAX-first API (reference equivalent:
 ``horovod/tensorflow/__init__.py`` / ``horovod/torch/__init__.py``).
 """
 
-from horovod_tpu import compat  # noqa: F401  (installs jax.shard_map shim)
+import sys as _sys
+import time as _time
+
+# the set-up record opens here, before the first heavy import, on the
+# clock its other spans use (telemetry/startup.py)
+_IMPORT_STARTED = _time.time()
+_JAX_WAS_IMPORTED = "jax" in _sys.modules
+
+from horovod_tpu import compat  # noqa: E402,F401  (installs jax.shard_map shim)
 from horovod_tpu.basics import (
     init,
     shutdown,
@@ -96,3 +104,7 @@ __all__ = [
     "broadcast_optimizer_state", "allreduce_metrics", "join",
     "checkpoint", "ckpt", "data", "elastic", "telemetry",
 ]
+
+telemetry.startup.RECORD.add(
+    telemetry.scopes.IMPORT, _IMPORT_STARTED, _time.time(),
+    jax_was_imported=_JAX_WAS_IMPORTED)

@@ -29,6 +29,7 @@ import jax
 
 from horovod_tpu.config import Config
 from horovod_tpu.parallel import mesh as mesh_lib
+from horovod_tpu.telemetry import scopes, startup
 
 logger = logging.getLogger("horovod_tpu")
 
@@ -78,6 +79,14 @@ def init(num_slices=None, devices=None):
     with _lock:
         if _state.initialized:
             return
+        with startup.span(scopes.INIT):
+            _init(num_slices, devices)
+    atexit.register(shutdown)
+
+
+def _init(num_slices, devices):
+    """``init()``'s work, in the four parts the set-up record names."""
+    with startup.span(scopes.INIT_CONFIG):
         cfg = Config.from_env()
         _configure_logging(cfg)
 
@@ -88,16 +97,18 @@ def init(num_slices=None, devices=None):
         config_lib.apply_xla_flags(cfg)
         config_lib.apply_compile_cache()
 
-        # Multi-process: join the distributed JAX runtime so jax.devices()
-        # spans every chip in the job. The coordinator address is provided by
-        # the hvdrun launcher (TPU analogue of the gloo rendezvous address,
-        # gloo_context.cc:41-50). cluster.ensure_distributed is the one
-        # sanctioned jax.distributed.initialize call site (HVD-DISTINIT)
-        # and also arms the CPU gloo collectives + forced per-process
-        # device count before the first backend touch.
+    # Multi-process: join the distributed JAX runtime so jax.devices()
+    # spans every chip in the job. The coordinator address is provided by
+    # the hvdrun launcher (TPU analogue of the gloo rendezvous address,
+    # gloo_context.cc:41-50). cluster.ensure_distributed is the one
+    # sanctioned jax.distributed.initialize call site (HVD-DISTINIT)
+    # and also arms the CPU gloo collectives + forced per-process
+    # device count before the first backend touch.
+    with startup.span(scopes.INIT_DISTRIBUTED):
         from horovod_tpu.cluster import procmesh
         multiproc = procmesh.ensure_distributed(cfg)
 
+    with startup.span(scopes.INIT_BACKEND) as backend:
         if multiproc and jax.process_count() > 1 and devices is None and \
                 num_slices in (None, jax.process_count()):
             # ONE logical mesh spanning every process: dcn outer axis =
@@ -110,22 +121,23 @@ def init(num_slices=None, devices=None):
                 num_slices = cfg.cross_size if cfg.cross_size > 1 else 1
             m = mesh_lib.build_mesh(devices=devices, num_slices=num_slices)
         mesh_lib.set_mesh(m)
+        backend["devices"] = int(m.devices.size)
 
-        _state.config = cfg
-        _state.mesh = m
-        _state.initialized = True
+    _state.config = cfg
+    _state.mesh = m
+    _state.initialized = True
 
-        # Host-side services (timeline, stall inspector, controller client)
-        # attach lazily; see horovod_tpu.runtime.
+    # Host-side services (timeline, stall inspector, controller client)
+    # attach lazily; see horovod_tpu.runtime.
+    with startup.span(scopes.INIT_SERVICES):
         from horovod_tpu.runtime import services
         services.start(_state)
 
-        logger.info(
-            "horovod_tpu initialized: rank=%d size=%d local=%d/%d cross=%d/%d "
-            "mesh=%s devices=%d", cfg.rank, cfg.size, cfg.local_rank,
-            cfg.local_size, cfg.cross_rank, cfg.cross_size,
-            dict(zip(m.axis_names, m.devices.shape)), m.devices.size)
-    atexit.register(shutdown)
+    logger.info(
+        "horovod_tpu initialized: rank=%d size=%d local=%d/%d cross=%d/%d "
+        "mesh=%s devices=%d", cfg.rank, cfg.size, cfg.local_rank,
+        cfg.local_size, cfg.cross_rank, cfg.cross_size,
+        dict(zip(m.axis_names, m.devices.shape)), m.devices.size)
 
 
 def shutdown():

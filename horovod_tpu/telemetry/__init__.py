@@ -14,6 +14,8 @@ Chrome-trace counter events merged across ranks (``merge.py`` +
 from horovod_tpu.telemetry import instruments  # noqa: F401
 from horovod_tpu.telemetry import ledger  # noqa: F401
 from horovod_tpu.telemetry import report  # noqa: F401
+from horovod_tpu.telemetry import scopes  # noqa: F401
+from horovod_tpu.telemetry import startup  # noqa: F401
 from horovod_tpu.telemetry.instruments import (  # noqa: F401
     DataInstruments,
     StepInstruments,
@@ -41,5 +43,6 @@ __all__ = [
     "data_instruments", "enabled", "build_info_gauge",
     "install_compile_listeners", "record_collective", "record_bucket",
     "load_events", "merge_traces", "instruments", "ledger", "report",
+    "scopes", "startup",
     "TimeLedger", "get_ledger",
 ]

@@ -706,9 +706,16 @@ _compile_listener_installed = False
 
 
 def install_compile_listeners():
-    """Count jax compilation-cache hits/misses and compile seconds via
-    ``jax.monitoring`` events. Idempotent; silently unavailable on jax
-    builds without the monitoring hooks."""
+    """The one listener the program has on ``jax.monitoring``: cache hits
+    and misses, the seconds jax spent building programs, and the set-up
+    record's jax spans (``telemetry/startup.py``, which tells a top-level
+    span from one inside another). ``hvd_compile_seconds_total`` and the
+    goodput ledger's ``compile`` phase get the top-level spans of the
+    three ``/jax/core/compile/*`` events (trace, lowering, backend: a
+    compile or a cache read), each second once; the seconds a cache hit
+    saved and the cache's retrieval time inside the backend span are not
+    added. Idempotent; silently unavailable on jax builds without the
+    monitoring hooks."""
     global _compile_listener_installed
     if _compile_listener_installed:
         return
@@ -717,43 +724,54 @@ def install_compile_listeners():
     # hvd-lint: disable=HVD-EXCEPT -- jax.monitoring absent on this version
     except Exception:
         return
+    from horovod_tpu.telemetry import ledger as ledger_lib
+    from horovod_tpu.telemetry import startup
     r = get_registry()
     hits = r.counter(COMPILE_CACHE_HITS,
                      "jax compilation-cache hits this process")
     misses = r.counter(COMPILE_CACHE_MISSES,
                        "jax compilation-cache misses this process")
-    compile_s = r.counter(COMPILE_SECONDS,
-                          "Cumulative seconds spent in XLA compilation")
+    compile_s = r.counter(
+        COMPILE_SECONDS,
+        "Cumulative seconds jax spent building programs: top-level "
+        "trace, lowering and backend (compile or cache read) spans")
 
     def on_event(event, **kwargs):
         # a telemetry listener must NEVER throw into jax's dispatch path
         try:
-            if "cache_hit" in event or event.endswith("cache_hits"):
+            said = startup.cache_said(event)
+            if said == "hit":
                 hits.inc()
-            elif "cache_miss" in event or event.endswith("cache_misses"):
+            elif said == "miss":
                 misses.inc()
         # hvd-lint: disable=HVD-EXCEPT -- a listener must never break compilation
         except Exception:
             pass
 
-    def on_duration(event, duration, **kwargs):
+    def on_start(event, value, **kwargs):
         try:
-            # some jax events report negative/relative durations; only
-            # positive compile times are meaningful to accumulate
-            if "compil" in event and duration > 0:
-                compile_s.inc(duration)
-                # the goodput ledger books compilation out of the step
+            startup.build_started(event)
+        # hvd-lint: disable=HVD-EXCEPT -- a listener must never break compilation
+        except Exception:
+            pass
+
+    def on_span(event, start, end, fun_name="", **kwargs):
+        try:
+            seconds = startup.build_ended(event, start, end, fun_name)
+            if seconds is not None and seconds > 0:
+                compile_s.inc(seconds)
+                # the goodput ledger books building out of the step
                 # interval it lands in (first dispatch), so a compile-
                 # heavy run cannot masquerade as compute
-                from horovod_tpu.telemetry import ledger as ledger_lib
-                ledger_lib.get_ledger().charge("compile", duration)
+                ledger_lib.get_ledger().charge("compile", seconds)
         # hvd-lint: disable=HVD-EXCEPT -- a listener must never break compilation
         except Exception:
             pass
 
     try:
         monitoring.register_event_listener(on_event)
-        monitoring.register_event_duration_secs_listener(on_duration)
+        monitoring.register_scalar_listener(on_start)
+        monitoring.register_event_time_span_listener(on_span)
         _compile_listener_installed = True
     # hvd-lint: disable=HVD-EXCEPT -- monitoring registration is optional
     except Exception:

@@ -26,7 +26,10 @@ Phases (exclusive — each second lands in exactly one):
   pipeline (``hvd_data_wait_seconds``'s source, charged here too).
 * ``ckpt_stall``          — the blocking portion of checkpoint saves
   (snapshot + budget wait + any flush the training thread sat in).
-* ``compile``             — XLA compilation (jax.monitoring durations).
+* ``compile``             — building programs: jax's top-level trace,
+  lowering and backend spans (a compile, or a read of the persistent
+  cache), each second once; the seconds a cache hit saved are not time
+  spent and are not charged (``instruments.install_compile_listeners``).
 * ``rendezvous_recovery`` — elastic recovery: rollback, restore from
   checkpoint, re-rendezvous sync.
 * ``preemption``          — planned-churn cost: the graceful-eviction
@@ -433,6 +436,13 @@ class TimeLedger:
             from horovod_tpu.telemetry import instruments as _tele
             payload["build_info"] = _tele.build_info_labels()
         # hvd-lint: disable=HVD-EXCEPT -- build info is optional dump metadata
+        except Exception:
+            pass
+        try:
+            from horovod_tpu.telemetry import startup as _startup
+            # where the seconds before the first warm step went
+            payload["startup"] = _startup.RECORD.summary()
+        # hvd-lint: disable=HVD-EXCEPT -- the set-up record is optional dump metadata
         except Exception:
             pass
         if extra:

@@ -122,6 +122,7 @@ def aggregate(dumps):
             "steps": d.get("steps"),
             "build_info": d.get("build_info"),
             "compiled_path": bool(d.get("compiled_path")),
+            "startup": d.get("startup"),
             "path": d.get("_path"),
         }
         for p in PHASES:
@@ -179,6 +180,49 @@ def _pct(seconds, wall):
     return 100.0 * seconds / wall if wall > 0 else 0.0
 
 
+def format_startup(rank, startup, top=8):
+    """The set-up record of one rank's dump (``telemetry/startup.py``):
+    its program spans in order, indented under their parents, then the
+    programs jax built before the first warm step, longest first."""
+    spans = startup.get("spans") or []
+    if not spans:
+        return []
+    first = spans[0]["start"]
+    upto = startup.get("closed_at")
+    lines = [f"rank {rank} start-up: "
+             + (f"{upto - first:.2f}s to the first step from warm caches"
+                if upto else "no step ran from warm caches yet")
+             + (f"; step program: {startup['step_program']}"
+                if startup.get("step_program") else "")]
+    depth = {}
+    for s in spans:
+        depth[s["name"]] = depth.get(s.get("parent"), 0) + 1
+        took = (f"{s['end'] - s['start']:>8.2f}s" if s.get("end")
+                else "    open")
+        lines.append(f"  {'  ' * (depth[s['name']] - 1)}{s['name']:<24} "
+                     f"+{s['start'] - first:>7.2f}s {took}")
+    programs = sorted(startup.get("programs") or [],
+                      key=lambda p: -(p["trace_s"] + p["lower_s"]
+                                      + p["xla_s"]))
+    if programs:
+        lines.append(f"  {'program':<32} builds  trace_s  lower_s    "
+                     "xla_s  cache")
+    for p in programs[:top]:
+        lines.append(f"  {p['program'][:32]:<32} {p['builds']:>6} "
+                     f"{p['trace_s']:>8.2f} {p['lower_s']:>8.2f} "
+                     f"{p['xla_s']:>8.2f}  {p['cache'] or '-'}")
+    if len(programs) > top:
+        rest = programs[top:]
+        lines.append(f"  ({len(rest)} more programs: "
+                     f"{sum(p['trace_s'] for p in rest):.2f}s tracing, "
+                     f"{sum(p['lower_s'] for p in rest):.2f}s lowering, "
+                     f"{sum(p['xla_s'] for p in rest):.2f}s backend)")
+    for name, late in sorted((startup.get("late_builds") or {}).items()):
+        lines.append(f"  built after start-up: {name} x{late['builds']} "
+                     f"({late['seconds']:.2f}s)")
+    return lines
+
+
 def format_report(report):
     lines = []
     add = lines.append
@@ -218,6 +262,9 @@ def format_report(report):
             f"{100 * info['goodput_ratio']:.1f}%, dominant sink: {sink}"
             + (f", steps {info['steps']}"
                if info.get("steps") is not None else ""))
+    for r, info in sorted(report["ranks"].items()):
+        if info.get("startup"):
+            lines.extend(format_startup(r, info["startup"]))
     bi = next((i["build_info"] for i in report["ranks"].values()
                if i.get("build_info")), None)
     if bi:

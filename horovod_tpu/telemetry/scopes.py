@@ -55,6 +55,18 @@ KDA_SCAN = "hvd_kda_scan"
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding
 LAUNCH = "hvd_launch"  # the call of the jitted / compiled step
+# the set-up record's spans (telemetry/startup.py), host annotations too
+IMPORT = "hvd_import"  # first to last line of horovod_tpu/__init__.py
+INIT = "hvd_init"      # the whole of basics.init, and its four parts:
+INIT_CONFIG = "hvd_init_config"  # env, logging, XLA flags, compile cache
+INIT_DISTRIBUTED = "hvd_init_distributed"  # procmesh.ensure_distributed
+INIT_BACKEND = "hvd_init_backend"    # the first backend touch: the mesh
+INIT_SERVICES = "hvd_init_services"  # runtime.services.start
+LOWER = "hvd_lower"    # _HostStep.lower: placement and program.lower
+# jax's own top-level build spans, as the record keeps them
+JAX_TRACE = "jax_trace"  # function -> jaxpr
+JAX_LOWER = "jax_lower"  # jaxpr -> MLIR module
+JAX_XLA = "jax_xla"      # the backend: a compile, or a cache read
 
 
 def device(name):

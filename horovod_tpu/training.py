@@ -33,6 +33,7 @@ from horovod_tpu.parallel import mesh as mesh_lib
 from horovod_tpu.parallel import zero as zero_lib
 from horovod_tpu.telemetry import ledger as _ledger_lib
 from horovod_tpu.telemetry import scopes
+from horovod_tpu.telemetry import startup as _startup
 
 
 @dataclasses.dataclass
@@ -507,6 +508,25 @@ class _HostStep:
         return tuple(batch)
 
     def step(self, state, *batch):
+        # the set-up record keeps every step until the first one that ran
+        # from warm caches alone; after that a step pays this one check
+        # (and a call: the body below is the same with and without it)
+        record = _startup.RECORD
+        entry = (None if record.closed
+                 else record.open_span(scopes.STEP, step_num=self._n))
+        if entry is None:
+            return self._step(state, batch, None)
+        try:
+            out = self._step(state, batch, entry["attrs"])
+        except BaseException:
+            record.close_span(entry)
+            raise
+        record.step_returned(entry)
+        return out
+
+    __call__ = step
+
+    def _step(self, state, batch, marks):
         n = self._n
         with scopes.step(n):
             if not batch:
@@ -531,10 +551,14 @@ class _HostStep:
             try:
                 with scopes.host(scopes.PLACE):
                     placed = self._place(state, batch)
+                if marks is not None:
+                    marks["place_end"] = time.time()
                 launch = (self._program if self._prepare is None
                           else self._prepare(placed))
                 with scopes.host(scopes.LAUNCH):
                     outs = launch(*placed)
+                if marks is not None:
+                    marks["launch_end"] = time.time()
                 if self._carry is not None:
                     self._carry.keep(outs[1])
             except BaseException:
@@ -570,13 +594,12 @@ class _HostStep:
                     step_no=n)
         return outs[0], loss
 
-    __call__ = step
-
     def lower(self, state, *batch):
         """AOT lower with the SAME placement the executed path uses, so
         the compile cache is shared and cost_analysis describes the
         module that actually runs."""
-        return self._program.lower(*self._place(state, batch))
+        with _startup.span(scopes.LOWER):
+            return self._program.lower(*self._place(state, batch))
 
 
 def _xray(step, state, *batch, k=3, profile_dir=None):
