@@ -20,9 +20,13 @@ points users call, at the full width of two models the repo supports:
                     groups: result, input and weight gradient, at the
                     SwiGLU cell's sizes and at the relu^2 cell's (a width
                     off the 128 lanes).
-* ``state_space_scan`` the state-space mixer's chunked scan against the
-                    recurrence one step at a time, result and gradients,
-                    at its benchmark cell's sizes; then timed.
+* ``state_space_scan`` the state-space mixer's chunked scan
+                    (``models/ssm.py``: the Pallas kernels of
+                    ``ops/ssm_scan.py`` at these sizes, reported as
+                    ``path``) against the recurrence one step at a time,
+                    result and gradients, at its benchmark cell's sizes;
+                    forward and backward timed beside the plain
+                    ``jax.numpy`` form.
 * ``delta_scan``    delta attention's chunked scan (``models/kda.py``:
                     the Pallas kernels of ``ops/delta_scan.py`` at these
                     sizes, reported as ``path``) against the delta rule one
@@ -506,19 +510,40 @@ def _scan_errors(what, names, got, want):
     return errors
 
 
+def _chosen_and_plain_ms(scan, plain, chunked, args, count):
+    """Forward and backward milliseconds a layer of the path the shapes
+    chose (``chunked``: its jitted value and gradients) beside the plain
+    form's, in the same call."""
+    import jax
+
+    ms = {}
+    for name, fn, with_gradients in (
+            ("chosen", scan, chunked),
+            ("plain", plain, _with_gradients(plain, count))):
+        forward = _ms_per_call(jax.jit(fn), args[:count])
+        both = _ms_per_call(with_gradients, args)
+        ms[name] = {"forward_ms": forward,
+                    "backward_ms": round(both - forward, 3),
+                    "forward_backward_ms": both}
+    return ms
+
+
 def phase_state_space_scan():
     """``models/ssm.chunked_scan`` in bfloat16 at the sizes of its
     benchmark cell against the benchmark reference's recurrence, one step
     at a time in float32: the result and the gradients of ``u``, ``B``,
     ``C`` and the step. The steps and decays are drawn as the family
     initialises them, so the slow heads carry a state across every one of
-    the 32 chunks."""
+    the 32 chunks. Which path the shapes chose (the Pallas kernels of
+    ``ops/ssm_scan.py`` here), and its forward and backward milliseconds
+    a layer beside the plain form's in the same call."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from benchmark.reference import ssm_moe_lm as reference
     from horovod_tpu.models import ssm
+    from horovod_tpu.ops import ssm_scan
 
     a = SCAN
     rng = np.random.default_rng(0)
@@ -541,15 +566,28 @@ def phase_state_space_scan():
                 f32(u), jnp.repeat(f32(b), share, 2),
                 jnp.repeat(f32(c), share, 2), dt, dt * decay, skip)
 
-    chunked = _with_gradients(
-        lambda *x: ssm.chunked_scan(*x, decay, skip, a["chunk"]), 4)
+    scan = lambda *x: ssm.chunked_scan(  # noqa: E731
+        *x, decay, skip, a["chunk"])
+    # the form every size off the lanes takes, recomputed as it runs there
+    plain = lambda *x: jax.checkpoint(  # noqa: E731
+        ssm._plain_scan, static_argnums=(6,))(*x, decay, skip, a["chunk"])
+    path = ("kernel" if ssm_scan.supported(
+        a["chunk"], a["head_dim"], a["heads"] // a["groups"], a["states"],
+        u.dtype) else "plain")
     with CompileWatch() as watch:
         args = (u, b, c, dt, weight)
+        chunked = _with_gradients(scan, 4)
+        if path == "kernel":
+            assert_kernel_compiled(
+                chunked.lower(*args).compile().as_text(),
+                "state-space scan")
         errors = _scan_errors(
             "chunked scan", ("o", "d_u", "d_B", "d_C", "d_step"),
             chunked(*args), _with_gradients(recurrence, 4)(*args))
-        _emit("state_space_scan", sizes=a, dtype="bfloat16", rel_l2=errors,
-              forward_backward_ms=_ms_per_call(chunked, args),
+        ms = _chosen_and_plain_ms(scan, plain, chunked, args, 4)
+        _emit("state_space_scan", sizes=a, dtype="bfloat16", path=path,
+              rel_l2=errors, scan_ms=ms,
+              forward_backward_ms=ms["chosen"]["forward_backward_ms"],
               tolerance={"rel_l2": SCAN_REL_L2}, **watch.fields())
 
 
@@ -609,15 +647,7 @@ def phase_delta_scan():
             "chunked delta scan",
             ("o", "d_q", "d_k", "d_v", "d_g", "d_beta"),
             chunked(*args), _with_gradients(recurrence, 5)(*args))
-        ms = {}
-        for name, fn, with_gradients in (
-                ("chosen", scan, chunked),
-                ("plain", plain, _with_gradients(plain, 5))):
-            forward = _ms_per_call(jax.jit(fn), args[:5])
-            both = _ms_per_call(with_gradients, args)
-            ms[name] = {"forward_ms": forward,
-                        "backward_ms": round(both - forward, 3),
-                        "forward_backward_ms": both}
+        ms = _chosen_and_plain_ms(scan, plain, chunked, args, 5)
         del args
         mixer = kda.DeltaAttention(TransformerConfig(
             d_model=a["d_model"], norm_eps=1e-5, kda=kda.DeltaAttentionConfig(

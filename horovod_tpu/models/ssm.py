@@ -19,19 +19,31 @@ reads group ``h // (H / G)``), and a causal depthwise convolution of
 
 ``chunked_scan`` computes the recurrence in chunks of ``chunk_size``
 positions (the state-space-duality form): inside a chunk the lower
-triangle of decay products times ``C B^T`` multiplies ``D * u`` as batched
-matrix products; each chunk's end state is one more product; a
-``lax.scan`` carries the state over the chunks; and what a chunk inherits
-reaches its positions through ``C S`` times the decay since the chunk's
-start. The step, the cumulative log-decay (differences BEFORE the
-``exp``: a product of decays underflows, a ratio of them overflows) and
-the carried state are float32; the operands of the products are the
-module's dtype with float32 accumulation.
+triangle of decay products times ``C B^T`` multiplies ``D * u`` as
+matrix products; each chunk's end state is one more product; the state is
+carried over the chunks; and what a chunk inherits reaches its positions
+through ``C S`` times the decay since the chunk's start. The step, the
+cumulative log-decay (differences BEFORE the ``exp``: a product of decays
+underflows, a ratio of them overflows) and the carried state are float32;
+the operands of the products are the module's dtype with float32
+accumulation.
 
-Two device scopes: ``hvd_ssm_scan`` from ``(u, B, C, D)`` to ``o``, and
-``hvd_ssm`` for the rest of the mixer. The scan is recomputed in the
-backward pass (``jax.checkpoint``): its per-chunk decay matrices and
-states are never kept.
+Two paths compute it, chosen by the shape alone. Heads that share whole
+128-lane slabs, a group's heads and the states whole slabs and a chunk of
+whole sublane tiles (the published 64 heads of 64 in 8 groups of 128
+states, chunks of 128) take the Pallas kernels of ``ops/ssm_scan.py``: a
+group's chunk lives in VMEM from its inputs to its outputs, the states of
+the group's heads are carried in a VMEM scratch, and a backward kernel of
+its own walks the chunks in reverse from the states the forward kept.
+Every other size (the small cells of the tests) takes ``_plain_scan``
+below, plain ``jax.numpy`` differentiated by jax and recomputed in the
+backward pass (``jax.checkpoint``: its per-chunk decay matrices and states
+are never kept), which is also what the kernels are tested against: all
+chunks' products at once as batched matrix products and a ``lax.scan``
+that carries the state over them.
+
+Two device scopes: ``hvd_ssm_scan`` from ``(u, B, C, D)`` to ``o`` (either
+path, forward and backward), and ``hvd_ssm`` for the rest of the mixer.
 
 Training only: decode against a convolution window and a state cache is
 ROADMAP's (queue R), and ``cache=`` is refused, not approximated.
@@ -44,6 +56,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.ops import ssm_scan
 from horovod_tpu.telemetry import scopes
 
 
@@ -66,7 +79,24 @@ def chunked_scan(u, b, c, dt, a, d_skip, chunk):
 
     u [B, S, H, P]; b, c [B, S, G, N]; dt [B, S, H] float32, positive;
     a [H] float32, negative; d_skip [H]. ``chunk`` must divide S. The
-    products run in ``u.dtype`` with float32 accumulation."""
+    products run in ``u.dtype`` with float32 accumulation.
+
+    The shape alone chooses the path: sizes that fill the lanes take the
+    kernels (``ops/ssm_scan.py``), which keep their inputs and the state
+    each chunk inherits for a backward kernel of their own; every other
+    size goes through ``_plain_scan``, recomputed in the backward pass.
+    Either way a caller wraps this in no ``jax.checkpoint``."""
+    if ssm_scan.supported(chunk, u.shape[3], u.shape[2] // b.shape[2],
+                          b.shape[3], u.dtype):
+        return ssm_scan.ssm_scan(u, b, c, dt, a, d_skip, chunk)
+    # a chunk's decay matrices and states are eight times the size of
+    # what goes in
+    return jax.checkpoint(_plain_scan, static_argnums=(6,))(
+        u, b, c, dt, a, d_skip, chunk)
+
+
+def _plain_scan(u, b, c, dt, a, d_skip, chunk):
+    """``chunked_scan`` in plain ``jax.numpy`` differentiated by jax."""
     f32, dtype = jnp.float32, u.dtype
     bsz, s, h, p = u.shape
     g, n = b.shape[2:]
@@ -215,9 +245,7 @@ class StateSpaceMixer(nn.Module):
             u, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
             step = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
         with scopes.device(scopes.SSM_SCAN):
-            # recomputed in the backward pass: a chunk's decay matrices
-            # and states are eight times the size of what goes in
-            o = jax.checkpoint(chunked_scan, static_argnums=(6,))(
+            o = chunked_scan(
                 u.reshape(bsz, s, h, p), b.reshape(bsz, s, g, n),
                 c.reshape(bsz, s, g, n), step,
                 -jnp.exp(a_log.astype(jnp.float32)), d_skip, m.chunk_size)

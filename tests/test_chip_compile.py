@@ -29,6 +29,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 from horovod_tpu.ops import delta_scan  # noqa: E402
 from horovod_tpu.ops import flash_attention as fa  # noqa: E402
+from horovod_tpu.ops import ssm_scan  # noqa: E402
 
 # [B, S, H, D] of the d2048 16-head LM and the d768 12-head LM, and two
 # long ones for the backward's one sequence-sized VMEM buffer, the
@@ -221,11 +222,15 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     """The whole train step of ``nemotron-3-nano-30b-a3b-train-s4096`` as
     its benchmark family builds it (9 layers at the published widths, 2 x
     4096 tokens, AdamW), compiled for one described v5e chip: it fits the
-    chip's 15.75 GiB with only the scan and the expert share recomputed,
-    the flash kernel and megablox are in it as kernels, nothing fell
-    back, and the expert share's way out past its buffers' bound is a
-    branch of its own whose scope is on nothing else. (Nothing recomputed compiled to 16.32 GiB, the whole mixer
-    recomputed to 11.79: PERF.md section 4.)"""
+    chip's 15.75 GiB with only the expert share recomputed and nothing
+    rematerialised by the compiler, the state-space scan's kernels, the
+    flash kernel and megablox are in it as kernels, nothing fell back, no
+    loop is left under the scan's scope, and the expert share's way out
+    past its buffers' bound is a branch of its own whose scope is on
+    nothing else. (With the plain scan: nothing recomputed compiled to
+    16.32 GiB, the scan recomputed to 13.00, the whole mixer recomputed to
+    11.79; with the kernels, which keep the states every chunk inherits,
+    13.05: PERF.md section 4.)"""
     import json
     import warnings
 
@@ -266,11 +271,15 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     parameters = sum(int(np.prod(a.shape)) for a in
                      jax.tree_util.tree_leaves(state.params))
     assert parameters == config["parameters"] == 666_963_456
-    # one attention layer's forward and backward kernel, and megablox's
+    # one attention layer's forward and backward kernel, megablox's
     # three a product, two products a layer, forward, recomputed, backward,
-    # at both sizes of the share's buffers in expert order
+    # at both sizes of the share's buffers in expert order, and the scan's
+    # forward and backward kernel in each of the four state-space layers
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 2 + 4 * 8 * 2
+    assert text.count("tpu_custom_call") == 2 + 4 * 8 * 2 + 4 * 2
+    assert ".remat" not in text
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "hvd_ssm_scan" in line]
     # one conditional a layer each way, and no instruction outside their
     # second branches, the way out, reads as overflow
     entry = text[text.index("\nENTRY "):]
@@ -280,6 +289,88 @@ def test_state_space_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     # no buffer of every slot is left in expert order, and the taken
     # branch hands back no zeros for the other's residuals
     assert footprint / 2 ** 30 < 14.1  # 14.091 until PR 32 (PERF.md)
+
+
+@pytest.mark.parametrize("sizes", [
+    (2, 4096, 64, 64, 8, 128, 128), (1, 64, 4, 64, 2, 128, 16),
+    (1, 64, 8, 32, 1, 256, 32), (1, 32, 2, 128, 2, 128, 8, jnp.float32)],
+    ids=["the_cells", "one_tile_chunks", "four_heads_a_slab",
+         "a_slab_a_head"])
+def test_ssm_scan_kernels_compile_for_v5e(v5e, sizes):
+    """The state-space scan's forward and backward kernels
+    (``ops/ssm_scan.py``) compiled for the chip at the sizes of
+    ``nemotron-3-nano-30b-a3b-train-s4096`` (2 x 4096 positions, 64 heads
+    of 64 in 8 groups of 128 states, chunks of 128, bfloat16), at chunks
+    of one bfloat16 tile, at four heads to a slab with states two slabs
+    wide, and at a head a slab in float32 with chunks of one tile: what
+    interpret mode cannot see (the lane broadcasts, the transposes, the
+    kept copies' VMEM). One kernel each way and nothing else of a scan's
+    size: the arrays stay ``[B, S, H * P]``."""
+    batch, s, heads, p, groups, n, chunk = sizes[:7]
+    dtype = sizes[7] if len(sizes) > 7 else jnp.bfloat16
+    like = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    args = (like((batch, s, heads, p), dtype),
+            like((batch, s, groups, n), dtype),
+            like((batch, s, groups, n), dtype), like((batch, s, heads)),
+            like((heads,)), like((heads,)))
+    assert ssm_scan.supported(chunk, p, heads // groups, n, dtype)
+    scan = lambda *x: ssm_scan.ssm_scan(  # noqa: E731
+        *x, chunk, interpret=False)
+    text = jax.jit(scan).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    text = jax.jit(jax.grad(
+        lambda *x: jnp.sum(scan(*x).astype(jnp.float32)),
+        argnums=range(6))).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    # the step and its running sum are re-laid by group, 2 MB each at the
+    # cell's sizes; u, B, C, o and their gradients are never transposed
+    big = batch * s * groups * n
+    for transposed in re.findall(r"= \w+\[([\d,]+)\][^=]* transpose\(", text):
+        assert np.prod([int(i) for i in transposed.split(",")]) < big
+
+
+def test_state_space_layer_kernels_carry_the_scan_scope(v5e, monkeypatch):
+    """``value_and_grad`` of one ``StateSpaceMixer`` layer at a
+    kernel-sized shape, compiled for the chip: the two Mosaic calls are
+    the state-space scan's, forward and backward, both carry
+    ``hvd_ssm_scan`` in their ``op_name`` (what ``ssm_scan_pct`` and
+    ``ssm_scan_roofline_pct`` read, inside ``jvp(...)`` and
+    ``transpose(jvp(...))`` alike), and what is left of the scope around
+    them is the running sum of the step's log-decay, its re-layouts and
+    the sums that finish ``A``'s and ``D``'s gradients: no loop and no
+    recomputed forward."""
+    from horovod_tpu.models import ssm
+    from horovod_tpu.models.transformer import TransformerConfig
+
+    (device,) = v5e.device_set
+    monkeypatch.setattr(jax, "devices", lambda *a: [device])
+    mixer = ssm.StateSpaceMixer(TransformerConfig(
+        d_model=256, norm_eps=1e-5, ssm=ssm.StateSpaceConfig(
+            num_heads=4, head_dim=64, n_groups=2, state_size=128,
+            chunk_size=64)))
+    x = jax.ShapeDtypeStruct((2, 256, 256), jnp.bfloat16, sharding=v5e)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(lambda: mixer.init(
+            jax.random.PRNGKey(0), jnp.zeros(x.shape, x.dtype))["params"]))
+    text = jax.jit(jax.value_and_grad(lambda p, x: jnp.sum(mixer.apply(
+        {"params": p}, x).astype(jnp.float32)), argnums=(0, 1))).lower(
+            params, x).compile().as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    assert len(calls) == 2
+    forward, backward = sorted(calls, key=lambda n: "transpose(" in n)
+    assert forward.endswith(
+        "hvd_ssm_scan/jit(_forward)/ssm_scan_forward/pallas_call")
+    assert backward.endswith(
+        "hvd_ssm_scan/jit(_backward)/ssm_scan_backward/pallas_call")
+    assert "jvp(" in forward and "transpose(jvp(" in backward
+    scan = {n for n in re.findall(r'op_name="([^"]*)"', text)
+            if re.search(r"hvd_ssm_scan(?![\w.])", n)}
+    assert not any("while" in n or "checkpoint" in n or "remat" in n
+                   for n in scan)
 
 
 def _delta_arguments(v5e, batch, s, heads, d, dtype=jnp.bfloat16):
