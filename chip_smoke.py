@@ -79,11 +79,12 @@ HVDRUN = dict(model="resnet50", batch=32, image=224, warmup=1,
               batches_per_iter=3, iters=1, timeout=600)
 # kernel vs reference: the main path's head shapes [B, S, H, D] (a fifth
 # number: v and o that wide, q and k at D; latent attention's 192 / 128 at
-# the sequence of its benchmark cell); batch 2, or 1 at s4096, keeps the
-# plain-XLA reference's S x S scores (and their gradients) small beside the
-# kernel's operands
+# the sequence of its benchmark cell; a sixth: a sliding window, the 512
+# of laguna-xs.2-train-s8192's sliding layers at their sequence); batch 2,
+# or 1 at s4096, or 8 heads at s8192, keeps the plain-XLA reference's
+# S x S scores (and their gradients) small beside the kernel's operands
 KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64),
-                 (1, 4096, 32, 192, 128))
+                 (1, 4096, 32, 192, 128), (1, 8192, 8, 128, 128, 512))
 # bf16 operands, fp32 accumulation: a tensor agrees when its error is
 # under 2% of the reference in L2 and no element is off by more than
 # tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
@@ -320,7 +321,11 @@ def phase_flash_kernel():
     with CompileWatch() as watch:
         for shape in KERNEL_SHAPES:
             b, s, h, d = shape[:4]
-            d_v = shape[-1]
+            d_v = shape[4] if len(shape) > 4 else d
+            window = shape[5] if len(shape) > 5 else None
+            forward_blocks, backward_blocks = (
+                (fa.FORWARD_BLOCKS, fa.BACKWARD_BLOCKS) if window is None
+                else fa.WINDOW_BLOCKS)
             rng = np.random.default_rng(0)
             q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, width)),
                                    jnp.bfloat16) for width in (d, d, d_v))
@@ -330,7 +335,7 @@ def phase_flash_kernel():
             # constant of the executable, and four 30 MB constants push
             # the train steps out of a size-capped compile cache
             def kernel_loss(q, k, v, w):
-                out = fa.flash_attention(q, k, v, causal=True)
+                out = fa.flash_attention(q, k, v, causal=True, window=window)
                 return jnp.sum(out.astype(jnp.float32) * w), out
 
             def reference_loss(q, k, v, w):
@@ -339,7 +344,8 @@ def phase_flash_kernel():
 
                 out = fa._reference_attention(
                     to_bh(q), to_bh(k), to_bh(v),
-                    jnp.zeros((2,), jnp.int32), True, 1.0 / d ** 0.5)
+                    jnp.zeros((2,), jnp.int32), True, 1.0 / d ** 0.5,
+                    window)
                 out = out.reshape(b, h, s, d_v).transpose(0, 2, 1, 3)
                 return jnp.sum(out.astype(jnp.float32) * w), out
 
@@ -375,14 +381,14 @@ def phase_flash_kernel():
             # around its kernels, and how many block pairs of a
             # batch*head lie under the diagonal, on it, and are skipped
             forward = jax.jit(lambda q, k, v: fa.flash_attention(
-                q, k, v, causal=True))
+                q, k, v, causal=True, window=window))
             results.append({"shape": list(shape), "dtype": "bfloat16",
                             "tpu_custom_calls": kernels, "errors": errors,
                             "block_schedule": {
                                 "forward": fa.block_schedule(
-                                    s, s, *fa.FORWARD_BLOCKS),
+                                    s, s, *forward_blocks, window=window),
                                 "backward": fa.block_schedule(
-                                    s, s, *fa.BACKWARD_BLOCKS)},
+                                    s, s, *backward_blocks, window=window)},
                             "forward_ms": _ms_per_call(forward, (q, k, v)),
                             "forward_backward_ms": _ms_per_call(
                                 kernel, (q, k, v, w))})

@@ -16,6 +16,14 @@ of the TPU framework. Design:
   (``models/experts.py``). Either half may be ``None``: a layer of one
   sublayer, behind one norm and one residual. The default pattern is the
   block above.
+* More than one kind of multi-head attention in one model: every
+  ``AttentionConfig`` of ``TransformerConfig.attention`` is a mixer the
+  pattern calls by its ``kind``, with its own query and key/value heads,
+  head size, sliding window (in the flash kernel's block schedule and in
+  ``dense_attention`` alike), rotary embedding (theta, the width of a
+  head it turns, YaRN's blended frequencies and its factor on cos and
+  sin) and per-head sigmoid output gate. ``"mha"`` is the kind the
+  top-level fields size, and its parameter tree is what it always was.
 * ``sequence_axis``: when set (inside shard_map over that mesh axis), the
   sequence dimension is sharded across the axis and attention runs as
   **ring attention** (``horovod_tpu.parallel.ring``): K/V blocks rotate
@@ -34,11 +42,15 @@ of the TPU framework. Design:
 """
 
 import dataclasses
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from horovod_tpu.telemetry import scopes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,7 +92,10 @@ class TransformerConfig:
     moe_num_groups: int = 1
     moe_group_axis: Optional[str] = None
     # A layer is a pair (mixer, feed-forward), one pair a layer:
-    #   mixer         "mha" (Attention below) | "mla" (models/mla.py,
+    #   mixer         "mha" (Attention below, sized by the "mha only"
+    #                 fields) | the ``kind`` of an entry of ``attention``
+    #                 (Attention too, sized by that entry: its heads,
+    #                 window, rotary and gate) | "mla" (models/mla.py,
     #                 sized by ``mla``) | "ssm" (models/ssm.py, sized by
     #                 ``ssm``) | "kda" (models/kda.py, sized by ``kda``)
     #                 | None
@@ -98,6 +113,7 @@ class TransformerConfig:
     experts: Any = None  # models.experts.ExpertShareConfig
     ssm: Any = None      # models.ssm.StateSpaceConfig
     kda: Any = None      # models.kda.DeltaAttentionConfig
+    attention: tuple = ()  # of AttentionConfig, one a kind of "mha"
     # "mha" only. Key/value heads (None: one a query head); query head i
     # attends to key/value head i // (num_heads // num_kv_heads). The
     # width of a head (None: d_model // num_heads). Rotary position
@@ -106,6 +122,18 @@ class TransformerConfig:
     head_dim: Optional[int] = None
     rotary: bool = True
     norm_eps: float = 1e-6  # of every RMSNorm (flax's own default)
+
+    def attention_kind(self, mixer):
+        """The ``AttentionConfig`` the pattern's ``mixer`` names: "mha" is
+        the top-level fields', any other the entry of ``attention`` of
+        that ``kind``; ``None`` when it names no multi-head attention."""
+        if mixer == "mha":
+            return AttentionConfig(
+                kind="mha", num_heads=self.num_heads,
+                num_kv_heads=self.num_kv_heads,
+                head_dim=self.head_dim or self.d_model // self.num_heads,
+                rotary=self.rotary)
+        return next((a for a in self.attention if a.kind == mixer), None)
 
     def layers(self):
         """The (mixer, feed-forward) pair of every layer."""
@@ -121,17 +149,100 @@ class TransformerConfig:
             for i in range(self.num_layers))
 
 
-def _rotary(x, positions):
-    """Apply rotary position embedding. x: [B, S, H, D], positions: [B, S]."""
-    d = x.shape[-1]
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN (``rope_type yarn``, its fields named as ``config.json`` names
+    them): the rotary frequencies of a model trained to
+    ``original_max_position_embeddings`` positions blended, a frequency at
+    a time, between themselves (the pairs that turn more than
+    ``beta_fast`` times over the original length) and themselves over
+    ``factor`` (fewer than ``beta_slow`` times), and a factor on cos and
+    sin that stands in for a temperature on the scores."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None  # None: 0.1 ln(factor) + 1
+
+    def inv_freq(self, theta, width):
+        """The ``width // 2`` blended inverse frequencies (numpy: they are
+        constants of the program)."""
+        n = np.arange(width // 2, dtype=np.float64)
+        plain = theta ** (-2.0 * n / width)
+
+        def turns(t):  # the index of the pair that turns t times
+            original = self.original_max_position_embeddings
+            return (width * math.log(original / (2 * math.pi * t))
+                    / (2 * math.log(theta)))
+
+        low = max(math.floor(turns(self.beta_fast)), 0)
+        high = min(math.ceil(turns(self.beta_slow)), width - 1)
+        ramp = np.clip((n - low) / max(high - low, 1e-3), 0.0, 1.0)
+        return ((1.0 - ramp) * plain
+                + ramp * plain / self.factor).astype(np.float32)
+
+    def cos_sin_factor(self):
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionConfig:
+    """One kind of multi-head attention layer: what ``layer_pattern``
+    calls it, and everything two kinds of one model may differ in."""
+    kind: str
+    num_heads: int
+    head_dim: int
+    # key/value heads (None: one a query head); query head i attends to
+    # key/value head i // (num_heads // num_kv_heads)
+    num_kv_heads: Optional[int] = None
+    # a query sees itself and the window - 1 positions before it
+    window: Optional[int] = None
+    # rotary embedding (rotate-half) on the first ``rotary_dim`` elements
+    # of each head of q and k (None: the whole head), the rest passed
+    # through; ``yarn`` blends its frequencies
+    rotary: bool = True
+    rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    yarn: Optional[YarnScaling] = None
+    # head h's output times sigmoid(x Wg)[h], Wg [d_model, num_heads]
+    gate: bool = False
+
+    def rotate(self, x, positions):
+        if not self.rotary:
+            return x
+        width, yarn = self.rotary_dim or self.head_dim, self.yarn
+        return _rotary(x, positions, self.rope_theta, width,
+                       yarn and yarn.inv_freq(self.rope_theta, width),
+                       yarn and yarn.cos_sin_factor())
+
+
+def _rotary(x, positions, theta=10000.0, width=None, inv_freq=None,
+            factor=None):
+    """Apply rotary position embedding (rotate-half) to the first
+    ``width`` elements of every head (None: all of them) and pass the
+    rest through. ``inv_freq`` [width // 2]: scaled inverse frequencies in
+    place of ``theta^(-2n / width)``; ``factor`` multiplies cos and sin.
+    x: [B, S, H, D], positions: [B, S]."""
+    d = x.shape[-1] if width is None else width
     half = d // 2
-    freqs = 1.0 / (10000.0 ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, half]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                           axis=-1)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor is not None:
+        cos, sin = cos * factor, sin * factor
+    cos = cos[:, :, None, :].astype(x.dtype)
+    sin = sin[:, :, None, :].astype(x.dtype)
+    x1, x2 = x[..., :half], x[..., half:d]
+    turned = [x1 * cos - x2 * sin, x1 * sin + x2 * cos]
+    if d < x.shape[-1]:
+        turned.append(x[..., d:])
+    return jnp.concatenate(turned, axis=-1)
 
 
 def wants_flash(cfg, seq_len):
@@ -142,99 +253,140 @@ def wants_flash(cfg, seq_len):
     return jax.devices()[0].platform == "tpu" and seq_len >= 1024
 
 
-def dense_attention(q, k, v, *, causal, q_positions, kv_positions):
+def dense_attention(q, k, v, *, causal, q_positions, kv_positions,
+                    window=None):
     """Single-device attention: softmax(QK^T/sqrt(d)) V with causal mask by
-    absolute position (so it composes with sequence-sharded inputs)."""
+    absolute position (so it composes with sequence-sharded inputs), and
+    with a ``window`` only the position itself and the ``window - 1``
+    before it."""
     d = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     scores = scores.astype(jnp.float32) / (float(d) ** 0.5)
+    if window is not None and not causal:
+        raise ValueError("a window lies behind a causal diagonal")
     if causal:
-        mask = q_positions[:, None, :, None] >= kv_positions[:, None, None, :]
+        behind = (q_positions[:, None, :, None]
+                  - kv_positions[:, None, None, :])
+        mask = behind >= 0 if window is None else (
+            (behind >= 0) & (behind < window))
         scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
+    """``(softmax(q k^T / sqrt(d)) v under the mask of ``a``, an
+    ``AttentionConfig``, the new tokens' (k, v) for the cache or None)``
+    of q, k, v [B, S, H, D] after rotary and the key/value heads'
+    broadcast: the kernel, the ring or the dense path, as ``cfg`` and
+    the positions choose. A plain function, not a method of ``Attention``:
+    a module's method would put its name into every ``op_name`` under it."""
+    if cache is not None:
+        # incremental decode: attend over cached context ++ the new
+        # tokens, and hand the new tokens' (post-rotary) K/V back to
+        # the caller to write into its pool (serve/kvcache.py). Pad
+        # context slots carry a sentinel position larger than any
+        # real one, so the absolute-position causal mask hides them;
+        # masked scores are exactly -inf -> exactly-zero probs, so
+        # padding never perturbs the visible tokens' output. Always
+        # the dense path: decode q_len (1, or one prefill chunk)
+        # sits below the flash kernel's MXU block floor
+        # (ops/flash_attention.kernel_supported routes it out too).
+        ck, cv, ctx_positions = cache
+        k_all = jnp.concatenate([ck.astype(k.dtype), k], axis=1)
+        v_all = jnp.concatenate([cv.astype(v.dtype), v], axis=1)
+        kv_pos = jnp.concatenate([ctx_positions, positions], axis=1)
+        return dense_attention(q, k_all, v_all, causal=cfg.causal,
+                               q_positions=positions,
+                               kv_positions=kv_pos), (k, v)
+    use_flash = wants_flash(cfg, q.shape[1])
+    from horovod_tpu.ops import flash_attention as fa
+    if cfg.flash_attention and not contiguous_positions:
+        # the kernel masks by offset-contiguous positions; arbitrary
+        # user-supplied position arrays must use the dense path
+        fa.warn_fallback(
+            "models.transformer.Attention", q.shape, k.shape[1],
+            "explicit positions were passed and the kernel masks "
+            "by contiguous offset only")
+    if cfg.sequence_axis is not None:
+        from horovod_tpu.parallel import ring
+        if use_flash and contiguous_positions:
+            # Pallas kernel per rotated K/V block, lse-merged
+            out = ring.ring_attention(
+                q, k, v, axis_name=cfg.sequence_axis,
+                causal=cfg.causal, use_flash=True)
+        else:
+            out = ring.ring_attention(
+                q, k, v, axis_name=cfg.sequence_axis,
+                causal=cfg.causal, q_positions=positions,
+                kv_positions=positions)
+    elif use_flash and contiguous_positions:
+        out = fa.attention(q, k, v, causal=cfg.causal, window=a.window)
+    else:
+        out = dense_attention(q, k, v, causal=cfg.causal,
+                              q_positions=positions,
+                              kv_positions=positions, window=a.window)
+    return out, None
+
+
 class Attention(nn.Module):
+    """Multi-head attention of one kind (``cfg.attention_kind``): "mha",
+    sized by ``cfg``'s top-level fields, or an entry of
+    ``cfg.attention``."""
     cfg: TransformerConfig
+    kind: str = "mha"
 
     @nn.compact
     def __call__(self, x, positions, contiguous_positions=False,
                  cache=None):
-        cfg = self.cfg
-        h, h_kv = cfg.num_heads, cfg.num_kv_heads or cfg.num_heads
-        d = cfg.head_dim or cfg.d_model // h
+        cfg, a = self.cfg, self.cfg.attention_kind(self.kind)
+        h, h_kv, d = a.num_heads, a.num_kv_heads or a.num_heads, a.head_dim
         if h % h_kv:
             raise ValueError(f"{h} query heads do not split over {h_kv} "
                              f"key/value heads")
-        dense = lambda name, heads=h: nn.DenseGeneral(  # noqa: E731
-            (heads, d), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
-        q, k, v = dense("query")(x), dense("key", h_kv)(x), dense(
-            "value", h_kv)(x)
-        if cfg.rotary:
-            q, k = _rotary(q, positions), _rotary(k, positions)
-        if h_kv != h:
+        if a.window is not None:
             if cache is not None:
                 raise NotImplementedError(
-                    "the paged cache holds one key/value head a query head "
-                    "(serve/kvcache.py); grouped-query attention trains "
+                    "models.transformer.Attention: the paged cache keeps "
+                    "every position of every layer and frees none behind "
+                    "a window (serve/kvcache.py); a windowed layer trains "
                     "only")
-            # every kernel and path below takes one key/value head a query
-            # head: broadcast the shared heads, and the broadcast's
-            # transpose sums their query heads' gradients back
-            k, v = (jnp.repeat(a, h // h_kv, axis=2) for a in (k, v))
-        if cache is not None:
-            # incremental decode: attend over cached context ++ the new
-            # tokens, and hand the new tokens' (post-rotary) K/V back to
-            # the caller to write into its pool (serve/kvcache.py). Pad
-            # context slots carry a sentinel position larger than any
-            # real one, so the absolute-position causal mask hides them;
-            # masked scores are exactly -inf -> exactly-zero probs, so
-            # padding never perturbs the visible tokens' output. Always
-            # the dense path: decode q_len (1, or one prefill chunk)
-            # sits below the flash kernel's MXU block floor
-            # (ops/flash_attention.kernel_supported routes it out too).
-            ck, cv, ctx_positions = cache
-            k_all = jnp.concatenate([ck.astype(k.dtype), k], axis=1)
-            v_all = jnp.concatenate([cv.astype(v.dtype), v], axis=1)
-            kv_pos = jnp.concatenate([ctx_positions, positions], axis=1)
-            out = dense_attention(q, k_all, v_all, causal=cfg.causal,
-                                  q_positions=positions,
-                                  kv_positions=kv_pos)
+            if cfg.sequence_axis is not None:
+                raise NotImplementedError(
+                    "models.transformer.Attention: ring attention "
+                    "(parallel/ring.py) rotates every key/value block to "
+                    "every shard and has no window in its schedule; build "
+                    "a windowed layer with sequence_axis=None")
+        dense = lambda name, heads=h: nn.DenseGeneral(  # noqa: E731
+            (heads, d), axis=-1, dtype=cfg.dtype, use_bias=False, name=name)
+        with scopes.device(scopes.ATTN):
+            q, k, v = dense("query")(x), dense("key", h_kv)(x), dense(
+                "value", h_kv)(x)
+            q, k = a.rotate(q, positions), a.rotate(k, positions)
+            if h_kv != h:
+                if cache is not None:
+                    raise NotImplementedError(
+                        "the paged cache holds one key/value head a query "
+                        "head (serve/kvcache.py); grouped-query attention "
+                        "trains only")
+                # every kernel and path below takes one key/value head a
+                # query head: broadcast the shared heads, and the
+                # broadcast's transpose sums their query heads' gradients
+                # back
+                k, v = (jnp.repeat(t, h // h_kv, axis=2) for t in (k, v))
+        with scopes.device(scopes.ATTN_FULL if a.window is None
+                           else scopes.ATTN_WINDOW):
+            out, new_kv = attend(cfg, a, q, k, v, positions,
+                                 contiguous_positions, cache)
+        with scopes.device(scopes.ATTN):
+            if a.gate:
+                gate = nn.Dense(h, dtype=cfg.dtype, use_bias=False,
+                                name="gate")(x)
+                out = out * jax.nn.sigmoid(gate)[..., None]
             out = nn.DenseGeneral(cfg.d_model, axis=(-2, -1),
                                   dtype=cfg.dtype, use_bias=False,
                                   name="out")(out)
-            return out, (k, v)
-        use_flash = wants_flash(cfg, x.shape[1])
-        from horovod_tpu.ops import flash_attention as fa
-        if cfg.flash_attention and not contiguous_positions:
-            # the kernel masks by offset-contiguous positions; arbitrary
-            # user-supplied position arrays must use the dense path
-            fa.warn_fallback(
-                "models.transformer.Attention", q.shape, k.shape[1],
-                "explicit positions were passed and the kernel masks "
-                "by contiguous offset only")
-        if cfg.sequence_axis is not None:
-            from horovod_tpu.parallel import ring
-            if use_flash and contiguous_positions:
-                # Pallas kernel per rotated K/V block, lse-merged
-                out = ring.ring_attention(
-                    q, k, v, axis_name=cfg.sequence_axis,
-                    causal=cfg.causal, use_flash=True)
-            else:
-                out = ring.ring_attention(
-                    q, k, v, axis_name=cfg.sequence_axis,
-                    causal=cfg.causal, q_positions=positions,
-                    kv_positions=positions)
-        elif use_flash and contiguous_positions:
-            out = fa.attention(q, k, v, causal=cfg.causal)
-        else:
-            out = dense_attention(q, k, v, causal=cfg.causal,
-                                  q_positions=positions,
-                                  kv_positions=positions)
-        return nn.DenseGeneral(cfg.d_model, axis=(-2, -1), dtype=cfg.dtype,
-                               use_bias=False, name="out")(out)
-
+        return out if cache is None else (out, new_kv)
 
 class Block(nn.Module):
     cfg: TransformerConfig
@@ -250,8 +402,8 @@ class Block(nn.Module):
         new_kv = None
         if self.mixer is not None:
             y = norm()(x)
-            if self.mixer == "mha":
-                attention = Attention(cfg, name="attn")
+            if cfg.attention_kind(self.mixer) is not None:
+                attention = Attention(cfg, self.mixer, name="attn")
             elif self.mixer == "mla":
                 from horovod_tpu.models.mla import LatentAttention
                 attention = LatentAttention(cfg, name="attn")

@@ -29,6 +29,18 @@ sees an unchanged block index and issues no copy. Interior and diagonal
 pairs run ONE body, masked: the mask's iota, compare and select hide
 behind the matmuls, and a second unmasked body measured slower.
 
+A sliding window (``window=W``: a query at position i sees key j when
+``j <= i`` and ``i - j < W``, itself and the ``W - 1`` before it) is part
+of the same schedule. A pair wholly behind the window (its first query
+``W`` or more past its last key) is skipped like a pair above the
+diagonal, and the grid does not even visit it: the inner grid dimension of
+a windowed call is as long as the most blocks one outer block can see
+(two at 512 x 512 and a window of 512, whatever the sequence), its index
+maps start at the first block the window reaches (``_first_visible_kv``;
+``_last_visible_q`` in the backward) and clamp at the diagonal as before.
+A pair the window's lower edge crosses is masked by the same select as
+the diagonal's. ``window=None`` is the causal kernel as it was.
+
 Two head sizes: q and k are ``d_qk`` wide, v, the output, dO and dV
 ``d_v`` wide. Every caller but latent attention (models/mla.py: keys
 carry a 64-wide rotary part the values lack, 192 against 128) passes
@@ -89,6 +101,19 @@ BACKWARD_BLOCKS = (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K)
 # masked share of a 1024-wide diagonal block. The 4 MiB score tile and
 # its temporaries fit Mosaic's default scoped VMEM, fp32 operands too.
 FORWARD_BLOCKS = (1024, 1024)
+# With a window, (forward, backward), measured on v5e at b1 h64 s8192 d128
+# and a window of 512 (bf16; host clock over 20 calls of each kernel with
+# the [B, S, H, D] <-> [BH, S, D] copies around it, about 1.3 ms of the
+# forward's reading and 2.6 of the backward's; PERF.md PR 37). Forward /
+# backward ms at block_q x block_k: 512 x 512 4.65 / 5.60; 512 x 256
+# 5.42 / 6.72; 1024 x 512 5.38 / 7.56; 512 x 1024 5.67 / 7.07; 1024 x 1024
+# 6.07 / 8.48; 256 x 512 6.63 / 7.03; 256 x 256 6.76 / 7.92; 128 x 256
+# 9.62 / 10.19; 256 x 128 9.10 / 13.21; 128 x 128 12.67 / 13.98. At 512 x
+# 512 a q block runs two key blocks and half of their scores are live;
+# wider blocks compute more outside the band than they save in grid
+# steps, narrower ones starve the MXU and pay more steps (every pair is
+# crossed by an edge at all of these sizes but the last four).
+WINDOW_BLOCKS = ((512, 512), (512, 512))
 NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))  # dot_general: a @ b.T
 _TN = (((0,), (0,)), ((), ()))  # dot_general: a.T @ b
@@ -112,21 +137,29 @@ def warn_fallback(caller, q_shape, kv_len, reason):
         f"{kv_len} keys: {reason}", FlashFallbackWarning, stacklevel=3)
 
 
-def _block_kind(q_first, kv_first, block_q, block_k, causal):
+def _block_kind(q_first, kv_first, block_q, block_k, causal, window=None):
     """Where one (q-block, kv-block) pair lies against the causal
     diagonal, from the absolute positions of its first query and first
     key: ``(skipped, interior)``. *Skipped*: the block's last query comes
     before its first key, nothing is visible and nothing runs.
     *Interior*: the first query is at or after the last key, every score
     is visible and no mask is needed. Neither: the diagonal crosses the
-    block. Without ``causal`` every pair is interior. Plain comparisons,
+    block. Without ``causal`` every pair is interior. With a ``window`` a
+    pair is skipped too when its first query is ``window`` or more past
+    its last key (wholly behind the window), and interior only when its
+    last query is less than ``window`` past its first key; neither: an
+    edge, the diagonal or the window's, crosses it. Plain comparisons,
     so Python ints, numpy arrays (``block_schedule``) and the kernels'
-    traced int32 scalars all pass through it; the two index-map helpers
-    below are the same ``skipped`` inequality solved for a block index."""
+    traced int32 scalars all pass through it; the index-map helpers
+    below are the same ``skipped`` inequalities solved for a block
+    index."""
     if not causal:
         return False, True
     skipped = q_first + (block_q - 1) < kv_first
     interior = q_first >= kv_first + (block_k - 1)
+    if window is not None:
+        skipped = skipped | (q_first - (kv_first + (block_k - 1)) >= window)
+        interior = interior & (q_first + (block_q - 1) - kv_first < window)
     return skipped, interior
 
 
@@ -147,27 +180,80 @@ def _first_visible_q(j, off_ref, block_q, block_k, nq):
     return jax.lax.div(jnp.clip(reach, 0, nq * block_q - 1), block_q)
 
 
-def block_schedule(sq, skv, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                   q_offset=0, kv_offset=0, causal=True):
-    """How many block pairs of one batch*head lie wholly under the causal
-    diagonal, are crossed by it, and are skipped (nothing run, nothing
-    fetched): counts from the classification the kernels themselves use,
-    at the blocks they would fit. At 512 x 512
-    ``{"interior": 6, "diagonal": 4, "skipped": 6}`` at s2048 and
-    28 / 8 / 28 at s4096."""
-    bq, bk = _fit_block(sq, block_q), _fit_block(skv, block_k)
+def _first_visible_kv(i, off_ref, block_q, block_k, nkv, window):
+    """Index of the first kv block that q block ``i`` does not leave
+    wholly behind its window (``kv_last > q_first - window``), clamped
+    into the grid: where a windowed forward's inner grid dimension
+    starts."""
+    reach = off_ref[0] + i * block_q - (window - 1) - off_ref[1]
+    return jax.lax.div(jnp.clip(reach, 0, nkv * block_k - 1), block_k)
+
+
+def _last_visible_q(j, off_ref, block_q, block_k, nq, window):
+    """Index of the last q block that still has kv block ``j`` inside its
+    window (``q_first < kv_last + window``), clamped into the grid; the
+    windowed backward's q, dO, lse and delta index maps take
+    ``min(i, this)``."""
+    reach = off_ref[1] + (j + 1) * block_k - 1 + (window - 1) - off_ref[0]
+    return jax.lax.div(jnp.clip(reach, 0, nq * block_q - 1), block_q)
+
+
+def _kinds(sq, skv, bq, bk, q_offset, kv_offset, causal, window):
+    """``(skipped, interior)`` of every block pair, [sq // bq, skv // bk]
+    numpy booleans, from ``_block_kind``."""
     q_first = q_offset + np.arange(sq // bq)[:, None] * bq
     kv_first = kv_offset + np.arange(skv // bk)[None, :] * bk
-    skipped, interior = (
+    return tuple(
         np.broadcast_to(kind, (sq // bq, skv // bk))
-        for kind in _block_kind(q_first, kv_first, bq, bk, causal))
+        for kind in _block_kind(q_first, kv_first, bq, bk, causal, window))
+
+
+def block_schedule(sq, skv, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+                   q_offset=0, kv_offset=0, causal=True, window=None):
+    """How many block pairs of one batch*head lie wholly inside what the
+    mask leaves (under the causal diagonal, and with a ``window`` inside
+    it), are crossed by an edge (``diagonal``: the causal diagonal or the
+    window's lower edge), and are skipped (nothing run, nothing fetched):
+    counts from the classification the kernels themselves use, at the
+    blocks they would fit. At 512 x 512
+    ``{"interior": 6, "diagonal": 4, "skipped": 6}`` at s2048 and
+    28 / 8 / 28 at s4096; with a window of 512 at s8192 0 / 31 / 225 (a
+    q block runs the block on the diagonal and the one before it, and an
+    edge crosses both)."""
+    bq, bk = _fit_block(sq, block_q), _fit_block(skv, block_k)
+    skipped, interior = _kinds(sq, skv, bq, bk, q_offset, kv_offset, causal,
+                               window)
     return {"interior": int(interior.sum()),
             "diagonal": int((~skipped & ~interior).sum()),
             "skipped": int(skipped.sum())}
 
 
+def _window_steps(sq, skv, block_q, block_k, window, q_offset, kv_offset):
+    """``(kv blocks a q block visits, q blocks a kv block visits)``: the
+    inner grid dimensions of a windowed forward and backward at these
+    blocks. The blocks one outer block can see are adjacent, so with
+    offsets known here (Python ints) the count is read off the
+    classification; with traced offsets it is the most that a band
+    ``block + window - 1`` positions wide can touch at any alignment."""
+    nq, nkv = sq // block_q, skv // block_k
+    if isinstance(q_offset, int) and isinstance(kv_offset, int):
+        skipped, _ = _kinds(sq, skv, block_q, block_k, q_offset, kv_offset,
+                            True, window)
+        return (max(int((~skipped).sum(1).max()), 1),
+                max(int((~skipped).sum(0).max()), 1))
+    return (min(nkv, (block_q + window - 2) // block_k + 2),
+            min(nq, (block_k + window - 2) // block_q + 2))
+
+
+def _visible(q_pos, kv_pos, window):
+    """The mask of a block that runs: causal, and inside the window."""
+    if window is None:
+        return q_pos >= kv_pos
+    return (q_pos >= kv_pos) & (q_pos - kv_pos < window)
+
+
 def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
-            causal, sm_scale):
+            causal, sm_scale, window=None, nkv=None):
     """One (bh, q-block, kv-block) grid step. The score tile is built
     transposed (S^T = K Q^T, ``(block_k, block_q)``, as in the backward),
     so one query's statistics are one lane of a ``(1, block_q)`` row: the
@@ -177,15 +263,21 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
     ``(block_q, 1)`` column costs a cross-lane reduction and a lane
     broadcast each. Scratch (m, l, acc^T) carries the online-softmax
     state across the innermost kv dimension; ``rest`` is (lse, m, l, acc)
-    when the log-sum-exp rows are an output, else (m, l, acc)."""
+    when the log-sum-exp rows are an output, else (m, l, acc). With a
+    ``window`` the innermost dimension counts from the first kv block the
+    window reaches (of ``nkv``), not from block 0."""
     *lse_out, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(1)
-    j = pl.program_id(2)
-    nkv = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    j = step
+    if window is not None:
+        j = step + _first_visible_kv(i, off_ref, block_q, block_k, nkv,
+                                     window)
     q_first = off_ref[0] + i * block_q
     kv_first = off_ref[1] + j * block_k
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -208,7 +300,7 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
                 jnp.int32, (1, block_q), 1)
             kv_pos = kv_first + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, 1), 0)
-            s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
+            s = jnp.where(_visible(q_pos, kv_pos, window), s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
         # a query with nothing visible yet (m_new still NEG_INF)
         # subtracts 0 from its all-NEG_INF scores, so p = 0 and a
@@ -231,12 +323,15 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
         # Every block that runs is masked: a second, unmasked body for
         # interior blocks measured 0.5-1% SLOWER at all three benchmark
         # shapes (PERF.md, PR 28) — the mask hides behind the matmuls
-        skipped, _ = _block_kind(q_first, kv_first, block_q, block_k, causal)
+        skipped, _ = _block_kind(q_first, kv_first, block_q, block_k, causal,
+                                 window)
+        if window is not None:
+            skipped |= j >= nkv  # counted on past the last block
         pl.when(jnp.logical_not(skipped))(_update)
     else:
         _update()
 
-    @pl.when(j == nkv - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -249,19 +344,28 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
 
 
 def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
-                    interpret, with_lse=False):
+                    interpret, with_lse=False, window=None):
     """q: [BH, Sq, Dqk]; k: [BH, Skv, Dqk]; v: [BH, Skv, Dv]; offsets:
     int32[2] -> [BH, Sq, Dv] (plus the fp32 log-sum-exp of every query as
     lane-dense rows, [BH, 1, Sq], when ``with_lse``: what the backward's
     ``rowspec`` reads). The two head sizes are one for every caller but
-    latent attention, whose keys carry a rotary part the values lack."""
+    latent attention, whose keys carry a rotary part the values lack.
+    ``window``: ``None``, or ``(W, steps)``: the kv dimension of the grid
+    then has ``steps`` blocks (``_window_steps``), counted from the first
+    the window reaches."""
+    window, steps = window or (None, None)
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[2]
     nkv = skv // block_k
     kern = functools.partial(_kernel, block_q=block_q, block_k=block_k,
                              causal=causal, sm_scale=sm_scale)
+    if window is not None:
+        kern = functools.partial(kern, window=window, nkv=nkv)
 
     def kv_index(b, i, j, off_ref):
+        if window is not None:
+            j = j + _first_visible_kv(i, off_ref, block_q, block_k, nkv,
+                                      window)
         if causal:
             j = jnp.minimum(
                 j, _last_visible_kv(i, off_ref, block_q, block_k, nkv))
@@ -277,7 +381,7 @@ def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
                      jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(bh, sq // block_q, nkv),
+        grid=(bh, sq // block_q, nkv if window is None else steps),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j, *_: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
@@ -298,7 +402,7 @@ def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
     )(offsets, q, k, v)
 
 
-def _reference_attention(q, k, v, offsets, causal, sm_scale):
+def _reference_attention(q, k, v, offsets, causal, sm_scale, window=None):
     """Plain-XLA fp32 attention on [BH, S, D] — the backward-pass
     recompute target and the correctness oracle in tests. Matches the
     kernel's fully-masked-row-outputs-zero convention."""
@@ -307,7 +411,7 @@ def _reference_attention(q, k, v, offsets, causal, sm_scale):
     if causal:
         qp = offsets[0] + jnp.arange(q.shape[1])[:, None]
         kp = offsets[1] + jnp.arange(k.shape[1])[None, :]
-        mask = qp >= kp
+        mask = _visible(qp, kp, window)
         s = jnp.where(mask, s, NEG_INF)
         any_visible = jnp.any(mask, axis=-1)[None, :, None]
     else:
@@ -320,7 +424,7 @@ def _reference_attention(q, k, v, offsets, causal, sm_scale):
 
 def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_scratch,
-                block_q, block_k, causal, sm_scale):
+                block_q, block_k, causal, sm_scale, window=None, nq=None):
     """The whole backward: grid (bh, kv-block, q-block), q innermost.
     Each visible (kv, q) pair rebuilds its scores once, transposed
     (S^T = K Q^T, a (block_k, block_q) tile), so P^T and dS^T come out
@@ -329,20 +433,24 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     block-sized scratch for one kv-block; dQ for the WHOLE query range
     of this bh stays in VMEM across the two inner grid dimensions: in
     ``dq_ref`` itself when that is fp32, else in an fp32 scratch cast
-    into it on the last step."""
+    into it on the last step. With a ``window`` the innermost dimension
+    counts from the first q block (of ``nq``) that kv block ``j`` sees."""
     j = pl.program_id(1)
-    i = pl.program_id(2)
+    step = pl.program_id(2)
     nkv = pl.num_programs(1)
-    nq = pl.num_programs(2)
+    steps = pl.num_programs(2)
+    i = step
+    if window is not None:
+        i = step + _first_visible_q(j, off_ref, block_q, block_k, nq)
     q_off = off_ref[0]
     kv_off = off_ref[1]
     dq_acc = dq_scratch[0] if dq_scratch else dq_ref
 
-    @pl.when((j == 0) & (i == 0))
+    @pl.when((j == 0) & (step == 0))
     def _init_dq():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -364,7 +472,7 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                 jnp.int32, (1, block_q), 1))
             kv_pos = (kv_off + j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, 1), 0))
-            s = jnp.where(q_pos >= kv_pos, s, NEG_INF)
+            s = jnp.where(_visible(q_pos, kv_pos, window), s, NEG_INF)
         # p = exp(s - lse); rows with nothing visible have lse=NEG_INF
         p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
         dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
@@ -379,42 +487,47 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
     if causal:
         skipped, _ = _block_kind(q_off + i * block_q, kv_off + j * block_k,
-                                 block_q, block_k, causal)
+                                 block_q, block_k, causal, window)
+        if window is not None:
+            skipped |= i >= nq  # counted on past the last block
         pl.when(jnp.logical_not(skipped))(_update)
     else:
         _update()
 
-    @pl.when(i == nq - 1)
+    @pl.when(step == steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
     if dq_scratch:
-        @pl.when((j == nkv - 1) & (i == nq - 1))
+        @pl.when((j == nkv - 1) & (step == steps - 1))
         def _finalize_dq():
             dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_impl(q, k, v, g, out, lse, offsets, causal, sm_scale,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, window=None):
     """Fused flash backward from the forward's residuals; memory is
     O(S * block), never O(S^2)."""
     # delta_i = sum_d dO * O — the softmax-jacobian row correction
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)  # [BH, Sq]
     return _flash_bwd_core(q, k, v, g, lse[:, 0], delta, offsets, causal,
-                           sm_scale, block_q, block_k, interpret)
+                           sm_scale, block_q, block_k, interpret,
+                           window=window)
 
 
 def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
-                    block_q, block_k, interpret, out_dtype=None):
+                    block_q, block_k, interpret, out_dtype=None, window=None):
     """The one backward kernel launch, with (lse, delta) — fp32
     [BH, Sq] — supplied by the caller. Ring attention calls this per
     rotated K/V block with the globally-merged lse and the once-computed
     global delta — the per-block partials then sum to the exact
     global-softmax gradient (softmax over the union of blocks factorizes
     as p = exp(s - LSE)). ``out_dtype`` lets accumulating callers
-    request fp32 partials.
+    request fp32 partials. ``window``: ``None``, or ``(W, steps)``: the q
+    dimension of the grid then has ``steps`` blocks, counted from the
+    first that a kv block sees.
 
     The only quantity that grows with the sequence is the resident dQ:
     two output buffers of ``sq * d`` elements plus, unless dQ is fp32,
@@ -422,6 +535,7 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     32 MiB for ring attention's fp32 partials at 32k a chip and d128 —
     of the 128 MiB a v5e core has; past ``sq * d`` = 8 Mi elements the
     compile fails with Mosaic's out-of-VMEM message)."""
+    window, steps = window or (None, None)
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[2]
     # grads mirror their primal dtypes (custom_vjp aval contract) unless
@@ -434,7 +548,11 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     def q_block(j, i, off_ref):
         # a skipped step names the first q block this kv block sees: the
         # tiles already in VMEM, so the pipeline copies nothing for it
-        if causal:
+        if window is not None:
+            i = jnp.minimum(
+                i + _first_visible_q(j, off_ref, block_q, block_k, nq),
+                _last_visible_q(j, off_ref, block_q, block_k, nq, window))
+        elif causal:
             i = jnp.maximum(
                 i, _first_visible_q(j, off_ref, block_q, block_k, nq))
         return i
@@ -456,12 +574,15 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     if dq_dtype != jnp.float32:
         scratch.append(pltpu.VMEM((1, sq, d), jnp.float32))
         resident += sq * d * 4
+    kern = functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
+                             causal=causal, sm_scale=sm_scale)
+    if window is not None:
+        kern = functools.partial(kern, window=window, nq=nq)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, block_q=block_q, block_k=block_k,
-                          causal=causal, sm_scale=sm_scale),
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, skv // block_k, sq // block_q),
+            grid=(bh, skv // block_k, nq if window is None else steps),
             in_specs=[qspec, kspec, vspec, gspec, rowspec, rowspec],
             out_specs=(dqspec, kspec, vspec),
             scratch_shapes=scratch,
@@ -476,24 +597,29 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     )(offsets, q, k, v, g, lse[:, None, :], delta[:, None, :])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, offsets, causal, sm_scale, fwd_blocks, bwd_blocks,
-           interpret):
+           interpret, windows=(None, None)):
+    """``windows``: the forward's and the backward's ``(W, steps)``
+    (``_window_steps`` at each kernel's blocks), or ``None`` twice."""
     return _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, *fwd_blocks,
-                           interpret)
+                           interpret, window=windows[0])
 
 
 def _flash_fwd(q, k, v, offsets, causal, sm_scale, fwd_blocks, bwd_blocks,
-               interpret):
+               interpret, windows):
     out, lse = _flash_fwd_impl(q, k, v, offsets, causal, sm_scale,
-                               *fwd_blocks, interpret, with_lse=True)
+                               *fwd_blocks, interpret, with_lse=True,
+                               window=windows[0])
     return out, (q, k, v, offsets, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, fwd_blocks, bwd_blocks, interpret, res, g):
+def _flash_bwd(causal, sm_scale, fwd_blocks, bwd_blocks, interpret, windows,
+               res, g):
     q, k, v, offsets, out, lse = res
     dq, dk, dv = _flash_bwd_impl(q, k, v, g, out, lse, offsets, causal,
-                                 sm_scale, *bwd_blocks, interpret)
+                                 sm_scale, *bwd_blocks, interpret,
+                                 window=windows[1])
     return dq, dk, dv, None
 
 
@@ -589,22 +715,36 @@ def _from_bh(x, b):
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
-                    kv_offset=0, block_q=None, block_k=None, interpret=None):
+                    kv_offset=0, block_q=None, block_k=None, interpret=None,
+                    window=None):
     """Fused attention on [B, S, H, D] tensors (the transformer layout).
     ``block_q``/``block_k`` left unset give each kernel its own default
-    (``FORWARD_BLOCKS``, ``BACKWARD_BLOCKS``); a given size holds for both.
+    (``FORWARD_BLOCKS``, ``BACKWARD_BLOCKS``; with a ``window``,
+    ``WINDOW_BLOCKS``); a given size holds for both. ``window``: a query
+    sees itself and the ``window - 1`` positions before it (causal only).
 
     ``q_offset``/``kv_offset`` are the absolute positions of the first
     query/key token; ints or traced int32 scalars both work (they ride a
     scalar-prefetch argument), so a sequence-parallel shard can pass
     ``lax.axis_index(...) * s_local`` for a rotated K/V block."""
-    (b, _, _), sm_scale, interpret, fwd_blocks, bwd_blocks = _prep(
-        q, k, v, sm_scale, block_q, block_k, interpret, FORWARD_BLOCKS,
-        BACKWARD_BLOCKS)
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"flash_attention: a window ({window}) is a "
+                         f"positive number of positions behind a causal "
+                         f"diagonal (causal={causal})")
+    (b, sq, _), sm_scale, interpret, fwd_blocks, bwd_blocks = _prep(
+        q, k, v, sm_scale, block_q, block_k, interpret,
+        *((FORWARD_BLOCKS, BACKWARD_BLOCKS) if window is None
+          else WINDOW_BLOCKS))
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
+    windows = (None, None)
+    if window is not None:
+        steps = lambda blocks: _window_steps(  # noqa: E731
+            sq, k.shape[1], *blocks, window, q_offset, kv_offset)
+        windows = ((window, steps(fwd_blocks)[0]),
+                   (window, steps(bwd_blocks)[1]))
     out = _flash(_to_bh(q), _to_bh(k), _to_bh(v), offsets, causal, sm_scale,
-                 fwd_blocks, bwd_blocks, interpret)
+                 fwd_blocks, bwd_blocks, interpret, windows)
     return _from_bh(out, b)
 
 
@@ -660,7 +800,7 @@ def flash_attention_bwd_block(q, k, v, g, lse, delta, *, causal=True,
     return _from_bh(dq, b), _from_bh(dk, b), _from_bh(dv, b)
 
 
-def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0):
+def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0, window=None):
     """flash_attention, giving way to the plain-XLA path (with a
     :class:`FlashFallbackWarning`) when shapes don't tile onto the
     kernel blocks."""
@@ -668,10 +808,10 @@ def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0):
     skv = k.shape[1]
     if kernel_supported(sq, skv, d, d_v=v.shape[-1]):
         return flash_attention(q, k, v, causal=causal, q_offset=q_offset,
-                               kv_offset=kv_offset)
+                               kv_offset=kv_offset, window=window)
     warn_fallback("ops.flash_attention.attention", q.shape, skv,
                   "the shapes do not tile onto the kernel's blocks")
     offsets = jnp.asarray([q_offset, kv_offset], jnp.int32)
     out = _reference_attention(_to_bh(q), _to_bh(k), _to_bh(v), offsets,
-                               causal, 1.0 / (float(d) ** 0.5))
+                               causal, 1.0 / (float(d) ** 0.5), window)
     return _from_bh(out, b)

@@ -51,6 +51,17 @@ KDA = "hvd_kda"
 # products, the scan over chunks with the state; forward, recomputation
 # and backward
 KDA_SCAN = "hvd_kda_scan"
+# multi-head attention (models/transformer.Attention) outside its kernel:
+# the q, k, v projections, rotary, the key/value heads' broadcast and the
+# layout copies, the output gate, the output projection
+ATTN = "hvd_attn"
+# the attention itself, forward and backward: the flash kernel's calls (or
+# ring attention, or the plain-XLA path in its place) of a layer without a
+# window and of a layer with one. Each name ends in the module's own
+# ``attn``: the benchmark's accepted readers know the flash kernel as the
+# ``op_name`` that ends ``attn/pallas_call``
+ATTN_FULL = "hvd_attn_full/attn"
+ATTN_WINDOW = "hvd_attn_window/attn"
 # host spans
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding

@@ -1,6 +1,8 @@
 """Pallas flash-attention kernel vs the plain-XLA oracle (CPU runs the
 kernel in interpret mode; on TPU the same code compiles via Mosaic)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -628,3 +630,247 @@ def test_forward_and_lse_on_all_three_block_kinds(qo, ko, d_qk, d_v, causal,
                                atol=atol)
     np.testing.assert_array_equal(got[:, ~seen], 0.0)
     np.testing.assert_array_equal(got_lse[:, ~seen], np.float32(fa.NEG_INF))
+
+
+# ---------------------------------------------------------------- window
+
+def _hand_mask(sq, skv, qo, ko, window):
+    """[sq, skv]: query i sees key j when j <= i and i - j < window,
+    from positions written out."""
+    behind = (qo + np.arange(sq))[:, None] - (ko + np.arange(skv))[None, :]
+    return (behind >= 0) & (behind < window)
+
+
+def _hand_attention(q, k, v, mask):
+    """Attention under an element-wise mask, [B, S, H, D] in float32; a
+    row that sees nothing gives zeros."""
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / q.shape[-1] ** 0.5
+    probs = jax.nn.softmax(jnp.where(mask, scores, fa.NEG_INF), -1)
+    probs = jnp.where(mask.any(-1)[:, None], probs, 0.0)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+# a window smaller than a block, equal to one, not a multiple of one,
+# spanning several, with blocks that differ, and at least the sequence
+WINDOWS = [(256, 24, 64, 64), (256, 64, 64, 64), (256, 100, 64, 64),
+           (256, 160, 32, 32), (256, 48, 64, 32), (256, 48, 32, 64),
+           (256, 256, 64, 64), (256, 1000, 64, 64)]
+
+
+@pytest.mark.parametrize("s,window,bq,bk", WINDOWS, ids=lambda x: str(x))
+def test_windowed_kernel_matches_the_hand_built_mask(s, window, bq, bk):
+    """Output, ``dq``, ``dk`` and ``dv`` of the windowed kernels
+    (interpret mode) against attention under a mask built by hand from
+    positions, and against ``_reference_attention`` given the same
+    window."""
+    rng = np.random.default_rng(window + bq)
+    q, k, v = _qkv(rng, b=1, s=s, h=2, d=16)
+    weight = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    mask = jnp.asarray(_hand_mask(s, s, 0, 0, window))
+
+    def kernel(q, k, v):
+        return fa.flash_attention(q, k, v, window=window, block_q=bq,
+                                  block_k=bk)
+
+    got = kernel(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_hand_attention(q, k, v, mask)),
+        atol=2e-5)
+    ref = fa._reference_attention(
+        _to_bh(q), _to_bh(k), _to_bh(v), jnp.zeros(2, jnp.int32), True,
+        0.25, window)
+    np.testing.assert_allclose(np.asarray(_to_bh(got)), np.asarray(ref),
+                               atol=2e-5)
+    grads = jax.grad(lambda *a: jnp.sum(kernel(*a) * weight),
+                     argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(
+        _hand_attention(*a, mask) * weight), argnums=(0, 1, 2))(q, k, v)
+    for name, g, w in zip(("dq", "dk", "dv"), grads, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("qo,ko", [(128, 0), (64, 32), (0, 64), (512, 0)],
+                         ids=["q-later", "both", "kv-later", "behind"])
+def test_windowed_kernel_with_offsets(qo, ko, traced):
+    """``q_offset`` / ``kv_offset`` with a window, as Python ints (the
+    inner grid dimension read off the classification) and traced (its
+    bound at any alignment): queries later than the keys, both shifted
+    off the blocks, rows that see nothing, and every key behind the
+    window (zeros out, zero gradients)."""
+    s, window = 128, 40
+    rng = np.random.default_rng(qo + ko)
+    q, k, v = _qkv(rng, b=1, s=s, h=2, d=16)
+    weight = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    mask = jnp.asarray(_hand_mask(s, s, qo, ko, window))
+
+    def loss(q, k, v, qo, ko):
+        out = fa.flash_attention(q, k, v, window=window, q_offset=qo,
+                                 kv_offset=ko, block_q=32, block_k=32)
+        return jnp.sum(out * weight), out
+
+    f = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+    if traced:
+        (_, out), grads = jax.jit(f)(q, k, v, jnp.int32(qo), jnp.int32(ko))
+    else:
+        (_, out), grads = f(q, k, v, qo, ko)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_hand_attention(q, k, v, mask)),
+        atol=2e-5)
+    want = jax.grad(lambda *a: jnp.sum(
+        _hand_attention(*a, mask) * weight), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(grads, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
+    if (qo, ko) == (512, 0):
+        assert not np.asarray(out).any() and not mask.any()
+
+
+def test_a_window_of_the_whole_sequence_is_the_causal_kernel():
+    """A window at least the sequence masks nothing more than the
+    diagonal: the same output and gradients as ``window=None`` at the
+    same blocks, and ``block_schedule`` equal to today's."""
+    q, k, v = _qkv(np.random.default_rng(5), b=1, s=256, h=2, d=16)
+    f = lambda window: jax.value_and_grad(  # noqa: E731
+        lambda *a: jnp.sum(fa.flash_attention(
+            *a, window=window, block_q=64, block_k=64) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    (plain, plain_grads), (wide, wide_grads) = f(None), f(256)
+    np.testing.assert_allclose(float(wide), float(plain), rtol=1e-6)
+    for g, w in zip(wide_grads, plain_grads):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-5)
+    for window in (256, 300, 10 ** 6):
+        assert fa.block_schedule(256, 256, 64, 64, window=window) == (
+            fa.block_schedule(256, 256, 64, 64))
+
+
+def test_window_none_is_the_kernel_it_was(monkeypatch):
+    """``window=None`` traces the program the causal kernel always
+    traced: the same jaxpr as with every windowed branch made
+    unreachable (the helpers raising), the full-length grid, and the
+    output and gradients bit for bit those of the default call."""
+    q, k, v = _qkv(np.random.default_rng(6), b=1, s=256, h=2, d=16)
+
+    def program(**kw):
+        return jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(fa.flash_attention(
+                *a, block_q=64, block_k=64, **kw)), argnums=(0, 1, 2)))(
+                    q, k, v)
+
+    with_argument = str(program(window=None))
+    assert with_argument == str(program())
+
+    def unreachable(*a, **kw):
+        raise AssertionError("a windowed branch ran without a window")
+
+    for name in ("_first_visible_kv", "_last_visible_q", "_window_steps"):
+        monkeypatch.setattr(fa, name, unreachable)
+    assert str(program(window=None)) == with_argument
+    assert re.findall(r"grid=\(([\d, ]+)\)", with_argument) == [
+        "2, 4, 4", "2, 4, 4"]
+    f = lambda **kw: jax.value_and_grad(  # noqa: E731
+        lambda *a: jnp.sum(fa.flash_attention(*a, **kw) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree_util.tree_leaves(f()),
+                    jax.tree_util.tree_leaves(f(window=None))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _brute_force_window_schedule(sq, skv, bq, bk, qo, ko, window):
+    blocks = _hand_mask(sq, skv, qo, ko, window).reshape(
+        sq // bq, bq, skv // bk, bk)
+    some, every = blocks.any(axis=(1, 3)), blocks.all(axis=(1, 3))
+    return {"interior": int(every.sum()),
+            "diagonal": int((some & ~every).sum()),
+            "skipped": int((~some).sum())}, some
+
+
+@pytest.mark.parametrize("sq,skv,bq,bk,qo,ko,window,want,steps", [
+    # the sliding layers of laguna-xs.2-train-s8192 at the blocks measured
+    (8192, 8192, 512, 512, 0, 0, 512, (0, 31, 225), (2, 2)),
+    (8192, 8192, 1024, 1024, 0, 0, 512, (0, 15, 49), (2, 2)),
+    (8192, 8192, 512, 256, 0, 0, 512, (0, 62, 450), (4, 2)),
+    (8192, 8192, 256, 512, 0, 0, 512, (0, 62, 450), (2, 4)),
+    (8192, 8192, 256, 256, 0, 0, 512, (31, 62, 931), (3, 3)),
+    (8192, 8192, 128, 128, 0, 0, 512, (186, 124, 3786), (5, 5)),
+    (512, 512, 128, 128, 0, 0, 128, None, None),
+    (512, 512, 128, 128, 0, 0, 129, None, None),
+    (512, 512, 128, 128, 0, 0, 200, None, None),
+    (512, 512, 128, 128, 256, 0, 100, None, None),
+    (384, 256, 128, 128, 64, 192, 150, None, None),
+    (256, 512, 64, 128, 100, 37, 90, None, None),
+], ids=lambda x: None if isinstance(x, tuple) else str(x))
+def test_block_schedule_with_a_window(sq, skv, bq, bk, qo, ko, window, want,
+                                      steps):
+    """``block_schedule`` with a window against a brute-force count over
+    the hand-built mask; the pairs a block runs are adjacent, the four
+    index-map helpers name their ends, and ``_window_steps`` is the most
+    any block runs (with traced offsets: a bound on it)."""
+    got = fa.block_schedule(sq, skv, bq, bk, qo, ko, window=window)
+    brute, some = _brute_force_window_schedule(sq, skv, bq, bk, qo, ko,
+                                               window)
+    assert got == brute
+    if want is not None:
+        assert (got["interior"], got["diagonal"], got["skipped"]) == want
+    nq, nkv = sq // bq, skv // bk
+    off = np.asarray([qo, ko], np.int32)
+    for i in range(nq):
+        run = np.flatnonzero(some[i])
+        if run.size:
+            assert (run == np.arange(run[0], run[-1] + 1)).all()
+            assert int(fa._first_visible_kv(i, off, bq, bk, nkv,
+                                            window)) == run[0]
+            assert int(fa._last_visible_kv(i, off, bq, bk, nkv)) == run[-1]
+    for j in range(nkv):
+        run = np.flatnonzero(some[:, j])
+        if run.size:
+            assert int(fa._first_visible_q(j, off, bq, bk, nq)) == run[0]
+            assert int(fa._last_visible_q(j, off, bq, bk, nq,
+                                          window)) == run[-1]
+    exact = fa._window_steps(sq, skv, bq, bk, window, qo, ko)
+    assert exact == (max(some.sum(1).max(), 1), max(some.sum(0).max(), 1))
+    if steps is not None:
+        assert exact == steps
+    bound = fa._window_steps(sq, skv, bq, bk, window, jnp.int32(qo),
+                             jnp.int32(ko))
+    assert exact[0] <= bound[0] <= nkv and exact[1] <= bound[1] <= nq
+
+
+def test_windowed_grid_visits_only_what_the_window_reaches():
+    """Pairs behind the window are skipped, not masked: the windowed
+    kernels' inner grid dimension is ``_window_steps`` long, whatever the
+    sequence, where the causal kernels' is the whole row of blocks."""
+    q, k, v = _qkv(np.random.default_rng(7), b=1, s=512, h=1, d=16)
+    grids = lambda **kw: re.findall(r"grid=\(([\d, ]+)\)", str(  # noqa: E731
+        jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            *a, block_q=64, block_k=64, **kw)), argnums=(0, 1, 2)))(
+                q, k, v)))
+    assert grids() == ["1, 8, 8", "1, 8, 8"]
+    assert grids(window=64) == ["1, 8, 2", "1, 8, 2"]
+    assert grids(window=100) == ["1, 8, 3", "1, 8, 3"]
+
+
+def test_window_fallback_and_refusals():
+    """``attention`` hands the window to the plain-XLA path when the
+    shapes do not tile; a window without a causal diagonal, or of no
+    positions, is refused; ``dense_attention`` takes the same window."""
+    q, k, v = _qkv(np.random.default_rng(8), b=1, s=1048, h=1, d=16)
+    mask = jnp.asarray(_hand_mask(1048, 1048, 0, 0, 100))
+    with pytest.warns(fa.FlashFallbackWarning):
+        got = fa.attention(q, k, v, window=100)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_hand_attention(q, k, v, mask)),
+        atol=2e-5)
+    pos = jnp.arange(1048)[None]
+    np.testing.assert_allclose(
+        np.asarray(dense_attention(q, k, v, causal=True, q_positions=pos,
+                                   kv_positions=pos, window=100)),
+        np.asarray(got), atol=2e-5)
+    with pytest.raises(ValueError, match="behind a causal diagonal"):
+        fa.flash_attention(q[:, :256], k[:, :256], v[:, :256], causal=False,
+                           window=64)
+    with pytest.raises(ValueError, match="positive number"):
+        fa.flash_attention(q[:, :256], k[:, :256], v[:, :256], window=0)
+    with pytest.raises(ValueError, match="causal"):
+        dense_attention(q, k, v, causal=False, q_positions=pos,
+                        kv_positions=pos, window=100)

@@ -476,3 +476,55 @@ def test_delta_scopes_are_on_the_delta_mixer_and_split_it():
                 and n.endswith("dot_general")]
     assert products and all(n in scan or n in rest for n in products)
     assert any("block_1/attn/hvd_mla" in n for n in names)
+
+
+def test_attention_scopes_split_each_kind_of_layer():
+    """``hvd_attn`` is on a multi-head attention layer outside its
+    attention (the projections, rotary, the key/value heads' broadcast,
+    the gate, the output projection), ``hvd_attn_full`` and
+    ``hvd_attn_window`` on the attention itself of a layer without and
+    with a window (the plain-XLA path here, the flash kernel on the
+    chip), every product of an attention layer is under exactly one of
+    the three, nothing outside one carries any, and the two kernel scopes
+    end in the module's own name, so that a kernel under them is still
+    the ``op_name`` that ends ``attn/pallas_call``."""
+    from horovod_tpu.models.transformer import AttentionConfig
+
+    assert scopes.ATTN_FULL.endswith("/attn")
+    assert scopes.ATTN_WINDOW.endswith("/attn")
+    kinds = (AttentionConfig(kind="full", num_heads=2, head_dim=8,
+                             num_kv_heads=1, rotary_dim=4, gate=True),
+             AttentionConfig(kind="sliding", num_heads=4, head_dim=8,
+                             num_kv_heads=1, window=4, gate=True))
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=3, num_heads=2, d_model=16, d_ff=32,
+        dtype=jnp.float32, flash_attention=False, attention=kinds,
+        layer_pattern=(("full", "swiglu"), ("sliding", "swiglu"),
+                       ("mha", "swiglu")))
+    model = Transformer(cfg)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)[
+        "params"]
+    text = jax.jit(jax.grad(lambda p: jnp.sum(model.apply(
+        {"params": p}, tokens)))).lower(params).compile().as_text()
+    names = _OP_NAME_RE.findall(text)
+    under = lambda scope: {n for n in names if re.search(  # noqa: E731
+        r"(?<![\w.])" + scope + r"(?![\w.])", n)}
+    rest, full, window = (under("hvd_attn"), under("hvd_attn_full"),
+                          under("hvd_attn_window"))
+    assert rest and full and window
+    assert not rest & full and not rest & window and not full & window
+    assert all(re.search(r"block_\d/attn/", n) for n in rest | full | window)
+    # the layer without a window, named or plain "mha", is a full one
+    assert {re.search(r"block_\d", n).group() for n in full} == {
+        "block_0", "block_2"}
+    assert {re.search(r"block_\d", n).group() for n in window} == {
+        "block_1"}
+    for part in ("query", "key", "value", "gate", "out"):
+        assert any(f"hvd_attn/{part}/" in n for n in rest), part
+    assert not any("query" in n or "gate" in n for n in full | window)
+    products = [n for n in names if re.search(r"block_\d/attn/", n)
+                and n.endswith("dot_general")]
+    assert products and all(
+        n in rest or n in full or n in window for n in products)
+    assert any("transpose(jvp(" in n for n in window)
