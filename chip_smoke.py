@@ -80,11 +80,16 @@ HVDRUN = dict(model="resnet50", batch=32, image=224, warmup=1,
 # kernel vs reference: the main path's head shapes [B, S, H, D] (a fifth
 # number: v and o that wide, q and k at D; latent attention's 192 / 128 at
 # the sequence of its benchmark cell; a sixth: a sliding window, the 512
-# of laguna-xs.2-train-s8192's sliding layers at their sequence); batch 2,
-# or 1 at s4096, or 8 heads at s8192, keeps the plain-XLA reference's
-# S x S scores (and their gradients) small beside the kernel's operands
+# of laguna-xs.2-train-s8192's sliding layers at their sequence, or None;
+# a seventh: key/value heads that the query heads share, read in place by
+# the kernels, laguna's sliding group of 8 and its full group of 6);
+# batch 2, or 1 at s4096, or 8 heads at s8192, keeps the plain-XLA
+# reference's S x S scores (and their gradients) small beside the
+# kernel's operands
 KERNEL_SHAPES = ((2, 2048, 16, 128), (2, 2048, 12, 64),
-                 (1, 4096, 32, 192, 128), (1, 8192, 8, 128, 128, 512))
+                 (1, 4096, 32, 192, 128), (1, 8192, 8, 128, 128, 512),
+                 (1, 8192, 8, 128, 128, 512, 1),
+                 (1, 4096, 12, 128, 128, None, 2))
 # bf16 operands, fp32 accumulation: a tensor agrees when its error is
 # under 2% of the reference in L2 and no element is off by more than
 # tests/test_flash_attention.py allows bf16 (5e-2 forward, 8e-2 grads)
@@ -323,13 +328,16 @@ def phase_flash_kernel():
             b, s, h, d = shape[:4]
             d_v = shape[4] if len(shape) > 4 else d
             window = shape[5] if len(shape) > 5 else None
+            h_kv = shape[6] if len(shape) > 6 else h
             forward_blocks, backward_blocks = (
                 (fa.FORWARD_BLOCKS, fa.BACKWARD_BLOCKS) if window is None
                 else fa.WINDOW_BLOCKS)
             rng = np.random.default_rng(0)
-            q, k, v = (jnp.asarray(rng.standard_normal((b, s, h, width)),
-                                   jnp.bfloat16) for width in (d, d, d_v))
-            w = jnp.asarray(rng.standard_normal(v.shape), jnp.float32)
+            q, k, v = (jnp.asarray(rng.standard_normal((b, s, heads, width)),
+                                   jnp.bfloat16)
+                       for heads, width in ((h, d), (h_kv, d), (h_kv, d_v)))
+            w = jnp.asarray(rng.standard_normal(q.shape[:3] + (d_v,)),
+                            jnp.float32)
 
             # w is an argument, not a closure: a captured array becomes a
             # constant of the executable, and four 30 MB constants push
@@ -342,6 +350,7 @@ def phase_flash_kernel():
                 def to_bh(x):
                     return x.transpose(0, 2, 1, 3).reshape(b * h, s, -1)
 
+                k, v = fa.per_query_head(q, k, v)
                 out = fa._reference_attention(
                     to_bh(q), to_bh(k), to_bh(v),
                     jnp.zeros((2,), jnp.int32), True, 1.0 / d ** 0.5,

@@ -277,10 +277,15 @@ def dense_attention(q, k, v, *, causal, q_positions, kv_positions,
 def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
     """``(softmax(q k^T / sqrt(d)) v under the mask of ``a``, an
     ``AttentionConfig``, the new tokens' (k, v) for the cache or None)``
-    of q, k, v [B, S, H, D] after rotary and the key/value heads'
-    broadcast: the kernel, the ring or the dense path, as ``cfg`` and
-    the positions choose. A plain function, not a method of ``Attention``:
-    a module's method would put its name into every ``op_name`` under it."""
+    of q [B, S, H, D] and k, v [B, S, H_kv, D] after rotary, ``H_kv`` any
+    number of heads that divides ``H`` (query head ``h`` sees key/value
+    head ``h // (H / H_kv)``): the kernel, the ring or the dense path, as
+    ``cfg`` and the positions choose. The flash kernel reads shared heads
+    in place; the other paths take one key/value head a query head and
+    get the heads repeated (``fa.per_query_head``). A plain function, not
+    a method of ``Attention``: a module's method would put its name into
+    every ``op_name`` under it."""
+    from horovod_tpu.ops import flash_attention as fa
     if cache is not None:
         # incremental decode: attend over cached context ++ the new
         # tokens, and hand the new tokens' (post-rotary) K/V back to
@@ -293,6 +298,7 @@ def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
         # sits below the flash kernel's MXU block floor
         # (ops/flash_attention.kernel_supported routes it out too).
         ck, cv, ctx_positions = cache
+        k, v = fa.per_query_head(q, k, v)
         k_all = jnp.concatenate([ck.astype(k.dtype), k], axis=1)
         v_all = jnp.concatenate([cv.astype(v.dtype), v], axis=1)
         kv_pos = jnp.concatenate([ctx_positions, positions], axis=1)
@@ -300,7 +306,6 @@ def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
                                q_positions=positions,
                                kv_positions=kv_pos), (k, v)
     use_flash = wants_flash(cfg, q.shape[1])
-    from horovod_tpu.ops import flash_attention as fa
     if cfg.flash_attention and not contiguous_positions:
         # the kernel masks by offset-contiguous positions; arbitrary
         # user-supplied position arrays must use the dense path
@@ -310,6 +315,7 @@ def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
             "by contiguous offset only")
     if cfg.sequence_axis is not None:
         from horovod_tpu.parallel import ring
+        k, v = fa.per_query_head(q, k, v)
         if use_flash and contiguous_positions:
             # Pallas kernel per rotated K/V block, lse-merged
             out = ring.ring_attention(
@@ -323,8 +329,8 @@ def attend(cfg, a, q, k, v, positions, contiguous_positions, cache=None):
     elif use_flash and contiguous_positions:
         out = fa.attention(q, k, v, causal=cfg.causal, window=a.window)
     else:
-        out = dense_attention(q, k, v, causal=cfg.causal,
-                              q_positions=positions,
+        out = dense_attention(q, *fa.per_query_head(q, k, v),
+                              causal=cfg.causal, q_positions=positions,
                               kv_positions=positions, window=a.window)
     return out, None
 
@@ -363,17 +369,11 @@ class Attention(nn.Module):
             q, k, v = dense("query")(x), dense("key", h_kv)(x), dense(
                 "value", h_kv)(x)
             q, k = a.rotate(q, positions), a.rotate(k, positions)
-            if h_kv != h:
-                if cache is not None:
-                    raise NotImplementedError(
-                        "the paged cache holds one key/value head a query "
-                        "head (serve/kvcache.py); grouped-query attention "
-                        "trains only")
-                # every kernel and path below takes one key/value head a
-                # query head: broadcast the shared heads, and the
-                # broadcast's transpose sums their query heads' gradients
-                # back
-                k, v = (jnp.repeat(t, h // h_kv, axis=2) for t in (k, v))
+            if h_kv != h and cache is not None:
+                raise NotImplementedError(
+                    "the paged cache holds one key/value head a query "
+                    "head (serve/kvcache.py); grouped-query attention "
+                    "trains only")
         with scopes.device(scopes.ATTN_FULL if a.window is None
                            else scopes.ATTN_WINDOW):
             out, new_kv = attend(cfg, a, q, k, v, positions,

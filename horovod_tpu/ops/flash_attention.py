@@ -46,6 +46,16 @@ Two head sizes: q and k are ``d_qk`` wide, v, the output, dO and dV
 carry a 64-wide rotary part the values lack, 192 against 128) passes
 one size for both, and the kernels are the same program then.
 
+Shared key/value heads (grouped-query attention): k and v may hold
+``H / g`` heads. The forward's k and v index maps read row ``bh // g``
+(``_kv_head``) and nothing else changes; the backward runs the ``g``
+query heads of a key/value head one after another (grid (bh_kv, member,
+kv-block, q-block), ``_grouped_bwd``) and sums their dK and dV in
+sequence-sized fp32 scratch, written once a key/value head. Nothing is
+broadcast to the query heads or summed back outside the kernels. At
+``g == 1`` (every caller but grouped-query attention) neither path is
+traced: the kernels are the program they were.
+
 Sequence-parallel composition: ``q_offset``/``kv_offset`` give the
 absolute position of the first query/key token. They ride a
 scalar-prefetch argument (SMEM), so traced values — e.g. derived from
@@ -78,6 +88,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from horovod_tpu.telemetry import startup as _startup
 
 # Block sizes, measured on v5e (bf16 operands, causal; each kernel alone,
 # host clock over 20 calls, which reads 0.3-0.5 ms over the kernel's
@@ -196,6 +208,21 @@ def _last_visible_q(j, off_ref, block_q, block_k, nq, window):
     ``min(i, this)``."""
     reach = off_ref[1] + (j + 1) * block_k - 1 + (window - 1) - off_ref[0]
     return jax.lax.div(jnp.clip(reach, 0, nq * block_q - 1), block_q)
+
+
+def _kv_head(bh, group):
+    """The key/value row of query row ``bh`` where ``group`` query heads
+    share each key/value head: with ``H = group * H_kv``, ``(b * H + h) //
+    group`` is ``b * H_kv + h // group``, so ``_to_bh``'s [B * H, ...] and
+    [B * H_kv, ...] line up as they are. A grouped call's index maps
+    alone reach it."""
+    return jax.lax.div(bh, group)
+
+
+def _block_rows(j, block):
+    """The rows of block ``j`` in a sequence-sized VMEM buffer: a grouped
+    backward's dK and dV."""
+    return pl.ds(pl.multiple_of(j * block, block), block)
 
 
 def _kinds(sq, skv, bq, bk, q_offset, kv_offset, causal, window):
@@ -345,17 +372,20 @@ def _kernel(off_ref, q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
 
 def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
                     interpret, with_lse=False, window=None):
-    """q: [BH, Sq, Dqk]; k: [BH, Skv, Dqk]; v: [BH, Skv, Dv]; offsets:
-    int32[2] -> [BH, Sq, Dv] (plus the fp32 log-sum-exp of every query as
-    lane-dense rows, [BH, 1, Sq], when ``with_lse``: what the backward's
-    ``rowspec`` reads). The two head sizes are one for every caller but
-    latent attention, whose keys carry a rotary part the values lack.
-    ``window``: ``None``, or ``(W, steps)``: the kv dimension of the grid
-    then has ``steps`` blocks (``_window_steps``), counted from the first
-    the window reaches."""
+    """q: [BH, Sq, Dqk]; k: [BH_kv, Skv, Dqk]; v: [BH_kv, Skv, Dv];
+    offsets: int32[2] -> [BH, Sq, Dv] (plus the fp32 log-sum-exp of every
+    query as lane-dense rows, [BH, 1, Sq], when ``with_lse``: what the
+    backward's ``rowspec`` reads). The two head sizes are one for every
+    caller but latent attention, whose keys carry a rotary part the values
+    lack. ``BH`` is ``g`` times ``BH_kv``: query row ``bh`` reads
+    key/value row ``bh // g`` (``_kv_head``), and ``g`` is 1 for every
+    caller but grouped-query attention. ``window``: ``None``, or ``(W,
+    steps)``: the kv dimension of the grid then has ``steps`` blocks
+    (``_window_steps``), counted from the first the window reaches."""
     window, steps = window or (None, None)
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     nkv = skv // block_k
     kern = functools.partial(_kernel, block_q=block_q, block_k=block_k,
                              causal=causal, sm_scale=sm_scale)
@@ -369,7 +399,7 @@ def _flash_fwd_impl(q, k, v, offsets, causal, sm_scale, block_q, block_k,
         if causal:
             j = jnp.minimum(
                 j, _last_visible_kv(i, off_ref, block_q, block_k, nkv))
-        return b, j, 0
+        return (b if group == 1 else _kv_head(b, group)), j, 0
 
     out_specs = pl.BlockSpec((1, block_q, dv), lambda b, i, j, *_: (b, i, 0))
     out_shape = jax.ShapeDtypeStruct((bh, sq, dv), q.dtype)
@@ -424,7 +454,8 @@ def _reference_attention(q, k, v, offsets, causal, sm_scale, window=None):
 
 def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *dq_scratch,
-                block_q, block_k, causal, sm_scale, window=None, nq=None):
+                block_q, block_k, causal, sm_scale, window=None, nq=None,
+                group=None):
     """The whole backward: grid (bh, kv-block, q-block), q innermost.
     Each visible (kv, q) pair rebuilds its scores once, transposed
     (S^T = K Q^T, a (block_k, block_q) tile), so P^T and dS^T come out
@@ -434,26 +465,39 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     of this bh stays in VMEM across the two inner grid dimensions: in
     ``dq_ref`` itself when that is fp32, else in an fp32 scratch cast
     into it on the last step. With a ``window`` the innermost dimension
-    counts from the first q block (of ``nq``) that kv block ``j`` sees."""
-    j = pl.program_id(1)
-    step = pl.program_id(2)
-    nkv = pl.num_programs(1)
-    steps = pl.num_programs(2)
+    counts from the first q block (of ``nq``) that kv block ``j`` sees.
+
+    With a ``group`` (query heads that share one key/value head) the grid
+    is (bh_kv, member, kv-block, q-block): dQ is as above, for the query
+    head ``member`` names; dK/dV sum over the members in sequence-sized
+    fp32 scratch, kv block ``j``'s rows zeroed at the first member and
+    written, once, into the sequence-sized output block at the last."""
+    axis = 1 if group is None else 2
+    j = pl.program_id(axis)
+    step = pl.program_id(axis + 1)
+    nkv = pl.num_programs(axis)
+    steps = pl.num_programs(axis + 1)
     i = step
     if window is not None:
         i = step + _first_visible_q(j, off_ref, block_q, block_k, nq)
     q_off = off_ref[0]
     kv_off = off_ref[1]
     dq_acc = dq_scratch[0] if dq_scratch else dq_ref
+    # kv block j's rows of dK/dV: all of the block-sized scratch
+    kv_rows = slice(None) if group is None else _block_rows(j, block_k)
 
     @pl.when((j == 0) & (step == 0))
     def _init_dq():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(step == 0)
+    start = step == 0
+    if group is not None:
+        start &= pl.program_id(1) == 0
+
+    @pl.when(start)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[kv_rows] = jnp.zeros((block_k, dk_acc.shape[-1]), jnp.float32)
+        dv_acc[kv_rows] = jnp.zeros((block_k, dv_acc.shape[-1]), jnp.float32)
 
     def _update():
         # native-dtype matmul operands + fp32 accumulation (see _kernel)
@@ -475,12 +519,12 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             s = jnp.where(_visible(q_pos, kv_pos, window), s, NEG_INF)
         # p = exp(s - lse); rows with nothing visible have lse=NEG_INF
         p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
-                               preferred_element_type=jnp.float32)
+        dv_acc[kv_rows] += jnp.dot(p.astype(g.dtype), g,
+                                   preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(v, g, _NT,
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        dk_acc[kv_rows] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
         rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
         dq_acc[0, rows, :] += jax.lax.dot_general(
             ds, k, _TN, preferred_element_type=jnp.float32)
@@ -494,10 +538,14 @@ def _bwd_kernel(off_ref, q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     else:
         _update()
 
-    @pl.when(step == steps - 1)
+    end = step == steps - 1
+    if group is not None:
+        end &= pl.program_id(1) == group - 1
+
+    @pl.when(end)
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0, kv_rows] = dk_acc[kv_rows].astype(dk_ref.dtype)
+        dv_ref[0, kv_rows] = dv_acc[kv_rows].astype(dv_ref.dtype)
 
     if dq_scratch:
         @pl.when((j == nkv - 1) & (step == steps - 1))
@@ -527,17 +575,23 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
     as p = exp(s - LSE)). ``out_dtype`` lets accumulating callers
     request fp32 partials. ``window``: ``None``, or ``(W, steps)``: the q
     dimension of the grid then has ``steps`` blocks, counted from the
-    first that a kv block sees.
+    first that a kv block sees. k and v may hold ``BH / g`` rows
+    (grouped-query attention, ``_flash_fwd_impl``): the grid then runs
+    the ``g`` query heads of a key/value head one after another
+    (``_grouped_bwd``) and dK, dV come out at k's and v's rows.
 
     The only quantity that grows with the sequence is the resident dQ:
     two output buffers of ``sq * d`` elements plus, unless dQ is fp32,
     the fp32 accumulator (1 MiB at s2048 d64 bf16, 2 MiB at s2048 d128,
     32 MiB for ring attention's fp32 partials at 32k a chip and d128 —
     of the 128 MiB a v5e core has; past ``sq * d`` = 8 Mi elements the
-    compile fails with Mosaic's out-of-VMEM message)."""
+    compile fails with Mosaic's out-of-VMEM message); a grouped call
+    adds its sequence-sized dK and dV (8 MiB of scratch and 8 of output
+    buffers at s8192 d128 bf16)."""
     window, steps = window or (None, None)
     bh, sq, d = q.shape
     skv, dv = k.shape[1], v.shape[2]
+    group = bh // k.shape[0]
     # grads mirror their primal dtypes (custom_vjp aval contract) unless
     # the caller wants uniform fp32 partials for accumulation
     dq_dtype = jnp.dtype(out_dtype or q.dtype)
@@ -578,23 +632,61 @@ def _flash_bwd_core(q, k, v, g, lse, delta, offsets, causal, sm_scale,
                              causal=causal, sm_scale=sm_scale)
     if window is not None:
         kern = functools.partial(kern, window=window, nq=nq)
+    grid = (bh, skv // block_k, nq if window is None else steps)
+    in_specs = [qspec, kspec, vspec, gspec, rowspec, rowspec]
+    out_specs = (dqspec, kspec, vspec)
+    if group > 1:
+        grid, in_specs, out_specs, scratch, more = _grouped_bwd(
+            group, grid, in_specs, out_specs, scratch, skv,
+            jnp.dtype(dk_dtype).itemsize)
+        resident += more
+        kern = functools.partial(kern, group=group)
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bh, skv // block_k, nq if window is None else steps),
-            in_specs=[qspec, kspec, vspec, gspec, rowspec, rowspec],
-            out_specs=(dqspec, kspec, vspec),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
             scratch_shapes=scratch,
         ),
         out_shape=(jax.ShapeDtypeStruct((bh, sq, d), dq_dtype),
-                   jax.ShapeDtypeStruct((bh, skv, d), dk_dtype),
-                   jax.ShapeDtypeStruct((bh, skv, dv), dv_dtype)),
+                   jax.ShapeDtypeStruct((k.shape[0], skv, d), dk_dtype),
+                   jax.ShapeDtypeStruct((k.shape[0], skv, dv), dv_dtype)),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            dimension_semantics=("parallel",) + ("arbitrary",) * (
+                len(grid) - 1),
             vmem_limit_bytes=_BLOCK_VMEM_BYTES + resident),
         interpret=interpret,
     )(offsets, q, k, v, g, lse[:, None, :], delta[:, None, :])
+
+
+def _grouped_bwd(group, grid, in_specs, out_specs, scratch, skv, itemsize):
+    """The backward's launch for ``group`` query heads a key/value head,
+    from the one-head-each launch (``grid`` (bh, nkv, steps), its specs
+    and scratch): ``(grid, in_specs, out_specs, scratch, VMEM bytes it
+    adds)``. The grid becomes (bh_kv, member, nkv, steps); the query
+    side's maps (q, dO, lse, delta, dQ) name query row ``b * group +
+    member``, the key/value side's (k, v) row ``b``; dK and dV become
+    sequence-sized fp32 scratch and sequence-sized output blocks, which
+    stay in VMEM across the group and are copied out once."""
+    bh, nkv, steps = grid
+    q_side = lambda spec: pl.BlockSpec(  # noqa: E731
+        spec.block_shape,
+        lambda b, r, j, i, off: spec.index_map(b * group + r, j, i, off))
+    kv_side = lambda spec: pl.BlockSpec(  # noqa: E731
+        spec.block_shape, lambda b, r, j, i, off: spec.index_map(b, j, i,
+                                                                 off))
+    qspec, kspec, vspec, gspec, rowspec, _ = in_specs
+    widths = [spec.block_shape[-1] for spec in (kspec, vspec)]
+    whole = [pl.BlockSpec((1, skv, w), lambda b, r, j, i, off: (b, 0, 0))
+             for w in widths]
+    return ((bh // group, group, nkv, steps),
+            [q_side(qspec), kv_side(kspec), kv_side(vspec), q_side(gspec),
+             q_side(rowspec), q_side(rowspec)],
+            (q_side(out_specs[0]), *whole),
+            [pltpu.VMEM((skv, w), jnp.float32) for w in widths] + scratch[2:],
+            sum(skv * w * (4 + 2 * itemsize) for w in widths))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
@@ -691,6 +783,10 @@ def _prep(q, k, v, sm_scale, block_q, block_k, interpret, *passes):
     if k.shape[-1] != d:
         raise ValueError(f"flash_attention: q and k must share a head "
                          f"size (q {d}, k {k.shape[-1]})")
+    if k.shape[2] != v.shape[2] or h % k.shape[2]:
+        raise ValueError(f"flash_attention: k and v share one number of "
+                         f"heads that divides q's (q {h}, k {k.shape[2]}, "
+                         f"v {v.shape[2]})")
     sm_scale = sm_scale if sm_scale is not None else 1.0 / (float(d) ** 0.5)
     blocks = [(_fit_block(sq, block_q or default_q),
                _fit_block(skv, block_k or default_k))
@@ -718,10 +814,15 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
                     kv_offset=0, block_q=None, block_k=None, interpret=None,
                     window=None):
     """Fused attention on [B, S, H, D] tensors (the transformer layout).
-    ``block_q``/``block_k`` left unset give each kernel its own default
-    (``FORWARD_BLOCKS``, ``BACKWARD_BLOCKS``; with a ``window``,
-    ``WINDOW_BLOCKS``); a given size holds for both. ``window``: a query
-    sees itself and the ``window - 1`` positions before it (causal only).
+    k and v may hold fewer heads, ``H_kv``, that divide q's: query head
+    ``h`` then attends to key/value head ``h // (H / H_kv)``, read in
+    place by the kernels (no broadcast to ``H``), and dK, dV come back
+    at ``H_kv`` heads, summed over each head's queries inside the
+    backward kernel. ``block_q``/``block_k`` left unset give each kernel
+    its own default (``FORWARD_BLOCKS``, ``BACKWARD_BLOCKS``; with a
+    ``window``, ``WINDOW_BLOCKS``); a given size holds for both.
+    ``window``: a query sees itself and the ``window - 1`` positions
+    before it (causal only).
 
     ``q_offset``/``kv_offset`` are the absolute positions of the first
     query/key token; ints or traced int32 scalars both work (they ride a
@@ -731,10 +832,12 @@ def flash_attention(q, k, v, *, causal=True, sm_scale=None, q_offset=0,
         raise ValueError(f"flash_attention: a window ({window}) is a "
                          f"positive number of positions behind a causal "
                          f"diagonal (causal={causal})")
-    (b, sq, _), sm_scale, interpret, fwd_blocks, bwd_blocks = _prep(
+    (b, sq, h), sm_scale, interpret, fwd_blocks, bwd_blocks = _prep(
         q, k, v, sm_scale, block_q, block_k, interpret,
         *((FORWARD_BLOCKS, BACKWARD_BLOCKS) if window is None
           else WINDOW_BLOCKS))
+    if k.shape[2] != h:
+        _startup.RECORD.grouped_attention(h // k.shape[2])
     offsets = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                          jnp.asarray(kv_offset, jnp.int32)])
     windows = (None, None)
@@ -812,6 +915,21 @@ def attention(q, k, v, *, causal=True, q_offset=0, kv_offset=0, window=None):
     warn_fallback("ops.flash_attention.attention", q.shape, skv,
                   "the shapes do not tile onto the kernel's blocks")
     offsets = jnp.asarray([q_offset, kv_offset], jnp.int32)
+    k, v = per_query_head(q, k, v)
     out = _reference_attention(_to_bh(q), _to_bh(k), _to_bh(v), offsets,
                                causal, 1.0 / (float(d) ** 0.5), window)
     return _from_bh(out, b)
+
+
+def per_query_head(q, k, v):
+    """``(k, v)`` with one key/value head a query head of ``q``
+    ([B, S, H, D]): shared heads repeated ``H / H_kv`` times (query head
+    ``h`` sees head ``h // (H / H_kv)``), for the paths that take one head
+    each — ``_reference_attention``, ring attention, the dense and decode
+    paths of ``models/transformer.attend``. The repeat's transpose sums
+    the query heads' gradients back. k and v as they are where they
+    already have q's heads."""
+    h = q.shape[2]
+    return tuple(t if t.shape[2] == h else jnp.repeat(t, h // t.shape[2],
+                                                      axis=2)
+                 for t in (k, v))

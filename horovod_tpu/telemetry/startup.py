@@ -122,6 +122,24 @@ class Record:
                 self.closed_at = entry["end"]
         self._builds_at_step_return = self.builds
 
+    def grouped_attention(self, group):
+        """One attention call traced with ``group`` query heads a
+        key/value head (``ops/flash_attention.flash_attention``, which
+        reads the shared heads in place): counted on the innermost
+        program span open on this thread, ``hvd_lower`` while the step is
+        built, as ``grouped_attention_calls`` and the sorted
+        ``attention_groups``. A fact of the build, not of a step: the
+        kernels take shared heads whenever k has fewer heads than q."""
+        stack = _thread.open
+        with self._lock:
+            if self.closed or not stack:
+                return
+            attrs = self.spans[stack[-1]]["attrs"]
+            attrs["grouped_attention_calls"] = attrs.get(
+                "grouped_attention_calls", 0) + 1
+            attrs["attention_groups"] = sorted(
+                {*attrs.get("attention_groups", ()), group})
+
     # -- jax's spans ----------------------------------------------------------
     def built(self, name, start, end, program, cache):
         """One top-level build span of jax's, from the listener: kept

@@ -211,17 +211,24 @@ def test_laguna_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     benchmark family builds it (8 layers at the published widths, 1 x 8192
     tokens, AdamW), compiled for one described v5e chip: it fits the
     chip's 15.75 GiB as built (no attention mixer recomputed, nothing
-    rematerialised by the compiler: 13.04 GiB, PERF.md section 4), both
+    rematerialised by the compiler: 11.40 GiB, PERF.md section 4), both
     flash kernels and megablox are in it as kernels, nothing fell back,
     every kernel's ``op_name`` still ends ``attn/pallas_call`` (what the
     accepted readers know the flash kernel by), and the three attention
     scopes are on the attention layers' instructions alone, the windowed
-    one on the six sliding layers and the full one on the two others."""
+    one on the six sliding layers and the full one on the two others.
+    The 8 key/value heads reach the kernels as they are (PR 38): every
+    flash kernel takes k and v at ``[8, 8192, 128]``, the backward gives
+    dK and dV back at that size, nothing under ``hvd_attn`` broadcasts
+    them to 48 or 64 query heads or sums them back (13.04 GiB with the
+    broadcast), and the set-up record counts the eight grouped calls, at
+    groups of 6 and 8, on the build's span."""
     import json
     import warnings
 
     import horovod_tpu as hvd
     from benchmark.families import swa_moe_lm as family
+    from horovod_tpu.telemetry import scopes, startup
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -247,13 +254,18 @@ def test_laguna_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
             tokens = jax.ShapeDtypeStruct(
                 (traffic["per_chip_batch"], traffic["seq_len"]), jnp.int32,
                 sharding=NamedSharding(hvd.mesh(), P("data")))
-            compiled = built.step.jitted.lower(state, tokens).compile()
+            monkeypatch.setattr(startup, "RECORD", startup.Record())
+            with startup.span(scopes.LOWER) as built_with:
+                lowered = built.step.jitted.lower(state, tokens)
+            compiled = lowered.compile()
     finally:
         hvd.shutdown()
+    assert built_with == {"grouped_attention_calls": 8,
+                          "attention_groups": [6, 8]}
     m = compiled.memory_analysis()
     footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
                  + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert 12.0 < footprint / 2 ** 30 < 14.0  # 13.04 (PERF.md)
+    assert 11.0 < footprint / 2 ** 30 < 12.0  # 11.40 (PERF.md)
     parameters = sum(int(np.prod(a.shape)) for a in
                      jax.tree_util.tree_leaves(state.params))
     assert parameters == config["parameters"] == family.parameters(
@@ -288,6 +300,26 @@ def test_laguna_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     for part in ("query", "key", "value", "gate", "out"):
         assert any(f"hvd_attn/{part}/" in n for n in rest), part
     assert not any(n.endswith("pallas_call") for n in rest)
+    # k and v at 8 heads into every kernel, dK and dV at 8 out of the
+    # backward; no broadcast to the query heads and no sum back under
+    # hvd_attn (the parent had 16 and 16 of them there)
+    kernel_lines = [line for line in text.splitlines()
+                    if 'custom_call_target="tpu_custom_call"' in line
+                    and "hvd_moe_experts" not in line]
+    for line in kernel_lines:
+        operands = line.split("operand_layout_constraints=")[1].split(
+            "frontend_attributes")[0]
+        assert re.findall(r"bf16\[(\d+),8192,128\]", operands)[1:3] == [
+            "8", "8"], operands
+    backward = [line for line in kernel_lines if "transpose(jvp(" in line]
+    assert len(backward) == 8 and all(re.search(
+        r"= \(bf16\[(48|64),8192,128\]\{[^}]*\}, bf16\[8,8192,128\]"
+        r"\{[^}]*\}, bf16\[8,8192,128\]", line) for line in backward)
+    ops = re.findall(r"= (\S+) \S+\(.*?op_name=\"([^\"]*)\"", text)
+    assert not [(shape, name) for shape, name in ops
+                if under("hvd_attn", [name]) and re.match(
+                    r"bf16\[8192,8,(6,|8,)?128\]", shape)
+                and name.endswith(("/broadcast_in_dim", "/reduce_sum"))]
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1536), (768, 2048)],

@@ -874,3 +874,135 @@ def test_window_fallback_and_refusals():
     with pytest.raises(ValueError, match="causal"):
         dense_attention(q, k, v, causal=False, q_positions=pos,
                         kv_positions=pos, window=100)
+
+
+# ------------------------------------------------- shared key/value heads
+
+def _grouped_qkv(seed, b, s, h_kv, group, d=16, dtype=jnp.bfloat16):
+    rng = np.random.default_rng(seed)
+    mk = lambda h: jnp.asarray(  # noqa: E731
+        rng.standard_normal((b, s, h, d)), dtype)
+    return mk(h_kv * group), mk(h_kv), mk(h_kv)
+
+
+@pytest.mark.parametrize("window", [None, 300], ids=["causal", "window"])
+@pytest.mark.parametrize("group,h_kv", [(2, 2), (6, 2), (8, 2), (16, 1)],
+                         ids=lambda x: str(x))
+def test_shared_heads_are_the_broadcast_read_in_place(group, h_kv, window):
+    """The grouped kernels (k and v at ``H / g`` heads, read in place)
+    against the one-head-each kernels given the heads repeated, at the
+    default blocks of each (``WINDOW_BLOCKS`` with a window: four blocks
+    of 512 at s2048), bfloat16 operands, two sequences: the output and
+    the log-sum-exp rows bit for bit (each query head runs the same
+    tiles), dQ bit for bit, and dK, dV within the bfloat16 rounding of
+    the ``g``-term sum: the grouped backward sums the group's heads in
+    float32 and rounds once, where the broadcast's transpose sums the
+    heads' rounded gradients."""
+    b, s = 2, 2048
+    q, k, v = _grouped_qkv(group + h_kv, b, s, h_kv, group)
+    k_rep, v_rep = fa.per_query_head(q, k, v)
+    assert k_rep.shape == q.shape
+    offsets = jnp.zeros(2, jnp.int32)
+    windows = (None, None)
+    if window is not None:
+        windows = tuple((window, fa._window_steps(s, s, *blocks, window, 0,
+                                                  0)[n])
+                        for n, blocks in enumerate(fa.WINDOW_BLOCKS))
+    blocks = (fa.FORWARD_BLOCKS if window is None else fa.WINDOW_BLOCKS[0])
+    forward = lambda k, v: fa._flash_fwd_impl(  # noqa: E731
+        fa._to_bh(q), fa._to_bh(k), fa._to_bh(v), offsets, True,
+        16 ** -0.5, *blocks, True, with_lse=True, window=windows[0])
+    for got, want in zip(forward(k, v), forward(k_rep, v_rep)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    weight = jnp.asarray(np.random.default_rng(1).standard_normal(q.shape),
+                         jnp.float32)
+
+    def grads(k, v):
+        return jax.grad(lambda *a: jnp.sum(fa.flash_attention(
+            *a, window=window).astype(jnp.float32) * weight),
+            argnums=(0, 1, 2))(q, k, v)
+
+    dq, dk, dv = grads(k, v)
+    dq_rep, dk_heads, dv_heads = grads(k_rep, v_rep)
+    np.testing.assert_array_equal(np.asarray(dq), np.asarray(dq_rep))
+    for got, heads in ((dk, dk_heads), (dv, dv_heads)):
+        assert got.shape == k.shape and got.dtype == jnp.bfloat16
+        terms = np.asarray(heads, np.float32).reshape(
+            b, s, h_kv, group, 16)
+        want = terms.sum(3)
+        # half a bfloat16 ulp (2^-9 of the magnitude) for each rounded
+        # term and for the one rounding of the sum, and again as room
+        bound = 2.0 ** -8 * (np.abs(terms).sum(3) + np.abs(want)) + 1e-6
+        assert (np.abs(np.asarray(got, np.float32) - want) <= bound).all()
+
+
+def test_shared_heads_refused_where_they_do_not_divide():
+    q, k, v = _grouped_qkv(0, 1, 256, 3, 1)
+    with pytest.raises(ValueError, match="divides q's"):
+        fa.flash_attention(q[:, :, :2], k, v)
+    with pytest.raises(ValueError, match="share one number"):
+        fa.flash_attention(q, k, v[:, :, :1])
+
+
+def test_one_head_each_traces_no_grouped_code(monkeypatch):
+    """At ``g == 1`` (every MHA and latent-attention caller, and the
+    benchmark probe's broadcast call) the kernels trace the program they
+    traced before shared heads: the same jaxpr, forward and backward,
+    causal and windowed, and the ring's two primitives, with every
+    grouped helper made to raise; nothing is counted in the set-up
+    record."""
+    from horovod_tpu.telemetry import scopes, startup
+    q, k, v = _qkv(np.random.default_rng(9), b=1, s=256, h=2, d=16)
+    lse = jnp.zeros((1, 256, 2))
+
+    def programs():
+        train = [str(jax.make_jaxpr(jax.value_and_grad(
+            lambda *a: jnp.sum(fa.flash_attention(
+                *a, block_q=64, block_k=64, **kw)), argnums=(0, 1, 2)))(
+                    q, k, v)) for kw in ({}, {"window": 100})]
+        return train + [
+            str(jax.make_jaxpr(lambda *a: fa.flash_attention_with_lse(
+                *a, block_q=64, block_k=64))(q, k, v)),
+            str(jax.make_jaxpr(lambda *a: fa.flash_attention_bwd_block(
+                *a, block_q=64, block_k=64))(q, k, v, q, lse, lse))]
+
+    before = programs()
+
+    def unreachable(*a, **kw):
+        raise AssertionError("a grouped branch ran with one head each")
+
+    for name in ("_kv_head", "_block_rows", "_grouped_bwd"):
+        monkeypatch.setattr(fa, name, unreachable)
+    record = startup.Record()
+    monkeypatch.setattr(startup, "RECORD", record)
+    monkeypatch.setattr(record, "grouped_attention", unreachable)
+    with startup.span(scopes.LOWER) as attrs:
+        assert programs() == before
+    assert attrs == {}
+    assert re.findall(r"grid=\(([\d, ]+)\)", before[0]) == [
+        "2, 4, 4", "2, 4, 4"]
+
+
+def test_grouped_calls_are_counted_on_the_open_build_span(monkeypatch):
+    """Each call traced with shared heads counts, with its group size,
+    on the program span open while it is traced (``hvd_lower`` in a
+    step's build), and nowhere once the record closed."""
+    from horovod_tpu.telemetry import scopes, startup
+    record = startup.Record()
+    monkeypatch.setattr(startup, "RECORD", record)
+    q6, k, v = _grouped_qkv(0, 1, 256, 1, 6, dtype=jnp.float32)
+    q8 = jnp.concatenate([q6, q6[:, :, :2]], axis=2)
+    run = lambda q, **kw: jax.make_jaxpr(  # noqa: E731
+        lambda q: fa.flash_attention(q, k, v, **kw))(q)
+    with startup.span(scopes.LOWER) as attrs:
+        run(q6)
+        run(q8, window=64)
+        run(q8)
+        run(q6[:, :, :1])  # one head each: not counted
+    assert attrs == {"grouped_attention_calls": 3, "attention_groups": [6, 8]}
+    assert [span["attrs"] for span in record.spans
+            if span["name"] == scopes.LOWER] == [attrs]
+    record.closed = True
+    run(q6)
+    assert attrs["grouped_attention_calls"] == 3

@@ -437,6 +437,98 @@ def test_the_shares_add_up_to_the_uncut_layer(rng, experts, top_k, held):
                                np.asarray(whole), atol=5e-5)
 
 
+def _grouped_qkv(rng, kind, s=128):
+    """q [1, s, H, e] and k, v [1, s, H_kv, e] of ``kind``."""
+    mk = lambda h: jnp.asarray(  # noqa: E731
+        rng.standard_normal((1, s, h, kind.head_dim)), jnp.float32)
+    return mk(kind.num_heads), mk(kind.num_kv_heads), mk(kind.num_kv_heads)
+
+
+@pytest.mark.parametrize("flash", [True, False], ids=["flash", "dense"])
+@pytest.mark.parametrize("kind", [FULL, dataclasses.replace(SLIDING,
+                                                            window=40)],
+                         ids=lambda a: a.kind)
+def test_attend_takes_shared_heads_or_their_broadcast(rng, kind, flash):
+    """``attend`` with k and v at ``H_kv`` heads and with them repeated
+    to ``H`` (the benchmark probe's call): one result, on the kernel's
+    path (shared heads read in place) and on the dense path (repeated
+    inside ``attend``)."""
+    cfg = _config(attention=(kind,), flash_attention=flash)
+    q, k, v = _grouped_qkv(rng, kind)
+    positions = jnp.arange(q.shape[1])[None]
+    shared, _ = transformer_lib.attend(cfg, kind, q, k, v, positions, True)
+    repeated, _ = transformer_lib.attend(
+        cfg, kind, q, *(jnp.repeat(t, q.shape[2] // t.shape[2], axis=2)
+                        for t in (k, v)), positions, True)
+    np.testing.assert_array_equal(np.asarray(shared), np.asarray(repeated))
+
+
+@pytest.mark.parametrize("kind", ["full", "sliding"])
+def test_attention_without_the_broadcast_is_the_layer_it_was(rng, kind,
+                                                             monkeypatch):
+    """A layer of each kind (shared heads; the sliding one with a window
+    of 40 at 128 positions) on the kernel's path: its output and the
+    gradients of all its parameters as ``Attention`` builds them now (k
+    and v handed to the kernel at ``H_kv`` heads) and with the parent's
+    broadcast put back in front of ``attend``. The output is the same bit
+    for bit; the gradients agree to float32 rounding of the group's sum."""
+    cfg = _config(attention=(FULL, dataclasses.replace(SLIDING, window=40)),
+                  flash_attention=True)
+    y = jnp.asarray(rng.standard_normal((1, 128, D)), jnp.float32)
+    positions = jnp.arange(128)[None]
+    weight = jnp.asarray(rng.standard_normal((1, 128, D)), jnp.float32)
+    module = Attention(cfg, kind)
+    params = module.init(jax.random.PRNGKey(3), y, positions, True)["params"]
+
+    def run():
+        return jax.value_and_grad(lambda p: jnp.sum(module.apply(
+            {"params": p}, y, positions, True) * weight))(params)
+
+    loss, grads = run()
+    attend = transformer_lib.attend
+    monkeypatch.setattr(
+        transformer_lib, "attend", lambda cfg, a, q, k, v, *rest: attend(
+            cfg, a, q, *(jnp.repeat(t, q.shape[2] // t.shape[2], axis=2)
+                         for t in (k, v)), *rest))
+    loss_broadcast, grads_broadcast = run()
+    assert float(loss) == float(loss_broadcast)
+    for got, want in zip(jax.tree_util.tree_leaves(grads),
+                         jax.tree_util.tree_leaves(grads_broadcast)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("path", ["ring", "dense", "cache"])
+def test_paths_of_one_head_each_still_get_one_head_each(rng, path,
+                                                        monkeypatch):
+    """Ring attention, ``dense_attention`` and the decode cache take one
+    key/value head a query head: ``attend`` repeats shared heads for them
+    (and hands the cache the repeated new k and v)."""
+    from horovod_tpu.parallel import ring
+    seen = []
+
+    def record(q, k, v, **kw):
+        seen.append((q.shape, k.shape, v.shape))
+        return q
+
+    monkeypatch.setattr(transformer_lib, "dense_attention", record)
+    monkeypatch.setattr(ring, "ring_attention",
+                        lambda q, k, v, axis_name, **kw: record(q, k, v))
+    q, k, v = _grouped_qkv(rng, FULL, s=8)
+    positions = jnp.arange(8)[None]
+    cfg = _config(sequence_axis="seq" if path == "ring" else None)
+    cache = None
+    if path == "cache":
+        cache = (jnp.zeros((1, 4) + q.shape[2:]),) * 2 + (
+            jnp.arange(4)[None],)
+    _, new_kv = transformer_lib.attend(cfg, FULL, q, k, v, positions, True,
+                                       cache)
+    heads = q.shape[2]
+    assert [shape[2] for shape in seen[0]] == [heads] * 3
+    if path == "cache":
+        assert [t.shape[2] for t in new_kv] == [heads] * 2
+
+
 @pytest.mark.parametrize("case", ["cache", "ring"])
 def test_what_a_windowed_layer_cannot_run_is_refused(case):
     """A window meets the paged cache, or ring attention: refused, naming
