@@ -13,12 +13,18 @@ its own experts give:
     w   = s[idx] / (sum(s[idx]) + 1e-20) * routed_scaling_factor
     out = sum over the k with idx_k held of  w_k * E_idx_k(y)
 
+or, with ``router="softmax"`` (the Qwen3-MoE family's), ``s =
+softmax(y Wr)`` over all the routed experts, ``idx = top_k(s)`` and no
+bias parameter at all; the weights as above (``norm_topk_prob``, with a
+``routed_scaling_factor`` of 1).
+
 The weights are normalised over all k chosen, held or not; a token-slot
 whose expert is not held adds nothing, and what the absent experts would
 have added is left out, not stood in for: there is no exchange on one
 chip. The shared experts (``shared_expert``: one module of width
 ``n_shared_experts * moe_d_ff``, or ``shared_d_ff``) are the caller's to
-add; every share computes them alike.
+add; every share computes them alike. With neither size a layer has none
+(``models/transformer.Block`` adds nothing).
 
 An expert's body is ``expert_body``: ``"swiglu"``, ``(silu(y Wg) * (y
 Wu)) Wd``, three matrices (the DeepSeek-V3 family's), or ``"relu2"``,
@@ -33,8 +39,9 @@ products over the ragged groups.
 The buffers in expert order (the gathered tokens, the experts' hidden
 rows and output, and their gradients) hold ``rows`` rows, a bound on the
 slots the held experts can receive that follows from shapes alone:
-``HELD_ROOM`` times the ``T * k * experts_held / n_routed_experts`` slots
-that balance would send them, rounded up to a megablox row tile, and
+``held_room`` (``HELD_ROOM`` unless set) times the ``T * k *
+experts_held / n_routed_experts`` slots that balance would send them,
+rounded up to a megablox row tile, and
 never more than ``T * k`` (``held_rows``). A step whose held slots pass
 the bound takes the way out (``jax.lax.cond`` on ``total > rows``, under
 the device scope ``hvd_moe_overflow``): the same body over one window of
@@ -115,8 +122,15 @@ class ExpertShareConfig:
     selection_bias_std: float = 0.0
     expert_body: str = "swiglu"  # | "relu2"
     # width of the shared experts' one module (None: n_shared_experts *
-    # moe_d_ff)
+    # moe_d_ff); with neither, a layer has no shared expert
     shared_d_ff: Optional[int] = None
+    # "sigmoid": the scores above, with the selection bias; "softmax": s =
+    # softmax(y Wr) over all n_routed_experts, idx = top_k(s), no bias
+    # (the Qwen3-MoE family's router)
+    router: str = "sigmoid"
+    # the expert-order buffers' room, as a multiple of the slots balance
+    # would send the held experts (None: HELD_ROOM)
+    held_room: Optional[float] = None
 
 
 def _relu2(x):
@@ -315,11 +329,13 @@ def grouped_matmul(xs, w, group_sizes):
 
 def held_rows(slots, cfg):
     """The rows of the buffers in expert order, for ``slots`` token-slots
-    a step: ``HELD_ROOM`` times what balance sends the held experts, up to
-    a megablox row tile, and at most every slot."""
+    a step: ``cfg.held_room`` (``HELD_ROOM`` where unset) times what
+    balance sends the held experts, up to a megablox row tile, and at most
+    every slot."""
+    room = HELD_ROOM if cfg.held_room is None else cfg.held_room
     expected = slots * cfg.experts_held / cfg.n_routed_experts
     tile = GMM_ROWS[0]
-    return min(slots, math.ceil(HELD_ROOM * expected / tile) * tile)
+    return min(slots, math.ceil(room * expected / tile) * tile)
 
 
 def _prepared(c, dtype, y, params):
@@ -330,11 +346,15 @@ def _prepared(c, dtype, y, params):
     held, k = c.experts_held, c.num_experts_per_tok
     with scopes.device(scopes.MOE_ROUTE):
         # the router in float32: a score's eighth bit decides a choice
-        scores = jax.nn.sigmoid(jnp.dot(
-            y.astype(jnp.float32), params["router"],
-            precision=jax.lax.Precision.HIGHEST))
-        idx, w = route(scores, params["e_score_correction_bias"], k,
-                       c.routed_scaling_factor)
+        logits = jnp.dot(y.astype(jnp.float32), params["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        if c.router == "softmax":
+            idx, w = route(jax.nn.softmax(logits, axis=-1), 0.0, k,
+                           c.routed_scaling_factor)
+        else:
+            idx, w = route(jax.nn.sigmoid(logits),
+                           params["e_score_correction_bias"], k,
+                           c.routed_scaling_factor)
         # choice-major slots: slot j * T + i is choice j of token i
         local = idx.T.reshape(-1) - c.expert_offset
         is_held = (local >= 0) & (local < held)
@@ -484,6 +504,8 @@ class ExpertShare(nn.Module):
                              f"{c.expert_offset + held} of {n}")
         if c.expert_body not in BODIES:
             raise ValueError(f"unknown expert body {c.expert_body!r}")
+        if c.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown router {c.router!r}")
         expert = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                               batch_axis=(0,))
         shapes = {"up_proj": (held, d, f), "down_proj": (held, f, d)}
@@ -493,10 +515,11 @@ class ExpertShare(nn.Module):
                   for name, shape in shapes.items()}
         params["router"] = self.param(
             "router", nn.initializers.lecun_normal(), (d, n))
-        params["e_score_correction_bias"] = self.param(
-            "e_score_correction_bias",
-            nn.initializers.normal(c.selection_bias_std)
-            if c.selection_bias_std else nn.initializers.zeros, (n,))
+        if c.router == "sigmoid":
+            params["e_score_correction_bias"] = self.param(
+                "e_score_correction_bias",
+                nn.initializers.normal(c.selection_bias_std)
+                if c.selection_bias_std else nn.initializers.zeros, (n,))
         out, idx = _routed(c, self.dtype, y, params)
         # for a caller that asks (mutable=["intermediates"]); else no-op
         self.sow("intermediates", "chosen", idx)

@@ -22,8 +22,14 @@ of the TPU framework. Design:
   head size, sliding window (in the flash kernel's block schedule and in
   ``dense_attention`` alike), rotary embedding (theta, the width of a
   head it turns, YaRN's blended frequencies and its factor on cos and
-  sin) and per-head sigmoid output gate. ``"mha"`` is the kind the
-  top-level fields size, and its parameter tree is what it always was.
+  sin) and per-head sigmoid output gate, a norm over each head of q and k
+  (``qk_norm``), and a lightning indexer (``indexer``,
+  ``models/sparse_attention.py``): each query then attends to the
+  ``top_k`` keys of the indexer's scores alone (``ops/sparse_attention.py``:
+  the selection, flash attention under it and the indexer's KL loss as
+  Pallas kernels), and the layer sows the KL, which
+  ``training.make_lm_train_step`` adds to its loss. ``"mha"`` is the kind
+  the top-level fields size, and its parameter tree is what it always was.
 * ``sequence_axis``: when set (inside shard_map over that mesh axis), the
   sequence dimension is sharded across the axis and attention runs as
   **ring attention** (``horovod_tpu.parallel.ring``): K/V blocks rotate
@@ -95,15 +101,15 @@ class TransformerConfig:
     #   mixer         "mha" (Attention below, sized by the "mha only"
     #                 fields) | the ``kind`` of an entry of ``attention``
     #                 (Attention too, sized by that entry: its heads,
-    #                 window, rotary and gate) | "mla" (models/mla.py,
-    #                 sized by ``mla``) | "ssm" (models/ssm.py, sized by
-    #                 ``ssm``) | "kda" (models/kda.py, sized by ``kda``)
-    #                 | None
+    #                 window, rotary, gate, q/k norm and indexer) | "mla"
+    #                 (models/mla.py, sized by ``mla``) | "ssm"
+    #                 (models/ssm.py, sized by ``ssm``) | "kda"
+    #                 (models/kda.py, sized by ``kda``) | None
     #   feed-forward  "gelu" (two matrices, width d_ff) | "moe" (the
     #                 capacity dispatch of models/moe.py) | "swiglu"
     #                 (gated, width d_ff) | "experts" (the no-drop share
-    #                 of models/experts.py plus its shared expert, sized
-    #                 by ``experts``) | None
+    #                 of models/experts.py plus its shared expert where
+    #                 one is sized, sized by ``experts``) | None
     # A half that is None has no norm, no parameters and no residual: the
     # layer is the other half alone. The pattern None: ("mha", "moe" on
     # every moe_every-th layer, else "gelu"), the block this file has
@@ -208,6 +214,17 @@ class AttentionConfig:
     yarn: Optional[YarnScaling] = None
     # head h's output times sigmoid(x Wg)[h], Wg [d_model, num_heads]
     gate: bool = False
+    # RMSNorm over each head of q and of k (a learned head_dim-wide weight
+    # each, eps ``norm_eps``), before rotary
+    qk_norm: bool = False
+    # a lightning indexer (models/sparse_attention.IndexerConfig): each
+    # query attends to the top_k keys of its scores, and the layer sows the
+    # indexer's KL loss. Training only, sequence_axis None
+    indexer: Any = None
+    # the layer recomputed in the backward pass (flax's remat), keeping
+    # its input and, with an indexer, the selection (ops/sparse_attention
+    # .SAVED): the selection runs once, the rest of the layer twice
+    recompute: bool = False
 
     def rotate(self, x, positions):
         if not self.rotary:
@@ -350,6 +367,19 @@ class Attention(nn.Module):
         if h % h_kv:
             raise ValueError(f"{h} query heads do not split over {h_kv} "
                              f"key/value heads")
+        if a.indexer is not None:
+            if cache is not None:
+                raise NotImplementedError(
+                    "models.transformer.Attention: decode with an indexer "
+                    "cache is not built (the paged cache keeps no indexer "
+                    "keys, serve/kvcache.py); a layer with an indexer "
+                    "trains only")
+            if cfg.sequence_axis is not None:
+                raise NotImplementedError(
+                    "models.transformer.Attention: ring attention "
+                    "(parallel/ring.py) rotates key/value blocks and has no "
+                    "per-query selection of keys; build a layer with an "
+                    "indexer with sequence_axis=None")
         if a.window is not None:
             if cache is not None:
                 raise NotImplementedError(
@@ -368,16 +398,35 @@ class Attention(nn.Module):
         with scopes.device(scopes.ATTN):
             q, k, v = dense("query")(x), dense("key", h_kv)(x), dense(
                 "value", h_kv)(x)
+            if a.qk_norm:
+                norm = lambda name: nn.RMSNorm(  # noqa: E731
+                    epsilon=cfg.norm_eps, dtype=cfg.dtype, name=name)
+                q, k = norm("query_norm")(q), norm("key_norm")(k)
             q, k = a.rotate(q, positions), a.rotate(k, positions)
             if h_kv != h and cache is not None:
                 raise NotImplementedError(
                     "the paged cache holds one key/value head a query "
                     "head (serve/kvcache.py); grouped-query attention "
                     "trains only")
-        with scopes.device(scopes.ATTN_FULL if a.window is None
-                           else scopes.ATTN_WINDOW):
-            out, new_kv = attend(cfg, a, q, k, v, positions,
-                                 contiguous_positions, cache)
+        if a.indexer is not None:
+            from horovod_tpu.models.sparse_attention import (
+                LOSSES, Indexer, attend_sparse)
+            with scopes.device(scopes.DSA_INDEX):
+                q_index, k_index, w = Indexer(
+                    a.indexer, a.rope_theta, cfg.dtype, name="indexer")(
+                        x, positions)
+            # the kernels' scopes are their own (ops/sparse_attention.py)
+            out, kl, selection = attend_sparse(cfg, a, q, k, v, q_index,
+                                               k_index, w)
+            new_kv = None
+            # for a caller that asks (mutable=[...]); else no-ops
+            self.sow(LOSSES, "indexer_kl", jnp.mean(kl))
+            self.sow("intermediates", "selection", selection)
+        else:
+            with scopes.device(scopes.ATTN_FULL if a.window is None
+                               else scopes.ATTN_WINDOW):
+                out, new_kv = attend(cfg, a, q, k, v, positions,
+                                     contiguous_positions, cache)
         with scopes.device(scopes.ATTN):
             if a.gate:
                 gate = nn.Dense(h, dtype=cfg.dtype, use_bias=False,
@@ -402,8 +451,16 @@ class Block(nn.Module):
         new_kv = None
         if self.mixer is not None:
             y = norm()(x)
-            if cfg.attention_kind(self.mixer) is not None:
-                attention = Attention(cfg, self.mixer, name="attn")
+            a = cfg.attention_kind(self.mixer)
+            if a is not None:
+                module = Attention
+                if a.recompute:
+                    from horovod_tpu.ops.sparse_attention import SAVED
+                    module = nn.remat(
+                        Attention, static_argnums=(3, 4),
+                        policy=jax.checkpoint_policies.save_only_these_names(
+                            *SAVED))
+                attention = module(cfg, self.mixer, name="attn")
             elif self.mixer == "mla":
                 from horovod_tpu.models.mla import LatentAttention
                 attention = LatentAttention(cfg, name="attn")
@@ -450,8 +507,9 @@ class Block(nn.Module):
             # none of its buffers of token-slots
             routed = ExpertShare(e, dtype=cfg.dtype, name="experts")(
                 y.reshape(b * s, d)).reshape(b, s, d)
-            y = routed + shared_expert(e, dtype=cfg.dtype,
-                                       name="shared_experts")(y)
+            y = routed if not (e.n_shared_experts or e.shared_d_ff) else (
+                routed + shared_expert(e, dtype=cfg.dtype,
+                                       name="shared_experts")(y))
         else:
             raise ValueError(f"unknown feed-forward {self.feed_forward!r}")
         if cache is not None:

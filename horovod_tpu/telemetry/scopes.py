@@ -62,6 +62,16 @@ ATTN = "hvd_attn"
 # ``op_name`` that ends ``attn/pallas_call``
 ATTN_FULL = "hvd_attn_full/attn"
 ATTN_WINDOW = "hvd_attn_window/attn"
+# the attention of a layer with an indexer, forward and backward: the
+# sparse kernels' calls (ops/sparse_attention.py) or the plain gather in
+# their place
+ATTN_SPARSE = "hvd_attn_sparse/attn"
+# the lightning indexer (models/sparse_attention.py): its projections, the
+# LayerNorm on its key, rotary, its scores and the KL's terms, forward and
+# backward
+DSA_INDEX = "hvd_dsa_index"
+# the indexer's selection of each query's keys: the top-k of its scores
+DSA_TOPK = "hvd_dsa_topk"
 # host spans
 STEP = "hvd_step"      # one whole step(...) call; carries step_num
 PLACE = "hvd_place"    # device_put of every leaf onto its sharding

@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 from horovod_tpu import basics, hvd_jax
 from horovod_tpu import telemetry as telemetry_lib
 from horovod_tpu.diag import recorder as _flightrec
+from horovod_tpu.models.sparse_attention import LOSSES as INDEXER_LOSSES
 from horovod_tpu.ops import collective, fusion
 from horovod_tpu.parallel import gspmd as gspmd_lib
 from horovod_tpu.parallel import mesh as mesh_lib
@@ -211,6 +212,23 @@ def _next_token_ll_bwd(residuals, g):
 
 
 _next_token_ll.defvjp(_next_token_ll_fwd, _next_token_ll_bwd)
+
+
+def _lm_forward(model, params, tokens):
+    """``(logits, the model's own loss terms or None)``: the forward pass
+    of an LM step, with every value the model sows into the indexer's
+    collection (``models/sparse_attention.LOSSES``: the KL of each
+    sparse-attention layer) summed into one term that the step adds to its
+    next-token loss and differentiates with it. ``"losses"`` (models/
+    moe.py's auxiliary terms) is not read: their weights are the caller's
+    (``moe.aux_loss``). A model that sows none gives None, and the step is
+    the program it always was."""
+    logits, sown = model.apply({"params": params}, tokens,
+                               mutable=[INDEXER_LOSSES])
+    terms = jax.tree_util.tree_leaves(sown.get(INDEXER_LOSSES, {}))
+    if not terms:
+        return logits, None
+    return logits, sum(jnp.sum(term) for term in terms)
 
 
 def _next_state(state, updates, opt_state, batch_stats, loss,
@@ -1177,10 +1195,10 @@ def _make_spmd_lm_train_step(model, tx, mesh, batch_axis, donate):
     chunked = wire is not None and getattr(wire, "chunked", False)
 
     def _local_loss(params, tokens):
-        ll = _next_token_ll(model.apply({"params": params}, tokens),
-                            tokens[:, 1:])
+        logits, own = _lm_forward(model, params, tokens)
+        ll = _next_token_ll(logits, tokens[:, 1:])
         with scopes.device(scopes.LOSS):
-            return -jnp.mean(ll)
+            return -jnp.mean(ll) if own is None else own - jnp.mean(ll)
 
     if chunked:
         def local_step(state, tokens):
@@ -1381,8 +1399,8 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
             mask = jnp.ones(targets.shape, jnp.float32)
 
         def compute_loss(params):
-            ll = _next_token_ll(model.apply({"params": params}, tokens),
-                                targets)
+            logits, own = _lm_forward(model, params, tokens)
+            ll = _next_token_ll(logits, targets)
             with scopes.device(scopes.LOSS):
                 local_sum = -jnp.sum(ll * mask)
                 global_count = collective.allreduce(
@@ -1390,8 +1408,10 @@ def make_lm_train_step(model, tx, mesh=None, batch_axis="data",
                     op=collective.Sum, axes=grad_axes)
                 # scaled so that the Average-allreduce of per-shard losses
                 # (and of per-shard gradients, inside ``tx``) equals the
-                # exact global-mean loss/gradient
-                return local_sum * n_shards / global_count
+                # exact global-mean loss/gradient; the model's own terms
+                # are means over the shard's tokens already
+                loss = local_sum * n_shards / global_count
+                return loss if own is None else loss + own
 
         loss, grads = jax.value_and_grad(compute_loss)(state.params)
         updates, opt_state = tx.update(grads, state.opt_state, state.params)

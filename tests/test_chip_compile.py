@@ -697,6 +697,114 @@ def test_delta_attention_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     assert not any("_proj" in n for n in scan)
 
 
+def test_sparse_attention_kernels_compile_for_v5e(v5e):
+    """The sparse attention's kernels (``ops/sparse_attention.py``: the
+    selection, the attention forward, the KL's forward, dQ, dK and dV, the
+    KL's backward) compiled for the chip at the cell's sizes, 1 x 32768
+    positions, 32 query heads over 4 key/value heads of 128, an indexer of
+    16 heads of 64 and 2048 keys a query: what interpret mode cannot see
+    (the sequence-sized VMEM of the selection's keys and of dkI, the
+    bit-packed masks, the lane-sliced head blocks)."""
+    from horovod_tpu.ops import sparse_attention as sa
+
+    x = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=v5e)
+    s = 32768
+    args = (x(1, s, 32, 128), x(1, s, 4, 128), x(1, s, 4, 128),
+            x(1, s, 16, 64), x(1, s, 64), x(1, s, 16, dtype=jnp.float32))
+
+    def train(*a):
+        def loss(*a):
+            out, kl, _ = sa.sparse_attention(*a, top_k=2048,
+                                             index_scale=0.125, kernel=True,
+                                             interpret=False)
+            return jnp.sum(out.astype(jnp.float32)) + jnp.mean(kl)
+        return jax.value_and_grad(loss, argnums=range(6))(*a)
+
+    text = jax.jit(train).lower(*args).compile().as_text()
+    names = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    scope = lambda n: re.search(  # noqa: E731
+        r"hvd_dsa_topk|hvd_dsa_index|hvd_attn_sparse", n).group()
+    assert sorted(map(scope, names)) == sorted(
+        ["hvd_dsa_topk", "hvd_attn_sparse", "hvd_dsa_index",
+         "hvd_attn_sparse", "hvd_attn_sparse", "hvd_dsa_index"])
+
+
+def test_keye_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
+    """The whole train step of ``keye-vl-2.0-30b-a3b-train-s32768`` as its
+    benchmark family builds it (5 layers at the published widths, each
+    attention layer recomputed in the backward pass keeping the selection,
+    the expert share's buffers at a held room of 4, 1 x 32768 tokens,
+    AdamW), compiled for one described v5e chip: it fits the chip's 15.75
+    GiB (14.49 GiB, PERF.md section 4), the sparse
+    kernels and megablox are in it as kernels, nothing fell back, the
+    selection runs once a layer and the attention's forward kernel twice
+    (the recomputation), and each new scope is on the indexed layers'
+    kernels alone."""
+    import json
+    import warnings
+
+    import horovod_tpu as hvd
+    from benchmark.families import dsa_moe_lm as family
+    from horovod_tpu.models.experts import GroupedFallbackWarning
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    load = lambda *parts: json.load(open(os.path.join(  # noqa: E731
+        repo, "benchmark", *parts)))
+    config = load("configs", "keye-vl-2.0-30b-a3b.json")
+    traffic = load("traffic", "b1-s32768.json")
+    (device,) = v5e.device_set
+    monkeypatch.setattr(jax, "devices", lambda *a: [device])
+    hvd.shutdown()
+    hvd.init(devices=[device])
+    try:
+        with warnings.catch_warnings():
+            warnings.simplefilter("error", fa.FlashFallbackWarning)
+            warnings.simplefilter("error", GroupedFallbackWarning)
+            built = family.build(config, traffic, hvd.mesh(), 7)
+            replicated = NamedSharding(hvd.mesh(), P())
+            state = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=replicated),
+                jax.eval_shape(built.init_state))
+            tokens = jax.ShapeDtypeStruct(
+                (traffic["per_chip_batch"], traffic["seq_len"]), jnp.int32,
+                sharding=NamedSharding(hvd.mesh(), P("data")))
+            compiled = built.step.jitted.lower(state, tokens).compile()
+    finally:
+        hvd.shutdown()
+    m = compiled.memory_analysis()
+    footprint = (m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert 13.5 < footprint / 2 ** 30 < 15.0  # 14.49 (PERF.md)
+    parameters = sum(int(np.prod(a.shape)) for a in
+                     jax.tree_util.tree_leaves(state.params))
+    assert parameters == config["parameters"] == 562_290_560
+    text = compiled.as_text()
+    calls = re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
+    experts = [n for n in calls if "hvd_moe_experts" in n]
+    sparse = [n for n in calls if "hvd_moe_experts" not in n]
+    # megablox: three a product, two products a layer, forward,
+    # recomputed, backward, at both sizes of the share's buffers
+    assert len(experts) == 5 * 16
+    scope = lambda n: re.search(  # noqa: E731
+        r"hvd_dsa_topk|hvd_dsa_index|hvd_attn_sparse", n).group()
+    count = {name: sum(scope(n) == name for n in sparse)
+             for name in ("hvd_dsa_topk", "hvd_attn_sparse", "hvd_dsa_index")}
+    # a layer: the selection once; the attention forward, again in the
+    # recomputation, dQ and dK/dV; the KL forward and backward
+    assert count == {"hvd_dsa_topk": 5, "hvd_attn_sparse": 20,
+                     "hvd_dsa_index": 10}
+    assert {re.search(r"block_\d", n).group() for n in sparse} == {
+        f"block_{i}" for i in range(5)}
+    assert all(n.endswith("pallas_call") for n in sparse)
+    assert sum("rematted_computation" in n for n in sparse) == 5
+
+
 def test_chip_smoke_refuses_to_run_without_a_chip():
     """chip_smoke.py on a machine whose jax finds no TPU runs no phase,
     prints no result and exits non-zero naming the platform it found —

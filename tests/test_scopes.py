@@ -22,6 +22,7 @@ from jax.experimental.compilation_cache import compilation_cache
 
 import horovod_tpu as hvd_api
 from horovod_tpu import training
+from horovod_tpu.models.sparse_attention import LOSSES
 from horovod_tpu.models.transformer import Transformer, TransformerConfig
 from horovod_tpu.telemetry import scopes
 
@@ -528,3 +529,57 @@ def test_attention_scopes_split_each_kind_of_layer():
     assert products and all(
         n in rest or n in full or n in window for n in products)
     assert any("transpose(jvp(" in n for n in window)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernels"])
+def test_sparse_attention_scopes_are_on_an_indexed_layer_alone(
+        kernel, monkeypatch):
+    """A layer with an indexer carries ``hvd_dsa_index`` (its indexer's
+    projections, norm and rotary, the KL's terms, forward and backward),
+    ``hvd_dsa_topk`` (the selection) and ``hvd_attn_sparse`` (the
+    attention itself: the plain gather here, the sparse kernels under
+    interpretation, which is how the chip sees them); a layer without one
+    carries none of the three, and the main attention's projections stay
+    under ``hvd_attn``."""
+    from horovod_tpu.models.sparse_attention import IndexerConfig
+    from horovod_tpu.models.transformer import AttentionConfig
+    from horovod_tpu.ops import sparse_attention as sa
+
+    monkeypatch.setattr(sa, "BLOCK", 256)
+    assert scopes.ATTN_SPARSE.endswith("/attn")
+    kinds = (AttentionConfig(kind="sparse", num_heads=2, head_dim=8,
+                             num_kv_heads=1, qk_norm=True,
+                             indexer=IndexerConfig(num_heads=2, head_dim=8,
+                                                   top_k=32, rotary_dim=4)),)
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=2, d_model=16, d_ff=32,
+        dtype=jnp.float32, flash_attention=kernel, attention=kinds,
+        layer_pattern=(("sparse", "swiglu"), ("mha", "swiglu")))
+    model = Transformer(cfg)
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)[
+        "params"]
+
+    def loss(p):
+        logits, sown = model.apply({"params": p}, tokens, mutable=[LOSSES])
+        return jnp.sum(logits) + sum(jax.tree_util.tree_leaves(sown))
+
+    text = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
+    names = _OP_NAME_RE.findall(text)
+    under = lambda scope: {n for n in names if re.search(  # noqa: E731
+        r"(?<![\w.])" + scope + r"(?![\w.])", n)}
+    index, topk, sparse = (under("hvd_dsa_index"), under("hvd_dsa_topk"),
+                           under("hvd_attn_sparse"))
+    assert index and topk and sparse
+    for found in (index, topk, sparse):
+        assert {re.search(r"block_\d", n).group() for n in found} == {
+            "block_0"}
+    assert not index & topk and not index & sparse and not topk & sparse
+    assert any("transpose(jvp(" in n for n in index)
+    assert any("transpose(jvp(" in n for n in sparse)
+    for part in ("wq", "wk", "k_norm", "weights_proj"):
+        assert any(f"hvd_dsa_index/indexer/{part}/" in n for n in index), part
+    rest = under("hvd_attn")
+    for part in ("query", "key", "value", "query_norm", "key_norm", "out"):
+        assert any(f"block_0/attn/hvd_attn/{part}/" in n for n in rest), part
+    assert not rest & (index | topk | sparse)

@@ -1,0 +1,159 @@
+"""The sparse-attention kernels (``ops/sparse_attention.py``) in interpret
+mode against the plain reference's gather (``tests/reference_dsa_moe_lm``:
+the indexer's scores, ``lax.top_k`` and attention over the selected keys,
+float32): the output, dq, dk, dv, the indexer's KL a query (which reads
+the head-summed probabilities) and its gradients; and the selection
+against ``lax.top_k``, ties included."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import reference_dsa_moe_lm as reference
+from horovod_tpu.models.transformer import dense_attention
+from horovod_tpu.ops import sparse_attention as sa
+
+SCALE = 0.125  # 64^-1/2 at an indexer head of 64; any number here
+
+
+@pytest.fixture
+def blocks(monkeypatch):
+    """Blocks of 256: two query and two key blocks at 512 positions."""
+    monkeypatch.setattr(sa, "BLOCK", 256)
+
+
+def _operands(seed, s=512, h=4, g=2, d=128, hi=4, di=64):
+    rng = np.random.default_rng(seed)
+    mk = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.standard_normal(shape), jnp.float32).astype(jnp.bfloat16)
+    return (mk(1, s, h, d), mk(1, s, g, d), mk(1, s, g, d), mk(1, s, hi, di),
+            mk(1, s, di), jnp.asarray(rng.standard_normal((1, s, hi)),
+                                      jnp.float32))
+
+
+def _reference(q, k, v, qi, ki, w, top_k):
+    """``(out, kl)`` of one sequence by the reference's functions, float32:
+    its own selection of the same scores (no rounding in the way: the
+    operands are bfloat16 numbers already)."""
+    f32 = lambda x: x[0].astype(jnp.float32)  # noqa: E731
+    q, k, v, qi, ki, w = map(f32, (q, k, v, qi, ki, w))
+    s, heads = q.shape[0], q.shape[1]
+    # the reference divides by sqrt(head_dim); the kernels take the scale
+    scores = reference.index_scores(qi, ki, w) * qi.shape[-1] ** 0.5 * SCALE
+    selected, _ = reference._own(scores, jnp.arange(s), top_k)
+    selected = jax.lax.stop_gradient(selected)
+    out, probs = reference.attend(q, k, v, selected)
+    p = jax.lax.stop_gradient(probs / heads)
+    log_q = jax.nn.log_softmax(jnp.where(selected, scores, -jnp.inf), -1)
+    live = selected & (p > 0)
+    kl = jnp.sum(jnp.where(live, p * (jnp.log(jnp.where(live, p, 1.0))
+                                      - jnp.where(live, log_q, 0.0)), 0.0),
+                 -1)
+    return out, kl, selected
+
+
+def _loss(fn, weights):
+    def f(*x):
+        out, kl = fn(*x)[:2]
+        return (jnp.sum(out.astype(jnp.float32).reshape(weights.shape)
+                        * weights) + 10.0 * jnp.mean(kl))
+    return f
+
+
+def _kernel(q, k, v, qi, ki, w, top_k):
+    return sa.sparse_attention(q, k, v, qi, ki, w, top_k=top_k,
+                               index_scale=SCALE, kernel=True)
+
+
+@pytest.mark.parametrize("top_k,h,g", [
+    (100, 8, 1), (100, 2, 2), (256, 4, 2), (300, 4, 2)],
+    ids=["k100_8_to_1", "k100_1_to_1", "k256_one_block", "k300_no_block"])
+def test_kernels_match_the_reference_gather(blocks, top_k, h, g):
+    """Queries before, at and past ``top_k`` (t < k takes the whole causal
+    prefix), a k that is a block and one that divides no block, and
+    grouped heads at 8 : 1 and 1 : 1: the output, dq, dk, dv, the KL a
+    query and dqI, dkI, dw against the reference, and the packed
+    selection is the reference's own."""
+    x = _operands(top_k + h, h=h, g=g)
+    out, kl, words = _kernel(*x, top_k)
+    want_out, want_kl, selected = _reference(*x, top_k)
+    np.testing.assert_array_equal(np.asarray(sa.unpack(words, 512)[0]),
+                                  np.asarray(selected))
+    np.testing.assert_allclose(np.asarray(out[0], np.float32),
+                               np.asarray(want_out), atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(np.asarray(kl[0]), np.asarray(want_kl),
+                               atol=2e-3, rtol=2e-2)
+    weights = jnp.cos(jnp.arange(out.size, dtype=jnp.float32)).reshape(
+        out.shape)
+    got = jax.grad(_loss(lambda *a: _kernel(*a, top_k), weights),
+                   argnums=range(6))(*x)
+    want = jax.grad(_loss(lambda *a: (
+        _reference(*a, top_k)[0][None], _reference(*a, top_k)[1][None]),
+        weights), argnums=range(6))(*(a.astype(jnp.float32) for a in x))
+    for name, a, b in zip(("dq", "dk", "dv", "dqI", "dkI", "dw"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2, name
+
+
+def test_a_top_k_of_every_key_is_causal_attention(blocks):
+    """With ``top_k`` at least the sequence every query takes its whole
+    causal prefix: the kernels' output is ``dense_attention``'s."""
+    q, k, v, qi, ki, w = _operands(7)
+    out, _, words = _kernel(q, k, v, qi, ki, w, 1024)
+    causal = np.tril(np.ones((512, 512), bool))
+    np.testing.assert_array_equal(np.asarray(sa.unpack(words, 512)[0]),
+                                  causal)
+    pos = jnp.arange(512)[None]
+    kk, vv = (jnp.repeat(t, 2, axis=2) for t in (k, v))
+    want = dense_attention(q.astype(jnp.float32), kk.astype(jnp.float32),
+                           vv.astype(jnp.float32), causal=True,
+                           q_positions=pos, kv_positions=pos)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want), atol=2e-2)
+
+
+@pytest.mark.parametrize("top_k", [5, 64, 200])
+def test_the_selection_is_top_k_with_ties_to_the_earlier_key(blocks, top_k):
+    """Scores of small whole numbers (exact in bfloat16 and in float32
+    sums, so every side computes the same numbers) tie by the hundred: the
+    kernel selects what ``lax.top_k`` does, the earlier of equal keys
+    first, and ``pack``/``unpack`` are each other's inverse."""
+    rng = np.random.default_rng(top_k)
+    ints = lambda *shape: jnp.asarray(  # noqa: E731
+        rng.integers(-2, 3, shape), jnp.bfloat16)
+    s = 512
+    qi, ki = ints(1, s, 2, 64), ints(1, s, 64)
+    ki = ki.at[:, 1::3].set(ki[:, ::3][:, :ki[:, 1::3].shape[1]])
+    w = jnp.asarray(rng.integers(0, 3, (1, s, 2)), jnp.float32)
+    q = k = v = jnp.zeros((1, s, 1, 128), jnp.bfloat16)
+    words = sa.sparse_attention(q, k, v, qi, ki, w, top_k=top_k,
+                                index_scale=1.0, kernel=True)[2]
+    scores = sa.index_scores(qi, ki, w, 1.0)[0]
+    assert len(np.unique(np.asarray(scores))) < s  # ties, many
+    masked = jnp.where(jnp.tril(jnp.ones((s, s), bool)), scores, -jnp.inf)
+    _, idx = jax.lax.top_k(masked, top_k)
+    want = np.zeros((s, s), bool)
+    rows = np.arange(s)[:, None]
+    want[rows, np.asarray(idx)] = np.asarray(idx) <= rows
+    np.testing.assert_array_equal(np.asarray(sa.unpack(words, s)[0]), want)
+    np.testing.assert_array_equal(np.asarray(sa.pack(jnp.asarray(want)[None])),
+                                  np.asarray(words))
+
+
+@pytest.mark.parametrize("s", [160, 768])
+def test_a_sequence_off_the_blocks_takes_the_plain_path_and_says_so(s):
+    """Asked for the kernels at a sequence that blocks of 512 do not
+    divide (768 among them: a whole number of 256-key groups), the plain
+    path runs, with a ``FlashFallbackWarning``; its result is the same
+    function."""
+    from horovod_tpu.ops.flash_attention import FlashFallbackWarning
+
+    x = _operands(3, s=s, h=2, g=1)
+    with pytest.warns(FlashFallbackWarning, match="blocks of 512"):
+        out, kl, _ = _kernel(*x, 40)
+    want_out, want_kl, _ = _reference(*x, 40)
+    np.testing.assert_allclose(np.asarray(out[0], np.float32),
+                               np.asarray(want_out), atol=2e-2)
+    np.testing.assert_allclose(np.asarray(kl[0]), np.asarray(want_kl),
+                               atol=2e-3, rtol=2e-2)
