@@ -37,14 +37,19 @@ key block, never the ``S x S`` matrix:
 * ``_fwd_kernel``, ``_dq_kernel``, ``_dkv_kernel`` (scope
   ``hvd_attn_sparse/attn``): flash attention (``ops/flash_attention.py``'s
   transposed score tiles and online softmax) under the selection's mask,
-  a query head a grid row, the causal block schedule's skipped blocks
-  fetching nothing; q, k, v and o in the model's own ``[B, S, H * D]``
-  layout (a head a block of ``D`` columns, no copy around the kernels) and
-  shared key/value heads read in place. The backward is
-  two kernels, dQ with the query block resident and dK, dV with the key
-  block resident over its query heads and query blocks. A block pair
-  under the diagonal runs whether or not a key of it was selected: with
-  random weights every one holds selected keys.
+  the causal block schedule's skipped blocks fetching nothing; q, k, v
+  and o in the model's own ``[B, S, H * D]`` layout (a head a block of
+  ``D`` columns, no copy around the kernels). Every head reads the same
+  selection, so a grid step runs as many query heads as fit VMEM against
+  one block pair (``_heads_per_step``: whole groups of a key/value
+  head's query heads where they fit, all 32 at 32 heads of 128 over 4):
+  the pair's words are unpacked once into an additive mask and each
+  shared k and v tile is read once, and a group's heads follow one
+  another in the step's body (``_each_head``). The backward is two
+  kernels, dQ with the query block resident and dK, dV with the key block
+  resident over the query blocks, the group's heads summed inside the
+  step. A block pair under the diagonal runs whether or not a key of it
+  was selected: with random weights every one holds selected keys.
 * ``_kl_fwd_kernel``, ``_kl_bwd_kernel`` (scope ``hvd_dsa_index``): the
   head-averaged probabilities of a block pair (the 32 heads innermost in
   the grid, their probabilities summed in VMEM from the forward's
@@ -342,10 +347,77 @@ def _first_q(j, block_q, block_k):
     return (j * block_k) // block_q
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, m_ref, l_ref,
-                acc_ref, *, block_q, block_k, scale):
-    """One (b, h, q-block, kv-block) step of flash attention under the
-    selection (``ops/flash_attention._kernel``'s transposed tiles)."""
+def _bias(words, rows):
+    """The selection's mask of a block pair as an additive float32 bias: 0
+    where the key was selected, ``NEG_INF`` elsewhere. Added to a score
+    tile it gives exactly what ``jnp.where(mask, s, NEG_INF)`` gives: no
+    score comes near the magnitude at which ``s + NEG_INF`` would round
+    off ``NEG_INF``."""
+    return jnp.where(_unpack(words, rows) != 0, 0.0, NEG_INF)
+
+
+def _columns(h, d):
+    """Head ``h``'s ``d`` columns of a tile that holds several heads."""
+    return pl.ds(pl.multiple_of(h * d, d), d)
+
+
+def _step_bytes(heads, group, d, block, itemsize):
+    """VMEM of one grid step of dQ, the largest of the three attention
+    kernels: q, its cotangent and dQ double-buffered and dQ's float32
+    accumulator a head, the k and v tiles double-buffered, the float32
+    log-sum-exp and delta rows, the words and six float32 block-pair
+    tiles."""
+    kv_tiles = -(-heads // group)
+    return (6 * block * block * 4 + 2 * block * block // 8
+            + kv_tiles * 4 * block * d * itemsize
+            + heads * (block * d * (6 * itemsize + 4) + 4 * 8 * block * 4))
+
+
+def _heads_per_step(h, group, d, block, itemsize):
+    """The query heads one grid step of the attention kernels runs, so
+    that a block pair's mask is unpacked and its k and v tiles read once
+    for all of them: the most that fit ``_VMEM_BYTES``, in whole groups
+    (a key/value head's ``group`` query heads; all ``h`` where they fit)
+    or else a divisor of one group."""
+    kv = h // group
+    sizes = [n for n in range(1, group + 1) if group % n == 0]
+    sizes += [m * group for m in range(2, kv + 1) if kv % m == 0]
+    return max(n for n in sizes if n == 1 or _step_bytes(
+        n, group, d, block, itemsize) <= _VMEM_BYTES)
+
+
+def _head_blocks(h, group, d, block, itemsize):
+    """``(heads, kv_heads, steps)``: the query heads of a grid step, the
+    key/value heads whose k and v tiles it reads, and the grid steps one
+    group takes."""
+    heads = _heads_per_step(h, group, d, block, itemsize)
+    return heads, -(-heads // group), -(-group // heads)
+
+
+def _each_head(heads, group, k_ref, v_ref, d, body):
+    """``body(h, c, k, v)`` for each query head ``h`` of a grid step, with
+    ``c`` its key/value head among the step's and that head's k and v
+    tiles: a loop over the key/value heads, each read once, and inside
+    it the heads of its group laid out one after the other, so that one
+    head's products overlap the vector work of the head before."""
+    per = min(heads, group)
+
+    def kv_head(c, carry):
+        k, v = k_ref[0, :, _columns(c, d)], v_ref[0, :, _columns(c, d)]
+        for u in range(per):
+            body(c * per + u, c, k, v)
+        return carry
+
+    jax.lax.fori_loop(0, heads // per, kv_head, 0)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, bias_ref,
+                m_ref, l_ref, acc_ref, *, heads, group, d, block_q, block_k,
+                scale):
+    """One (b, head block, q-block, kv-block) step of flash attention
+    under the selection (``ops/flash_attention._kernel``'s transposed
+    tiles): the block pair's mask unpacked once for the ``heads`` query
+    heads in q's ``heads * d`` columns (``_each_head``)."""
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -356,25 +428,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, m_ref, l_ref,
 
     @pl.when(j <= _last_kv(i, block_q, block_k))
     def _update():
-        q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = jax.lax.dot_general(k, q, _NT,
-                                preferred_element_type=jnp.float32) * scale
-        s = jnp.where(_unpack(words_ref[0], block_k) != 0, s, NEG_INF)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-        p = jnp.exp(s - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
-        alpha = jnp.exp(m - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        bias_ref[:] = _bias(words_ref[0], block_k)
+
+        def head(h, c, k, v):
+            s = jax.lax.dot_general(
+                k, q_ref[0, :, _columns(h, d)], _NT,
+                preferred_element_type=jnp.float32) * scale + bias_ref[:]
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+            p = jnp.exp(s - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
+            alpha = jnp.exp(m - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=0, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+        _each_head(heads, group, k_ref, v_ref, d, head)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).T.astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(l == 0.0, NEG_INF, m_ref[:] + jnp.log(l_safe))
+        def head(h, carry):
+            l = l_ref[h]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, :, _columns(h, d)] = (acc_ref[h] / l_safe).T.astype(
+                o_ref.dtype)
+            lse_ref[0, h] = jnp.where(l == 0.0, NEG_INF,
+                                      m_ref[h] + jnp.log(l_safe))
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
 
 
 def _attn_fwd_call(q, k, v, words, d, block, interpret):
@@ -382,23 +464,27 @@ def _attn_fwd_call(q, k, v, words, d, block, interpret):
     is a block of ``D`` columns), words -> ``(o [B, S, H * D], lse [B, H,
     1, S])``."""
     b, s, h = q.shape[0], q.shape[1], q.shape[2] // d
-    g = h // (k.shape[2] // d)
+    group = h // (k.shape[2] // d)
+    heads, kv_heads, steps = _head_blocks(h, group, d, block,
+                                          q.dtype.itemsize)
     n = s // block
-    kern = functools.partial(_fwd_kernel, block_q=block, block_k=block,
+    kern = functools.partial(_fwd_kernel, heads=heads, group=group, d=d,
+                             block_q=block, block_k=block,
                              scale=1.0 / math.sqrt(d))
 
     def kv_map(n_, hh, i, j):
-        return n_, jnp.minimum(j, _last_kv(i, block, block)), hh // g
+        return n_, jnp.minimum(j, _last_kv(i, block, block)), hh // steps
 
-    tile = pl.BlockSpec((1, block, d), lambda n_, hh, i, j: (n_, i, hh))
+    tile = pl.BlockSpec((1, block, heads * d),
+                        lambda n_, hh, i, j: (n_, i, hh))
     return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, h, n, n),
+            num_scalar_prefetch=0, grid=(b, h // heads, n, n),
             in_specs=[
                 tile,
-                pl.BlockSpec((1, block, d), kv_map),
-                pl.BlockSpec((1, block, d), kv_map),
+                pl.BlockSpec((1, block, kv_heads * d), kv_map),
+                pl.BlockSpec((1, block, kv_heads * d), kv_map),
                 pl.BlockSpec((1, block // 32, block),
                              lambda n_, hh, i, j: (
                                  n_, jnp.minimum(j, _last_kv(i, block,
@@ -406,23 +492,26 @@ def _attn_fwd_call(q, k, v, words, d, block, interpret):
             ],
             out_specs=(
                 tile,
-                pl.BlockSpec((1, 1, 1, block),
+                pl.BlockSpec((1, heads, 1, block),
                              lambda n_, hh, i, j: (n_, hh, 0, i)),
             ),
-            scratch_shapes=[pltpu.VMEM((1, block), jnp.float32),
-                            pltpu.VMEM((1, block), jnp.float32),
-                            pltpu.VMEM((d, block), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
+                            pltpu.VMEM((heads, 1, block), jnp.float32),
+                            pltpu.VMEM((heads, 1, block), jnp.float32),
+                            pltpu.VMEM((heads, d, block), jnp.float32)]),
         out_shape=(jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
     )(q, k, v, words)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
-               dq_ref, dq_acc, *, block_q, block_k, scale):
+               dq_ref, bias_ref, dq_acc, *, heads, group, d, block_q,
+               block_k, scale):
     i, j = pl.program_id(2), pl.program_id(3)
 
     @pl.when(j == 0)
@@ -431,23 +520,33 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
 
     @pl.when(j <= _last_kv(i, block_q, block_k))
     def _update():
-        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-        s = jax.lax.dot_general(k, q, _NT,
-                                preferred_element_type=jnp.float32) * scale
-        mask = _unpack(words_ref[0], block_k) != 0
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dp = jax.lax.dot_general(v, g, _NT, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(k.dtype)
-        dq_acc[:] += jax.lax.dot_general(ds, k, _TN,
-                                         preferred_element_type=jnp.float32)
+        bias_ref[:] = _bias(words_ref[0], block_k)
+
+        def head(h, c, k, v):
+            cols = _columns(h, d)
+            s = jax.lax.dot_general(k, q_ref[0, :, cols], _NT,
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(s * scale + bias_ref[:] - lse_ref[0, h])
+            dp = jax.lax.dot_general(v, g_ref[0, :, cols], _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h]) * scale).astype(k.dtype)
+            dq_acc[h] += jax.lax.dot_general(
+                ds, k, _TN, preferred_element_type=jnp.float32)
+
+        _each_head(heads, group, k_ref, v_ref, d, head)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+        def head(h, carry):
+            dq_ref[0, :, _columns(h, d)] = dq_acc[h].astype(dq_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k, scale):
+                dk_ref, dv_ref, bias_ref, dk_acc, dv_acc, *, heads, group, d,
+                block_q, block_k, scale):
     j, r, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
 
     @pl.when((r == 0) & (i == 0))
@@ -457,83 +556,101 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
 
     @pl.when(i >= _first_q(j, block_q, block_k))
     def _update():
-        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-        s = jax.lax.dot_general(k, q, _NT,
-                                preferred_element_type=jnp.float32) * scale
-        mask = _unpack(words_ref[0], block_k) != 0
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
-        dv_acc[:] += jnp.dot(p.astype(g.dtype), g,
-                             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(v, g, _NT, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
-        dk_acc[:] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+        bias_ref[:] = _bias(words_ref[0], block_k)
+
+        def head(h, c, k, v):
+            cols = _columns(h, d)
+            q, g = q_ref[0, :, cols], g_ref[0, :, cols]
+            s = jax.lax.dot_general(k, q, _NT,
+                                    preferred_element_type=jnp.float32)
+            p = jnp.exp(s * scale + bias_ref[:] - lse_ref[0, h])
+            dv_acc[c] += jnp.dot(p.astype(g.dtype), g,
+                                 preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(v, g, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[0, h]) * scale).astype(q.dtype)
+            dk_acc[c] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
+
+        _each_head(heads, group, k_ref, v_ref, d, head)
 
     @pl.when((r == pl.num_programs(3) - 1) & (i == pl.num_programs(4) - 1))
     def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        def head(c, carry):
+            dk_ref[0, :, _columns(c, d)] = dk_acc[c].astype(dk_ref.dtype)
+            dv_ref[0, :, _columns(c, d)] = dv_acc[c].astype(dv_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, dk_acc.shape[0], head, 0)
 
 
 def _attn_bwd_call(q, k, v, g, lse, delta, words, d, block, interpret):
     """dQ, dK, dV of the attention under the selection, in q's, k's and v's
-    layouts: two kernels."""
+    layouts: two kernels, each running ``_heads_per_step`` query heads a
+    grid step."""
     b, s, h = q.shape[0], q.shape[1], q.shape[2] // d
-    kv_heads = k.shape[2] // d
-    group = h // kv_heads
+    group = h // (k.shape[2] // d)
+    heads, kv_heads, steps = _head_blocks(h, group, d, block,
+                                          q.dtype.itemsize)
     n = s // block
     scale = 1.0 / math.sqrt(d)
     last = lambda i: _last_kv(i, block, block)  # noqa: E731
     first = lambda j: _first_q(j, block, block)  # noqa: E731
-    tile = (1, block, d)
-    row = (1, 1, 1, block)
+    kern = dict(heads=heads, group=group, d=d, block_q=block, block_k=block,
+                scale=scale)
+    tile = (1, block, heads * d)
+    row = (1, heads, 1, block)
+    kv_tile = (1, block, kv_heads * d)
     q_tile = pl.BlockSpec(tile, lambda n_, hh, i, j: (n_, i, hh))
-    kv_tile = pl.BlockSpec(tile, lambda n_, hh, i, j: (
-        n_, jnp.minimum(j, last(i)), hh // group))
     q_row = pl.BlockSpec(row, lambda n_, hh, i, j: (n_, hh, 0, i))
+    kv = pl.BlockSpec(kv_tile, lambda n_, hh, i, j: (
+        n_, jnp.minimum(j, last(i)), hh // steps))
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=block, block_k=block,
-                          scale=scale),
+        functools.partial(_dq_kernel, **kern),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, h, n, n),
+            num_scalar_prefetch=0, grid=(b, h // heads, n, n),
             in_specs=[
-                q_tile, kv_tile, kv_tile, q_tile, q_row, q_row,
+                q_tile, kv, kv, q_tile, q_row, q_row,
                 pl.BlockSpec((1, block // 32, block), lambda n_, hh, i, j: (
                     n_, jnp.minimum(j, last(i)), i)),
             ],
             out_specs=q_tile,
-            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
+                            pltpu.VMEM((heads, block, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) + ("arbitrary",) * 3),
+            dimension_semantics=("parallel",) + ("arbitrary",) * 3,
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
     )(q, k, v, g, lse, delta, words)
 
     def q_side(n_, kh, j, r, i):
-        """``(batch, query head, query block)`` of a dK, dV grid step."""
-        return n_, kh * group + r, jnp.maximum(i, first(j))
+        """``(batch, head block, query block)`` of a dK, dV grid step."""
+        return n_, kh * steps + r, jnp.maximum(i, first(j))
 
     q_tile = pl.BlockSpec(tile, lambda *a: (
         q_side(*a)[0], q_side(*a)[2], q_side(*a)[1]))
     q_row = pl.BlockSpec(row, lambda *a: (*q_side(*a)[:2], 0, q_side(*a)[2]))
-    kv_tile = pl.BlockSpec(tile, lambda n_, kh, j, r, i: (n_, j, kh))
+    kv = pl.BlockSpec(kv_tile, lambda n_, kh, j, r, i: (n_, j, kh))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block, block_k=block,
-                          scale=scale),
+        functools.partial(_dkv_kernel, **kern),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, kv_heads, n, group, n),
+            num_scalar_prefetch=0,
+            grid=(b, k.shape[2] // d // kv_heads, n, steps, n),
             in_specs=[
-                q_tile, kv_tile, kv_tile, q_tile, q_row, q_row,
+                q_tile, kv, kv, q_tile, q_row, q_row,
                 pl.BlockSpec((1, block // 32, block),
                              lambda n_, kh, j, r, i: (
                                  n_, j, jnp.maximum(i, first(j)))),
             ],
-            out_specs=(kv_tile, kv_tile),
-            scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                            pltpu.VMEM((block, d), jnp.float32)]),
+            out_specs=(kv, kv),
+            scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
+                            pltpu.VMEM((kv_heads, block, d), jnp.float32),
+                            pltpu.VMEM((kv_heads, block, d), jnp.float32)]),
         out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",) + ("arbitrary",) * 4),
+            dimension_semantics=("parallel",) + ("arbitrary",) * 4,
+            vmem_limit_bytes=_VMEM_BYTES),
         interpret=interpret,
     )(q, k, v, g, lse, delta, words)
     return dq, dk, dv

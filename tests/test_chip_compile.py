@@ -697,16 +697,51 @@ def test_delta_attention_cell_step_compiles_for_v5e_and_fits(v5e, monkeypatch):
     assert not any("_proj" in n for n in scan)
 
 
-def test_sparse_attention_kernels_compile_for_v5e(v5e):
+def _vmem_bytes(shape, dtype):
+    """Bytes of a VMEM buffer of ``shape``: its last two dimensions padded
+    to whole (8 x 32-bit sublanes, 128 lanes) tiles."""
+    itemsize = np.dtype(dtype).itemsize
+    sub = 8 * 4 // itemsize
+    *lead, rows, cols = shape
+    return (int(np.prod(lead)) * -(-rows // sub) * sub * -(-cols // 128)
+            * 128 * itemsize)
+
+
+def test_sparse_attention_kernels_compile_for_v5e(v5e, monkeypatch):
     """The sparse attention's kernels (``ops/sparse_attention.py``: the
     selection, the attention forward, the KL's forward, dQ, dK and dV, the
     KL's backward) compiled for the chip at the cell's sizes, 1 x 32768
     positions, 32 query heads over 4 key/value heads of 128, an indexer of
     16 heads of 64 and 2048 keys a query: what interpret mode cannot see
     (the sequence-sized VMEM of the selection's keys and of dkI, the
-    bit-packed masks, the lane-sliced head blocks)."""
+    bit-packed masks, the lane-sliced head blocks). The attention kernels
+    run all 32 query heads (the 4 key/value heads' groups) a grid step,
+    so their grids have one head block where 32 query heads stand, and
+    each call's blocks (double-buffered) and scratch stay within the VMEM
+    it asks for."""
     from horovod_tpu.ops import sparse_attention as sa
 
+    calls = []
+    pallas_call = sa.pl.pallas_call
+
+    def recorded(kernel, *, grid_spec, out_shape, compiler_params, **kw):
+        call = pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
+                           compiler_params=compiler_params, **kw)
+
+        def run(*args):
+            many = lambda x: x if isinstance(x, tuple) else (x,)  # noqa: E731
+            blocks = sum(2 * _vmem_bytes(spec.block_shape, a.dtype)
+                         for spec, a in zip(
+                             [*grid_spec.in_specs, *many(grid_spec.out_specs)],
+                             [*args, *many(out_shape)]))
+            scratch = sum(_vmem_bytes(m.shape, m.dtype)
+                          for m in grid_spec.scratch_shapes)
+            calls.append((kernel.func.__name__, grid_spec.grid,
+                          blocks + scratch, compiler_params.vmem_limit_bytes))
+            return call(*args)
+        return run
+
+    monkeypatch.setattr(sa.pl, "pallas_call", recorded)
     x = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=v5e)
     s = 32768
@@ -722,6 +757,15 @@ def test_sparse_attention_kernels_compile_for_v5e(v5e):
         return jax.value_and_grad(loss, argnums=range(6))(*a)
 
     text = jax.jit(train).lower(*args).compile().as_text()
+    heads = sa._heads_per_step(32, 8, 128, sa.BLOCK, 2)
+    assert heads == 32
+    grids = {name: grid for name, grid, _, _ in calls}
+    assert grids["_fwd_kernel"] == (1, 32 // heads, 64, 64) == (1, 1, 64, 64)
+    assert grids["_dq_kernel"] == (1, 1, 64, 64)
+    assert grids["_dkv_kernel"] == (1, 4 // (heads // 8), 64, 1, 64)
+    assert len(calls) == 6
+    for name, _, need, limit in calls:
+        assert limit is not None and need <= limit, (name, need, limit)
     names = re.findall(
         r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text)
     scope = lambda n: re.search(  # noqa: E731

@@ -67,14 +67,17 @@ def _kernel(q, k, v, qi, ki, w, top_k):
 
 
 @pytest.mark.parametrize("top_k,h,g", [
-    (100, 8, 1), (100, 2, 2), (256, 4, 2), (300, 4, 2)],
-    ids=["k100_8_to_1", "k100_1_to_1", "k256_one_block", "k300_no_block"])
+    (100, 8, 1), (100, 2, 2), (256, 4, 2), (300, 4, 2), (100, 16, 2),
+    (100, 6, 2), (200, 32, 4)],
+    ids=["k100_8_to_1", "k100_1_to_1", "k256_one_block", "k300_no_block",
+         "k100_8_to_1_two_groups", "k100_3_to_1", "k200_cell_ratio"])
 def test_kernels_match_the_reference_gather(blocks, top_k, h, g):
     """Queries before, at and past ``top_k`` (t < k takes the whole causal
     prefix), a k that is a block and one that divides no block, and
-    grouped heads at 8 : 1 and 1 : 1: the output, dq, dk, dv, the KL a
-    query and dqI, dkI, dw against the reference, and the packed
-    selection is the reference's own."""
+    grouped heads at 8 : 1 (one key/value head, and two), 3 : 1, 1 : 1 and
+    the cell's 32 over 4: the output, dq, dk, dv, the KL a query and dqI,
+    dkI, dw against the reference, and the packed selection is the
+    reference's own. A grid step runs a whole group of query heads."""
     x = _operands(top_k + h, h=h, g=g)
     out, kl, words = _kernel(*x, top_k)
     want_out, want_kl, selected = _reference(*x, top_k)
@@ -94,6 +97,61 @@ def test_kernels_match_the_reference_gather(blocks, top_k, h, g):
     for name, a, b in zip(("dq", "dk", "dv", "dqI", "dkI", "dw"), got, want):
         a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
         assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2, name
+
+
+@pytest.mark.parametrize("h,g,d", [
+    (32, 4, 128), (16, 16, 128), (6, 2, 128), (64, 8, 256)],
+    ids=["cell", "mha", "group_3", "d256"])
+def test_the_heads_of_a_grid_step_follow_the_shapes(h, g, d):
+    """The query heads of a grid step: all of them where they fit the
+    VMEM budget; else as many whole groups (a key/value head's query
+    heads) as fit; else the largest divisor of one group that fits; one
+    head a step at the least."""
+    group = h // g
+    step = lambda n: sa._step_bytes(n, group, d, 512, 2)  # noqa: E731
+    heads = lambda: sa._heads_per_step(h, group, d, 512, 2)  # noqa: E731
+    fits = [n for n in range(1, h + 1) if step(n) <= sa._VMEM_BYTES and (
+        group % n == 0 or (n % group == 0 and g % (n // group) == 0))]
+    assert heads() == max(fits)
+    if (h, d) == (32, 128):
+        assert heads() == 32  # the cell: one step, four key/value heads
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sa, "_VMEM_BYTES", step(group))
+        assert heads() == group
+        if group % 2 == 0:
+            mp.setattr(sa, "_VMEM_BYTES", step(2))
+            assert heads() == 2
+        mp.setattr(sa, "_VMEM_BYTES", 0)
+        assert heads() == 1
+
+
+def test_a_group_split_over_grid_steps_gives_the_same_numbers(blocks,
+                                                              monkeypatch):
+    """Two groups of 8 run two heads a grid step (a VMEM budget cut to fit
+    no more) give the output and dq of both groups in one step bit for
+    bit, and dk, dv to float32 rounding (the sum over heads and query
+    blocks runs in another order)."""
+    x = _operands(11, h=16, g=2)
+    weights = jnp.cos(jnp.arange(512 * 16 * 128, dtype=jnp.float32)
+                      ).reshape(1, 512, 16, 128)
+
+    def run():
+        out = _kernel(*x, 100)[0]
+        grads = jax.grad(_loss(lambda *a: _kernel(*a, 100), weights),
+                         argnums=range(3))(*x)
+        return [np.asarray(a, np.float32) for a in (out, *grads)]
+
+    assert sa._heads_per_step(16, 8, 128, sa.BLOCK, 2) == 16
+    whole = run()
+    monkeypatch.setattr(sa, "_VMEM_BYTES",
+                        sa._step_bytes(2, 8, 128, sa.BLOCK, 2))
+    assert sa._heads_per_step(16, 8, 128, sa.BLOCK, 2) == 2
+    parts = run()
+    for name, a, b in zip(("out", "dq", "dk", "dv"), parts, whole):
+        if name in ("out", "dq"):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+        else:
+            assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2, name
 
 
 def test_a_top_k_of_every_key_is_causal_attention(blocks):
