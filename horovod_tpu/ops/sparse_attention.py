@@ -51,12 +51,18 @@ key block, never the ``S x S`` matrix:
   step. A block pair under the diagonal runs whether or not a key of it
   was selected: with random weights every one holds selected keys.
 * ``_kl_fwd_kernel``, ``_kl_bwd_kernel`` (scope ``hvd_dsa_index``): the
-  head-averaged probabilities of a block pair (the 32 heads innermost in
-  the grid, their probabilities summed in VMEM from the forward's
-  log-sum-exp), the indexer's scores of the pair again, and the KL's two
-  sums a query; backward, ``dI = g (softmax_S(I) - p)`` and from it dqI,
-  dw (a query block's, resident) and dkI (the whole sequence's, resident
-  in VMEM, 16 MiB at 32k).
+  head-averaged probabilities of a block pair, the indexer's scores of
+  the pair again, and the KL's two sums a query; backward, ``dI = g
+  (softmax_S(I) - p)`` and from it dqI, dw (a query block's, resident)
+  and dkI (the whole sequence's, resident in VMEM, 16 MiB at 32k). The
+  grid is (b, q-block, kv-block, head block), a step running as many
+  query heads as the KL's VMEM count lets (``_kl_step_bytes``; all 32 at
+  32 heads of 128 over 4, so q is fetched once a query block): each
+  head's probabilities from the forward's log-sum-exp, against its
+  key/value head's k columns, are summed in head order into one float32
+  tile (``_probs_tile``), the head blocks innermost where the heads take
+  several steps, and the pair's mask is unpacked once, at its last head
+  block, for the sum and for the backward's ``softmax_S(I)``.
 
 Elsewhere, and where the sequence does not tile the blocks (with a
 ``FlashFallbackWarning`` when the kernel was asked for), ``_plain`` gives
@@ -373,39 +379,57 @@ def _step_bytes(heads, group, d, block, itemsize):
             + heads * (block * d * (6 * itemsize + 4) + 4 * 8 * block * 4))
 
 
-def _heads_per_step(h, group, d, block, itemsize):
-    """The query heads one grid step of the attention kernels runs, so
-    that a block pair's mask is unpacked and its k and v tiles read once
-    for all of them: the most that fit ``_VMEM_BYTES``, in whole groups
-    (a key/value head's ``group`` query heads; all ``h`` where they fit)
-    or else a divisor of one group."""
+def _kl_step_bytes(heads, group, d, block, itemsize, *, seq, index_heads,
+                   index_dim):
+    """VMEM of one grid step of the KL's backward, the larger of its two
+    kernels: the q and k tiles double-buffered, the heads' log-sum-exp
+    rows (8 sublanes each), the indexer's dkI of the whole sequence
+    double-buffered, its query block's qI and dqI double-buffered and
+    dqI's float32 accumulator (lanes padded to 128), and six float32
+    block-pair tiles."""
+    kv_tiles = -(-heads // group)
+    lanes = -(-index_dim // 128) * 128
+    return (2 * block * (heads + kv_tiles) * d * itemsize
+            + heads * 2 * 8 * block * 4 + 2 * seq * lanes * 4
+            + index_heads * block * lanes * (4 * itemsize + 4)
+            + 6 * block * block * 4)
+
+
+def _heads_per_step(h, group, d, block, itemsize, step_bytes=_step_bytes):
+    """The query heads one grid step of a kernel runs, so that a block
+    pair's mask is unpacked and its k (and v) tiles read once for all of
+    them: the most that fit ``_VMEM_BYTES`` by ``step_bytes`` (the
+    attention kernels' ``_step_bytes``, the KL's ``_kl_step_bytes``), in
+    whole groups (a key/value head's ``group`` query heads; all ``h``
+    where they fit) or else a divisor of one group."""
     kv = h // group
     sizes = [n for n in range(1, group + 1) if group % n == 0]
     sizes += [m * group for m in range(2, kv + 1) if kv % m == 0]
-    return max(n for n in sizes if n == 1 or _step_bytes(
+    return max(n for n in sizes if n == 1 or step_bytes(
         n, group, d, block, itemsize) <= _VMEM_BYTES)
 
 
-def _head_blocks(h, group, d, block, itemsize):
+def _head_blocks(h, group, d, block, itemsize, step_bytes=_step_bytes):
     """``(heads, kv_heads, steps)``: the query heads of a grid step, the
     key/value heads whose k and v tiles it reads, and the grid steps one
     group takes."""
-    heads = _heads_per_step(h, group, d, block, itemsize)
+    heads = _heads_per_step(h, group, d, block, itemsize, step_bytes)
     return heads, -(-heads // group), -(-group // heads)
 
 
-def _each_head(heads, group, k_ref, v_ref, d, body):
-    """``body(h, c, k, v)`` for each query head ``h`` of a grid step, with
-    ``c`` its key/value head among the step's and that head's k and v
-    tiles: a loop over the key/value heads, each read once, and inside
-    it the heads of its group laid out one after the other, so that one
-    head's products overlap the vector work of the head before."""
+def _each_head(heads, group, d, body, *refs):
+    """``body(h, c, *tiles)`` for each query head ``h`` of a grid step,
+    with ``c`` its key/value head among the step's and ``tiles`` that
+    head's columns of each of ``refs`` (k and v, or k): a loop over the
+    key/value heads, each tile read once, and inside it the heads of its
+    group laid out one after the other, so that one head's products
+    overlap the vector work of the head before."""
     per = min(heads, group)
 
     def kv_head(c, carry):
-        k, v = k_ref[0, :, _columns(c, d)], v_ref[0, :, _columns(c, d)]
+        tiles = [ref[0, :, _columns(c, d)] for ref in refs]
         for u in range(per):
-            body(c * per + u, c, k, v)
+            body(c * per + u, c, *tiles)
         return carry
 
     jax.lax.fori_loop(0, heads // per, kv_head, 0)
@@ -443,7 +467,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref, bias_ref,
                 v, p.astype(v.dtype), _TN, preferred_element_type=jnp.float32)
             m_ref[h] = m_new
 
-        _each_head(heads, group, k_ref, v_ref, d, head)
+        _each_head(heads, group, d, head, k_ref, v_ref)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
@@ -533,7 +557,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
             dq_acc[h] += jax.lax.dot_general(
                 ds, k, _TN, preferred_element_type=jnp.float32)
 
-        _each_head(heads, group, k_ref, v_ref, d, head)
+        _each_head(heads, group, d, head, k_ref, v_ref)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finalize():
@@ -571,7 +595,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, words_ref,
             ds = (p * (dp - delta_ref[0, h]) * scale).astype(q.dtype)
             dk_acc[c] += jnp.dot(ds, q, preferred_element_type=jnp.float32)
 
-        _each_head(heads, group, k_ref, v_ref, d, head)
+        _each_head(heads, group, d, head, k_ref, v_ref)
 
     @pl.when((r == pl.num_programs(3) - 1) & (i == pl.num_programs(4) - 1))
     def _finalize():
@@ -656,38 +680,51 @@ def _attn_bwd_call(q, k, v, g, lse, delta, words, d, block, interpret):
     return dq, dk, dv
 
 
-def _probs_tile(q_ref, k_ref, lse_ref, words_ref, block_k, scale):
-    """One head's probabilities of a block pair, transposed, 0 outside the
-    selection."""
-    s = jax.lax.dot_general(k_ref[0], q_ref[0], _NT,
-                            preferred_element_type=jnp.float32) * scale
-    mask = _unpack(words_ref[0], block_k) != 0
-    return jnp.where(mask, jnp.exp(s - lse_ref[0, 0]), 0.0)
+def _probs_tile(q_ref, k_ref, lse_ref, p_acc, first, *, heads, group, d,
+                scale):
+    """Add the probabilities of a block pair (transposed) of the step's
+    ``heads`` query heads, in head order, to ``p_acc`` (zeroed at the
+    pair's ``first`` head block): each head's scores against its
+    key/value head's k columns (``_each_head``). No mask: the caller
+    masks the sum once, which gives what masking each head gives (``exp``
+    is never ``-inf``, and a key outside the selection is 0 whatever the
+    sum holds)."""
+    @pl.when(first)
+    def _zero():
+        p_acc[:] = jnp.zeros_like(p_acc)
+
+    def head(h, c, k):
+        s = jax.lax.dot_general(k, q_ref[0, :, _columns(h, d)], _NT,
+                                preferred_element_type=jnp.float32) * scale
+        p_acc[:] += jnp.exp(s - lse_ref[0, h])
+
+    _each_head(heads, group, d, head, k_ref)
 
 
 def _kl_fwd_kernel(q_ref, k_ref, lse_ref, words_ref, qi_ref, ki_ref, w_ref,
                    ent_ref, cross_ref, p_acc, ent_acc, cross_acc, *, block_q,
-                   block_k, scale, heads, index_heads, index_scale):
-    i, j, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+                   block_k, all_heads, index_heads, index_scale, **layout):
+    """One (b, q-block, kv-block, head block) step of the KL's sums: the
+    step's query heads' probabilities added up (``_probs_tile``), and at
+    the pair's last head block the mask unpacked once for the mean of
+    ``all_heads`` and the indexer's scores of the pair."""
+    i, j, hh = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     live = j <= _last_kv(i, block_q, block_k)
+    last = hh == pl.num_programs(3) - 1
 
-    @pl.when((j == 0) & (h == 0))
+    @pl.when((j == 0) & (hh == 0))
     def _init():
         ent_acc[:] = jnp.zeros_like(ent_acc)
         cross_acc[:] = jnp.zeros_like(cross_acc)
 
-    @pl.when(h == 0)
-    def _zero():
-        p_acc[:] = jnp.zeros_like(p_acc)
-
     @pl.when(live)
     def _probs():
-        p_acc[:] += _probs_tile(q_ref, k_ref, lse_ref, words_ref, block_k,
-                                scale)
+        _probs_tile(q_ref, k_ref, lse_ref, p_acc, hh == 0, **layout)
 
-    @pl.when(live & (h == heads - 1))
+    @pl.when(live & last)
     def _terms():
-        p = p_acc[:] * (1.0 / heads)
+        mask = _unpack(words_ref[0], block_k) != 0
+        p = jnp.where(mask, p_acc[:], 0.0) * (1.0 / all_heads)
         score = _index_tile(ki_ref[0], qi_ref, w_ref, index_heads,
                             index_scale)
         pos = p > 0.0
@@ -696,7 +733,7 @@ def _kl_fwd_kernel(q_ref, k_ref, lse_ref, words_ref, qi_ref, ki_ref, w_ref,
             keepdims=True)
         cross_acc[:] += jnp.sum(p * score, axis=0, keepdims=True)
 
-    @pl.when((j == pl.num_programs(2) - 1) & (h == heads - 1))
+    @pl.when((j == pl.num_programs(2) - 1) & last)
     def _write():
         ent_ref[0] = ent_acc[:]
         cross_ref[0] = cross_acc[:]
@@ -704,34 +741,33 @@ def _kl_fwd_kernel(q_ref, k_ref, lse_ref, words_ref, qi_ref, ki_ref, w_ref,
 
 def _kl_bwd_kernel(q_ref, k_ref, lse_ref, words_ref, qi_ref, ki_ref, w_ref,
                    lse_i_ref, gkl_ref, dqi_ref, dw_ref, dki_ref, p_acc, dqi_acc,
-                   dw_acc, *, block_q, block_k, scale, heads, index_heads,
-                   index_scale):
-    i, j, h = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+                   dw_acc, *, block_q, block_k, all_heads, index_heads,
+                   index_scale, **layout):
+    """The KL's backward a grid step: the probabilities as in the forward,
+    then at the pair's last head block ``dI = g (softmax_S(I) - p)`` under
+    the one mask, and from it dqI, dw and dkI."""
+    i, j, hh = pl.program_id(1), pl.program_id(2), pl.program_id(3)
     live = j <= _last_kv(i, block_q, block_k)
+    last = hh == pl.num_programs(3) - 1
 
-    @pl.when((i == 0) & (j == 0) & (h == 0))
+    @pl.when((i == 0) & (j == 0) & (hh == 0))
     def _init_dki():
         dki_ref[:] = jnp.zeros_like(dki_ref)
 
-    @pl.when((j == 0) & (h == 0))
+    @pl.when((j == 0) & (hh == 0))
     def _init():
         dqi_acc[:] = jnp.zeros_like(dqi_acc)
         dw_acc[:] = jnp.zeros_like(dw_acc)
 
-    @pl.when(h == 0)
-    def _zero():
-        p_acc[:] = jnp.zeros_like(p_acc)
-
     @pl.when(live)
     def _probs():
-        p_acc[:] += _probs_tile(q_ref, k_ref, lse_ref, words_ref, block_k,
-                                scale)
+        _probs_tile(q_ref, k_ref, lse_ref, p_acc, hh == 0, **layout)
 
-    @pl.when(live & (h == heads - 1))
+    @pl.when(live & last)
     def _grads():
         ki = ki_ref[0]
         mask = _unpack(words_ref[0], block_k) != 0
-        p = p_acc[:] * (1.0 / heads)
+        p = jnp.where(mask, p_acc[:], 0.0) * (1.0 / all_heads)
         score = _index_tile(ki, qi_ref, w_ref, index_heads, index_scale)
         soft = jnp.where(mask, jnp.exp(score - lse_i_ref[0]), 0.0)
         d_score = (soft - p) * (gkl_ref[0] * index_scale)
@@ -749,42 +785,59 @@ def _kl_bwd_kernel(q_ref, k_ref, lse_ref, words_ref, qi_ref, ki_ref, w_ref,
             dki_ref[0, rows, :] += jnp.dot(da, qi,
                                            preferred_element_type=jnp.float32)
 
-    @pl.when((j == pl.num_programs(2) - 1) & (h == heads - 1))
+    @pl.when((j == pl.num_programs(2) - 1) & last)
     def _write():
         dqi_ref[0] = dqi_acc[:].astype(dqi_ref.dtype)
         dw_ref[0] = dw_acc[:]
 
 
-def _kl_specs(g, d, hi, di, block):
+def _kl_specs(q, k, qi, d, block, index_scale):
+    """What the KL's two calls share: ``(grid, in_specs, params)``, the
+    grid (b, q-block, kv-block, head block) with ``_heads_per_step`` query
+    heads a step by the KL's own VMEM count, the head blocks innermost so
+    that a pair's probabilities sum over them in one scratch tile; q is
+    fetched once a query block where one block holds every head, k once
+    a pair, and a skipped step repeats the last live step's blocks."""
+    b, s, h = q.shape[0], q.shape[1], q.shape[2] // d
+    hi, di = qi.shape[1], qi.shape[3]
+    group = h // (k.shape[2] // d)
+    heads, kv_heads, steps = _head_blocks(
+        h, group, d, block, q.dtype.itemsize, functools.partial(
+            _kl_step_bytes, seq=s, index_heads=hi, index_dim=di))
+    n, blocks = s // block, h // heads
     last = lambda i: _last_kv(i, block, block)  # noqa: E731
     kv = lambda i, j: jnp.minimum(j, last(i))  # noqa: E731
-    return [
-        pl.BlockSpec((1, block, d), lambda n_, i, j, hh: (n_, i, hh)),
-        pl.BlockSpec((1, block, d),
-                     lambda n_, i, j, hh: (n_, kv(i, j), hh // g)),
-        pl.BlockSpec((1, 1, 1, block), lambda n_, i, j, hh: (n_, hh, 0, i)),
+    hb = lambda i, j, hh: jnp.where(  # noqa: E731
+        j <= last(i), hh, blocks - 1)
+    specs = [
+        pl.BlockSpec((1, block, heads * d),
+                     lambda n_, i, j, hh: (n_, i, hb(i, j, hh))),
+        pl.BlockSpec((1, block, kv_heads * d), lambda n_, i, j, hh: (
+            n_, kv(i, j), hb(i, j, hh) // steps)),
+        pl.BlockSpec((1, heads, 1, block),
+                     lambda n_, i, j, hh: (n_, hb(i, j, hh), 0, i)),
         pl.BlockSpec((1, block // 32, block),
                      lambda n_, i, j, hh: (n_, kv(i, j), i)),
         pl.BlockSpec((1, hi, block, di), lambda n_, i, j, hh: (n_, 0, i, 0)),
         pl.BlockSpec((1, block, di), lambda n_, i, j, hh: (n_, kv(i, j), 0)),
         pl.BlockSpec((1, hi, block), lambda n_, i, j, hh: (n_, 0, i)),
     ]
+    params = dict(block_q=block, block_k=block, all_heads=h, index_heads=hi,
+                  index_scale=index_scale, heads=heads, group=group, d=d,
+                  scale=1.0 / math.sqrt(d))
+    return (b, n, n, blocks), specs, params
 
 
 def _kl_fwd_call(q, k, lse, words, qi, ki, w, d, index_scale, block,
                  interpret):
     """``(sum_s p log p, sum_s p I)`` a query, each [B, 1, S]."""
-    b, s, h = q.shape[0], q.shape[1], q.shape[2] // d
-    hi, di = qi.shape[1], qi.shape[3]
-    n = s // block
+    b, s = q.shape[0], q.shape[1]
+    grid, specs, params = _kl_specs(q, k, qi, d, block, index_scale)
     row = pl.BlockSpec((1, 1, block), lambda n_, i, j, hh: (n_, 0, i))
     return pl.pallas_call(
-        functools.partial(_kl_fwd_kernel, block_q=block, block_k=block,
-                          scale=1.0 / math.sqrt(d), heads=h, index_heads=hi,
-                          index_scale=index_scale),
+        functools.partial(_kl_fwd_kernel, **params),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, n, n, h),
-            in_specs=_kl_specs(h * d // k.shape[2], d, hi, di, block),
+            num_scalar_prefetch=0, grid=grid, in_specs=specs,
             out_specs=(row, row),
             scratch_shapes=[pltpu.VMEM((block, block), jnp.float32),
                             pltpu.VMEM((1, block), jnp.float32),
@@ -800,18 +853,14 @@ def _kl_fwd_call(q, k, lse, words, qi, ki, w, d, index_scale, block,
 def _kl_bwd_call(q, k, lse, words, qi, ki, w, lse_i, gkl, d, index_scale,
                  block, interpret):
     """``(dqI [B, HI, S, DI], dw [B, HI, S], dkI [B, S, DI] float32)``."""
-    b, s, h = q.shape[0], q.shape[1], q.shape[2] // d
+    s = q.shape[1]
     hi, di = qi.shape[1], qi.shape[3]
-    n = s // block
+    grid, specs, params = _kl_specs(q, k, qi, d, block, index_scale)
     row = pl.BlockSpec((1, 1, block), lambda n_, i, j, hh: (n_, 0, i))
     return pl.pallas_call(
-        functools.partial(_kl_bwd_kernel, block_q=block, block_k=block,
-                          scale=1.0 / math.sqrt(d), heads=h, index_heads=hi,
-                          index_scale=index_scale),
+        functools.partial(_kl_bwd_kernel, **params),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0, grid=(b, n, n, h),
-            in_specs=_kl_specs(h * d // k.shape[2], d, hi, di, block)
-            + [row, row],
+            num_scalar_prefetch=0, grid=grid, in_specs=specs + [row, row],
             out_specs=(
                 pl.BlockSpec((1, hi, block, di),
                              lambda n_, i, j, hh: (n_, 0, i, 0)),

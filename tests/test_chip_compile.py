@@ -13,6 +13,7 @@ limit.
 Nothing executes; a pass is not a chip run.
 """
 
+import functools
 import os
 import re
 
@@ -715,10 +716,10 @@ def test_sparse_attention_kernels_compile_for_v5e(v5e, monkeypatch):
     16 heads of 64 and 2048 keys a query: what interpret mode cannot see
     (the sequence-sized VMEM of the selection's keys and of dkI, the
     bit-packed masks, the lane-sliced head blocks). The attention kernels
-    run all 32 query heads (the 4 key/value heads' groups) a grid step,
-    so their grids have one head block where 32 query heads stand, and
-    each call's blocks (double-buffered) and scratch stay within the VMEM
-    it asks for."""
+    and the KL's run all 32 query heads (the 4 key/value heads' groups)
+    a grid step, so their grids have one head block where 32 query heads
+    stand, and each call's blocks (double-buffered) and scratch stay
+    within the VMEM it asks for."""
     from horovod_tpu.ops import sparse_attention as sa
 
     calls = []
@@ -763,6 +764,10 @@ def test_sparse_attention_kernels_compile_for_v5e(v5e, monkeypatch):
     assert grids["_fwd_kernel"] == (1, 32 // heads, 64, 64) == (1, 1, 64, 64)
     assert grids["_dq_kernel"] == (1, 1, 64, 64)
     assert grids["_dkv_kernel"] == (1, 4 // (heads // 8), 64, 1, 64)
+    assert sa._heads_per_step(32, 8, 128, sa.BLOCK, 2, functools.partial(
+        sa._kl_step_bytes, seq=s, index_heads=16, index_dim=64)) == 32
+    assert grids["_kl_fwd_kernel"] == (1, 64, 64, 1)
+    assert grids["_kl_bwd_kernel"] == (1, 64, 64, 1)
     assert len(calls) == 6
     for name, _, need, limit in calls:
         assert limit is not None and need <= limit, (name, need, limit)

@@ -5,6 +5,8 @@ float32): the output, dq, dk, dv, the indexer's KL a query (which reads
 the head-summed probabilities) and its gradients; and the selection
 against ``lax.top_k``, ties included."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -99,17 +101,30 @@ def test_kernels_match_the_reference_gather(blocks, top_k, h, g):
         assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2, name
 
 
-@pytest.mark.parametrize("h,g,d", [
-    (32, 4, 128), (16, 16, 128), (6, 2, 128), (64, 8, 256)],
-    ids=["cell", "mha", "group_3", "d256"])
-def test_the_heads_of_a_grid_step_follow_the_shapes(h, g, d):
-    """The query heads of a grid step: all of them where they fit the
-    VMEM budget; else as many whole groups (a key/value head's query
-    heads) as fit; else the largest divisor of one group that fits; one
-    head a step at the least."""
+def _kl_rule(seq=32768, index_heads=16, index_dim=64):
+    """The KL kernels' VMEM count at an indexer's sizes (the cell's by
+    default), for ``_heads_per_step``."""
+    return functools.partial(sa._kl_step_bytes, seq=seq,
+                             index_heads=index_heads, index_dim=index_dim)
+
+
+@pytest.mark.parametrize("h,g,d,kl", [
+    (32, 4, 128, False), (16, 16, 128, False), (6, 2, 128, False),
+    (64, 8, 256, False), (32, 4, 128, True), (16, 16, 128, True),
+    (6, 2, 128, True), (64, 8, 256, True)],
+    ids=["cell", "mha", "group_3", "d256", "kl_cell", "kl_mha",
+         "kl_group_3", "kl_d256"])
+def test_the_heads_of_a_grid_step_follow_the_shapes(h, g, d, kl):
+    """The query heads of a grid step, by the attention kernels' VMEM
+    count and by the KL kernels' (``kl``: the cell's indexer, dkI of 32k
+    positions resident): all of them where they fit the VMEM budget;
+    else as many whole groups (a key/value head's query heads) as fit;
+    else the largest divisor of one group that fits; one head a step at
+    the least."""
     group = h // g
-    step = lambda n: sa._step_bytes(n, group, d, 512, 2)  # noqa: E731
-    heads = lambda: sa._heads_per_step(h, group, d, 512, 2)  # noqa: E731
+    rule = _kl_rule() if kl else sa._step_bytes
+    step = lambda n: rule(n, group, d, 512, 2)  # noqa: E731
+    heads = lambda: sa._heads_per_step(h, group, d, 512, 2, rule)  # noqa: E731
     fits = [n for n in range(1, h + 1) if step(n) <= sa._VMEM_BYTES and (
         group % n == 0 or (n % group == 0 and g % (n // group) == 0))]
     assert heads() == max(fits)
@@ -152,6 +167,34 @@ def test_a_group_split_over_grid_steps_gives_the_same_numbers(blocks,
             np.testing.assert_array_equal(a, b, err_msg=name)
         else:
             assert np.linalg.norm(a - b) / np.linalg.norm(b) < 1e-2, name
+
+
+def test_the_kl_split_over_grid_steps_gives_the_same_numbers(blocks,
+                                                            monkeypatch):
+    """The KL's kernels at two groups of 8 heads with a VMEM budget cut to
+    fit one and then two heads a grid step (the head blocks innermost,
+    their probabilities summed over the steps in one scratch tile) give
+    the KL a query, dqI, dkI and dw of all 16 heads in one step bit for
+    bit: the heads are summed in the same order, and the mask applied
+    once to the sum."""
+    x = _operands(13, h=16, g=2)
+    rule = _kl_rule(512, 4, 64)
+
+    def run():
+        kl = _kernel(*x, 100)[1]
+        grads = jax.grad(lambda *a: jnp.sum(
+            _kernel(*a, 100)[1] * jnp.cos(jnp.arange(512.0))),
+            argnums=(3, 4, 5))(*x)
+        return [np.asarray(a, np.float32) for a in (kl, *grads)]
+
+    assert sa._heads_per_step(16, 8, 128, sa.BLOCK, 2, rule) == 16
+    whole = run()
+    for heads in (1, 2):
+        monkeypatch.setattr(sa, "_VMEM_BYTES",
+                            rule(heads, 8, 128, sa.BLOCK, 2))
+        assert sa._heads_per_step(16, 8, 128, sa.BLOCK, 2, rule) == heads
+        for name, a, b in zip(("kl", "dqI", "dkI", "dw"), run(), whole):
+            np.testing.assert_array_equal(a, b, err_msg=f"{name} {heads}")
 
 
 def test_a_top_k_of_every_key_is_causal_attention(blocks):
